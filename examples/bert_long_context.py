@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-import kungfu_tpu._jax_compat  # noqa: F401  (jax.shard_map on 0.4.x)
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import optax
 
-import kungfu_tpu._jax_compat  # noqa: F401  (jax.shard_map on 0.4.x)
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 

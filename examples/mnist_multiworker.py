@@ -19,14 +19,10 @@ import os
 
 # Workers in local emulation share one machine: run each on the CPU
 # backend. On a real TPU pod set KF_WORKER_PLATFORM=tpu so every host
-# worker grabs its chips. jax.config must also be set because an
-# environment-registered PJRT plugin can outrank the env var.
+# worker grabs its chips.
 os.environ["JAX_PLATFORMS"] = os.environ.get("KF_WORKER_PLATFORM", "cpu")
 
 import jax
-
-if os.environ["JAX_PLATFORMS"] == "cpu":
-    jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np
 import optax

@@ -101,7 +101,7 @@ def bench_ici(args) -> None:
     out = tuple(buffers)
     for _ in range(max(1, args.warmup)):
         out = allreduce_all(out)
-    _ = float(out[0][0, 0])  # true fence (see bench.py)
+    _ = float(out[0][0, 0])  # the fetch fences the chain
     t0 = time.perf_counter()
     for _ in range(args.iters):
         out = allreduce_all(out)

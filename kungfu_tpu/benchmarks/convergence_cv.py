@@ -62,8 +62,8 @@ def _train_resnet18(x, y, xt, yt, steps: int, batch: int, lr: float,
 
     sampler = ElasticSampler(len(x), batch * n, rank=0, size=1,
                              seed=seed)
-    # compile outside the timed region (the relay's first compile is
-    # tens of seconds and is not a training cost)
+    # compile outside the timed region (the first compile is tens of
+    # seconds and is not a training cost)
     idx = sampler.next_indices()
     b0 = shard_batch({"x": x[idx], "y": y[idx]}, mesh)
     params_s, stats_s, opt_s, loss = step(params_s, stats_s, opt_s, b0)

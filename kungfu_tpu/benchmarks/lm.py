@@ -33,6 +33,16 @@ _BF16_PEAK_BY_KIND = {
 }
 
 
+def _device_meta():
+    """The device a number was taken on — in every row this module
+    prints, so that none can be read without it."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "devices": jax.device_count()}
+
+
 def _train_mfu(cfg, tokens_per_sec, seq, n_chips):
     """Model FLOPs utilization of a train step vs the chip's bf16 peak
     across `n_chips` chips; None when the peak for this device kind is
@@ -177,28 +187,45 @@ def measure_lm_rate(size: str = "small", batch: int = 8, seq: int = 1024,
             lambda p, t: gpt_fused_loss(
                 model, p, t, residual=residual, mesh=mesh), tx)
 
+    from kungfu_tpu.compile_cache import timed_compile
+
+    compile_s, compiled = timed_compile(step, params, opt, tokens)
+    # Pallas kernels in the compiled step. Flash and the fused head
+    # both fall back to plain XLA without a word where a shape does
+    # not tile; on a TPU a zero here says the row timed the fallbacks
+    # (interpret mode on a CPU inlines the kernels: always zero there)
+    n_kernels = compiled.as_text().count("tpu_custom_call")
+    del compiled  # read, never run: the loops call the jitted `step`
+
     def one(params, opt, tokens):
         out = step(params, opt, tokens)
         return out[0], out[1], out[2], (out[3] if len(out) > 3 else None)
 
+    losses = []
     for _ in range(max(warmup, 1)):
         params, opt, loss, aux = one(params, opt, tokens)
+        losses.append(loss)
     float(loss)  # fence: async dispatch must drain before timing
     t0 = time.perf_counter()
     for _ in range(iters):
         params, opt, loss, aux = one(params, opt, tokens)
+        losses.append(loss)
     float(loss)
     dt = (time.perf_counter() - t0) / iters
     global_tokens = batch * d_data * seq
     meta = {
-        "platform": platform, "devices": n, "tp": tp, "size": size,
+        **_device_meta(), "tp": tp, "size": size,
         "per_data_batch": batch, "seq": seq, "attention": attention,
         "step_time_ms": round(dt * 1000, 2), "iters": iters,
         # key name is historical; the denominator is the peak for
-        # device_kind below (non-v5e kinds report None until listed)
+        # device_kind (non-v5e kinds report None until listed)
         "mfu_vs_v5e_bf16_peak": _train_mfu(
             cfg, global_tokens / dt, seq, n),
-        "device_kind": jax.devices()[0].device_kind,
+        "compile_s": round(compile_s, 2),
+        "pallas_kernels": n_kernels,
+        # the same batch every step, warmup included: a working
+        # optimizer makes this sequence fall
+        "losses": [round(float(x), 4) for x in losses],
     }
     if attention == "flash":
         # per-kernel achieved-FLOPs efficiency of the flash fwd+bwd at
@@ -263,7 +290,6 @@ def measure_pp_rate(size: str = "small", batch: int = 8, seq: int = 1024,
     import jax.numpy as jnp
     import optax
 
-    import kungfu_tpu._jax_compat  # noqa: F401  (jax.shard_map on 0.4.x)
     from jax import shard_map
     from jax.sharding import Mesh
 
@@ -320,7 +346,7 @@ def measure_pp_rate(size: str = "small", batch: int = 8, seq: int = 1024,
     float(loss)
     dt = (time.perf_counter() - t0) / iters
     meta = {
-        "platform": platform, "devices": n, "pp": pp, "size": size,
+        **_device_meta(), "pp": pp, "size": size,
         "batch": batch, "seq": seq, "microbatches": microbatches,
         "schedule": "1F1B", "step_time_ms": round(dt * 1000, 2),
         "iters": iters,
@@ -371,7 +397,7 @@ def measure_decode_rate(size: str = "small", batch: int = 8,
     # the timed region is one batched prefill forward + gen_len decode
     # steps; ms_per_token divides by gen_len, so it slightly overstates
     # per-decode-step cost by the (single) prefill pass
-    meta = {"platform": platform, "size": size, "batch": batch,
+    meta = {**_device_meta(), "size": size, "batch": batch,
             "prompt_len": prompt_len, "gen_len": gen_len, "tp": tp,
             "ms_per_token": round(dt * 1000 / gen_len, 3)}
     return batch * gen_len / dt, meta
@@ -423,6 +449,15 @@ def main():
     ap.add_argument("--gen-len", type=int, default=128,
                     help="(--decode) generated tokens")
     args = ap.parse_args()
+    from kungfu_tpu import compile_cache
+
+    cache = compile_cache.enable()
+
+    def emit(metric, rate, meta):
+        meta["compile_cache"] = cache.as_dict()
+        print(json.dumps({"metric": metric, "value": round(rate, 1),
+                          "unit": "tokens/sec", "details": meta}))
+
     if (args.decode or args.pp) and (args.remat
                                      or args.ce_variant != "residual"):
         raise SystemExit(
@@ -437,9 +472,7 @@ def main():
         rate, meta = measure_decode_rate(args.size, args.batch,
                                          args.prompt_len, args.gen_len,
                                          iters=args.iters, tp=args.tp)
-        print(json.dumps({"metric": "gpt_decode_tokens_per_sec",
-                          "value": round(rate, 1),
-                          "unit": "tokens/sec", "details": meta}))
+        emit("gpt_decode_tokens_per_sec", rate, meta)
         return
     if args.microbatch_bound:
         # the 1F1B pipeline cuts the global batch into `microbatches`
@@ -473,17 +506,13 @@ def main():
         meta["global_batch"] = args.batch
         meta["microbatches"] = args.microbatches
         meta["microbatch"] = mb
-        print(json.dumps({"metric": "gpt_microbatch_bound_tokens_per_sec",
-                          "value": round(rate, 1), "unit": "tokens/sec",
-                          "details": meta}))
+        emit("gpt_microbatch_bound_tokens_per_sec", rate, meta)
         return
     if args.pp:
         rate, meta = measure_pp_rate(args.size, args.batch, args.seq,
                                      args.pp, args.microbatches,
                                      iters=args.iters)
-        print(json.dumps({"metric": "gpt_pp_tokens_per_sec",
-                          "value": round(rate, 1), "unit": "tokens/sec",
-                          "details": meta}))
+        emit("gpt_pp_tokens_per_sec", rate, meta)
         return
     rate, meta = measure_lm_rate(args.size, args.batch, args.seq,
                                  args.tp, args.attention, args.iters,
@@ -492,9 +521,7 @@ def main():
                                  moe_bf16=args.moe_bf16,
                                  remat=args.remat,
                                  ce_variant=args.ce_variant)
-    print(json.dumps({"metric": "gpt_tokens_per_sec",
-                      "value": round(rate, 1), "unit": "tokens/sec",
-                      "details": meta}))
+    emit("gpt_tokens_per_sec", rate, meta)
 
 
 if __name__ == "__main__":

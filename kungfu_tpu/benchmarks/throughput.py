@@ -99,23 +99,15 @@ def measure_rate(model_name: str, n: int, batch: int = 0, iters: int = 20,
     # hand-counting branch convs invites errors. `step` is already
     # jitted — lower it directly so the executable (and its cache
     # entry) is the same one the timing loop runs.
-    step_flops = None
-    try:
-        cost = step.lower(params_s, stats_s, opt_s,
-                          batch_s).compile().cost_analysis()
-        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-        step_flops = float(cost.get("flops", 0.0)) or None
-    # cost_analysis walks unstable XLA internals that have raised
-    # different types across jaxlib versions; it is best-effort
-    # metadata, throughput still reports without it
-    # kflint: disable=retry-discipline
-    except Exception:
-        pass
+    # (`cost_analysis` is None where the backend has none to give)
+    cost = step.lower(params_s, stats_s, opt_s,
+                      batch_s).compile().cost_analysis() or {}
+    step_flops = float(cost.get("flops", 0.0)) or None
 
     for _ in range(warmup):
         params_s, stats_s, opt_s, loss = step(params_s, stats_s, opt_s,
                                               batch_s)
-    float(loss)  # true execution fence (see bench.py note)
+    float(loss)  # the fetch fences the dependent step chain
 
     t0 = time.perf_counter()
     for _ in range(iters):
@@ -261,6 +253,9 @@ def main(argv=None) -> int:
 
     import jax
 
+    from kungfu_tpu import compile_cache
+
+    compile_cache.enable()
     if args.adamw:
         ms, meta = measure_adamw_update(args.lm_size, args.adamw,
                                         args.iters, args.warmup)

@@ -364,7 +364,7 @@ def _dim_semantics(n):
     state ⇒ sequential)."""
     sem = ("parallel",) * n if n == 2 else (
         ("parallel",) * (n - 1) + ("arbitrary",))
-    return pltpu.TPUCompilerParams(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 # ---------------------------------------------------------------------------

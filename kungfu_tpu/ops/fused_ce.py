@@ -71,6 +71,16 @@ _BLOCK_V = 1024
 # real 18.92 MB) OOMs at compile time. The budget sits between them,
 # so blocks shrink exactly when the real limit would bite.
 _VMEM_BUDGET = 15 * 1024 * 1024
+# The block estimates below count a [bn, 1] column (targets, lse, the
+# running max/sum scratch) at bn * 4 bytes; in VMEM each is padded to
+# 128 lanes, and inside a larger program XLA may place these small
+# operands in VMEM itself. The v5e compiler then refuses the
+# vocab-sharded forward on a 2x2 mesh at GPT-2-small width (17.72 MB
+# scoped against the 16 MB default) although the same blocks compile
+# alone. Every kernel here states its limit, so what is in use around
+# it cannot take it under. v5e has 128 MiB of VMEM.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=32 * 1024 * 1024)
 
 
 def _fwd_vmem_bytes(bn, h, bv):
@@ -339,6 +349,7 @@ def _fwd_pallas(x, w, b, t, bn, bv, interpret, residual):
             pltpu.VMEM((bn, 1), jnp.float32),   # running sum-exp
             pltpu.VMEM((bn, 1), jnp.float32),   # target-logit gather
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(x, w, b, t)
     if residual:
@@ -374,6 +385,7 @@ def _residual_d_pallas(scale, logits, lse, t, bn, bv, interpret):
         # d overwrites the logits residual in place: same shape/dtype,
         # consumed nowhere else
         input_output_aliases={1: 0},
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(scale, logits, lse, t)
 
@@ -406,6 +418,7 @@ def _dw_pallas(scale, x, w, b, t, lse, bn, bv, interpret):
             pltpu.VMEM((h, bv), jnp.float32),   # dW accumulator
             pltpu.VMEM((1, bv), jnp.float32),   # db accumulator
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(scale, x, w, b, t, lse)
 
@@ -431,6 +444,7 @@ def _dx_pallas(scale, x, w, b, t, lse, bn, bv, interpret):
         scratch_shapes=[
             pltpu.VMEM((bn, h), jnp.float32),   # dx accumulator
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(scale, x, w, b, t, lse)
 

@@ -71,26 +71,38 @@ _FORCE_SCHEME = os.environ.get("KUNGFU_PAGED_SCHEME") or None
 # ---------------------------------------------------------------------------
 
 
+def _tile(rows, cols, isz):
+    """VMEM bytes of one [rows, cols] trailing tile as Mosaic lays it
+    out: padded to 8 sublanes of 32 bits (16 rows of bf16) by 128
+    lanes. A GPT-2 head tile [12, 64] bf16 occupies 4 KiB, not 1.5 —
+    the unpadded count let `paged_plan` offer the resident scheme at
+    max_len 2048, which the v5e compiler refuses (scoped VMEM)."""
+    sub = 8 * max(4 // isz, 1)  # 8-byte types: 8 sublanes, as f32
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * isz
+
+
 def _res_vmem(max_blocks, bt, h, d, isz):
     """Resident scheme: double-buffered K/V pool blocks + q/o + the
-    full-length score buffer (f32) and V copy (pool dtype) + softmax
-    temporaries (w and the exp intermediate, both [h, T] f32)."""
+    full-length score buffer (f32) and V copy (pool dtype) + the final
+    step's temporaries (V in f32 for the weighted sum, and s, w and
+    the exp intermediate, each [h, T] f32)."""
     t = max_blocks * bt
-    inputs = 2 * (2 * bt * h * d * isz)
-    io = 2 * (2 * h * d * isz)
-    scratch = max_blocks * h * bt * 4 + t * h * d * isz
-    temps = 2 * h * t * 4
+    inputs = 2 * (2 * bt * _tile(h, d, isz))
+    io = 2 * (2 * _tile(h, d, isz))
+    scratch = max_blocks * _tile(h, bt, 4) + t * _tile(h, d, isz)
+    temps = t * _tile(h, d, 4) + 3 * _tile(h, t, 4)
     return inputs + io + scratch + temps
 
 
 def _stream_vmem(bt, h, d, isz):
     """Stream scheme: double-buffered K/V blocks + q/o + the online
-    state (acc [h, d] + m/l rows, f32) + per-block score temporaries.
-    O(block) regardless of max_len."""
-    inputs = 2 * (2 * bt * h * d * isz)
-    io = 2 * (2 * h * d * isz)
-    scratch = h * d * 4 + 2 * h * 4
-    temps = 2 * h * bt * 4
+    state (acc [h, d] + m/l columns, f32) + per-block temporaries (the
+    K and V block in f32, score and weight tiles). O(block) regardless
+    of max_len."""
+    inputs = 2 * (2 * bt * _tile(h, d, isz))
+    io = 2 * (2 * _tile(h, d, isz))
+    scratch = _tile(h, d, 4) + 2 * _tile(h, 1, 4)
+    temps = 2 * bt * _tile(h, d, 4) + 2 * _tile(h, bt, 4)
     return inputs + io + scratch + temps
 
 
@@ -142,6 +154,29 @@ def paged_traffic_bytes(lengths, block_tokens, num_heads, head_dim,
 # ---------------------------------------------------------------------------
 
 
+def _qk(q, k):
+    """Per-head scores ``q [h, d] . k [t, h, d] -> [h, t]`` as a VPU
+    multiply and lane reduction. Mosaic refuses the direct
+    ``"nd,tnd->nt"`` einsum (batch dimension in the middle, no free
+    lhs dimension), and with one query row per head the MXU would run
+    one row deep anyway; the exact-f32 multiply-reduce is also the
+    form XLA's CPU backend gives the functional path's einsum, which
+    keeps the resident scheme its bitwise oracle in interpret mode."""
+    return jnp.sum(k * q[None], axis=-1).T
+
+
+def _pv(p, v):
+    """Per-head weighted sum ``p [h, t] . v [t, h, d] -> [h, d]`` on
+    the MXU, in the one form Mosaic lowers a batched `dot_general`
+    in: batch (head) dimension leading on both sides and a free lhs
+    dimension (a unit row axis here)."""
+    o = jax.lax.dot_general(
+        p[:, None, :], jnp.swapaxes(v, 0, 1),
+        (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)     # [h, 1, d]
+    return o[:, 0, :]
+
+
 def _block_scores(q_ref, k_ref, length, j, *, bt, scale):
     """One pool block's masked f32 score tile [h, bt] — shared by both
     schemes so masking/scaling semantics cannot drift. Matches the
@@ -150,7 +185,7 @@ def _block_scores(q_ref, k_ref, length, j, *, bt, scale):
     f32-finfo.min."""
     q = q_ref[0].astype(jnp.float32)            # [h, d]
     k = k_ref[0].astype(jnp.float32)            # [bt, h, d]
-    s = jnp.einsum("nd,tnd->nt", q, k) * scale  # [h, bt]
+    s = _qk(q, k) * scale                       # [h, bt]
     pos = j * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
     return jnp.where(pos <= length, s, NEG_INF)
 
@@ -188,8 +223,7 @@ def _res_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         s = s_buf[...].transpose(1, 0, 2).reshape(h, t)   # [h, T]
         w = jax.nn.softmax(s, axis=-1)
         v = v_buf[...].reshape(t, h, -1).astype(jnp.float32)
-        o = jnp.einsum("nt,tnd->nd", w, v)
-        o_ref[0] = o.astype(o_ref.dtype)
+        o_ref[0] = _pv(w, v).astype(o_ref.dtype)
 
 
 def _stream_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -219,8 +253,7 @@ def _stream_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new[:, None])
         l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
         v = v_ref[0].astype(jnp.float32)          # [bt, h, d]
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jnp.einsum("nt,tnd->nd", p, v))
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + _pv(p, v)
         m_ref[:, 0] = m_new
 
     @pl.when(j == nb - 1)
@@ -312,7 +345,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, lengths, q, k_pool, v_pool)

@@ -8,7 +8,6 @@ collectives compile onto ICI. Elastic resize swaps the mesh at an epoch
 boundary and re-broadcasts state (kungfu_tpu.elastic).
 """
 
-from .. import _jax_compat  # noqa: F401  (installs jax.shard_map on 0.4.x)
 from .mesh import (
     axis_size,
     broadcast_params,

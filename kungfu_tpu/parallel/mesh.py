@@ -68,17 +68,21 @@ def replicate_to_workers(tree, mesh: Mesh, axis_name: str = "data"):
 
     The data-plane equivalent of the reference's BroadcastGlobalVariablesOp
     at init (reference: srcs/python/kungfu/tensorflow/initializer/): every
-    worker starts from the same row-0 state.
+    worker starts from the same row-0 state. Each chip is sent its own
+    row and nothing else: the whole (n, ...) stack never exists on one
+    device (built there first, it costs the first chip n models).
     """
     n = axis_size(mesh, axis_name)
     sharding = worker_sharding(mesh, axis_name)
-    return jax.tree_util.tree_map(
-        lambda x: jax.device_put(
-            jnp.broadcast_to(jnp.asarray(x)[None], (n,) + jnp.shape(x)),
-            sharding,
-        ),
-        tree,
-    )
+
+    def place(x):
+        row = jnp.asarray(x)[None]
+        return jax.make_array_from_single_device_arrays(
+            (n,) + row.shape[1:], sharding,
+            [jax.device_put(row, d)
+             for d in sharding.addressable_devices])
+
+    return jax.tree_util.tree_map(place, tree)
 
 
 def unstack_worker_state(tree, row: int = 0):

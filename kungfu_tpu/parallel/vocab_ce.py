@@ -49,7 +49,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
-from .. import _jax_compat  # noqa: F401  (jax.shard_map on 0.4.x)
 from .rules import cols, replicated, rows, spec, stacked
 from ..ops.fused_ce import (_PAD_BIAS, _dw_pallas, _dx_pallas,
                             _fwd_pallas, _fwd_vmem_bytes, _pick_blocks,

@@ -20,6 +20,7 @@ from .hostspec import (
     HostList,
     HostSpec,
     PortRange,
+    free_port,
 )
 from .interval import even_partition
 from .peerlist import PeerList
@@ -49,6 +50,7 @@ __all__ = [
     "format_ipv4",
     "DEFAULT_PORT_RANGE",
     "DEFAULT_RUNNER_PORT",
+    "free_port",
     "even_partition",
     "gen_tree",
     "gen_binary_tree",

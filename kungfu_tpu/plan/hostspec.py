@@ -42,6 +42,18 @@ DEFAULT_PORT_RANGE = PortRange(10000, 11000)
 DEFAULT_RUNNER_PORT = 38080
 
 
+def free_port() -> int:
+    """A port the OS just handed out as free on every interface. A
+    watch-mode runner binds its `-runner-port`, so tests and harnesses
+    that may run side by side each pass one of their own: two that
+    leave the default collide with "Address already in use"."""
+    import socket
+
+    with socket.socket() as s:
+        s.bind(("", 0))
+        return s.getsockname()[1]
+
+
 def split_host_entry(spec: str) -> "tuple[str, int, str]":
     """'host[:slots[:public]]' -> (host, slots, public). The single
     grammar for -H entries; `host` may still be a hostname here (the

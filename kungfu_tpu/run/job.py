@@ -18,8 +18,9 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from .. import chaos
+from .. import compile_cache
 from .. import env as kfenv
-from ..plan import PeerID, PeerList
+from ..plan import PeerID, PeerList, free_port
 
 
 class ChipPool:
@@ -83,6 +84,39 @@ def _pump(stream, log_file, prefix: str, color: int, quiet: bool):
         stream.close()
 
 
+#: chip slot -> the mesh-controller port its newest worker was given
+_mesh_ports: Dict[int, int] = {}
+
+
+def _chip_env(chip: int) -> Dict[str, str]:
+    """One TPU chip per slot, like CUDA_VISIBLE_DEVICES per GPU slot
+    (reference: job.go:41-47). The visible chip alone is not enough
+    for libtpu: of four such processes on a four-chip v5e host one
+    starts and three abort on libtpu's multi-process lock (PERF.md,
+    PR 21). The bounds make each worker a one-chip slice of its own,
+    and each slice's mesh controller listens on a port the OS just
+    handed out as free — not libtpu's default plus the slot, which a
+    second job on the host, or the dying worker a respawn replaces,
+    may hold. A launch that lays its processes out itself
+    (`TPU_PROCESS_BOUNDS` in the runner's environment) keeps its
+    layout. Harmless when workers run on CPU."""
+    env = {"TPU_VISIBLE_DEVICES": str(chip)}
+    if not os.environ.get("TPU_PROCESS_BOUNDS"):
+        _mesh_ports.pop(chip, None)
+        port = free_port()
+        # workers spawned together have not bound theirs yet
+        while port in _mesh_ports.values():
+            port = free_port()
+        _mesh_ports[chip] = port
+        env.update({
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+            "TPU_MESH_CONTROLLER_PORT": str(port),
+        })
+    return env
+
+
 def _worker_env_delta(
     self_id: PeerID,
     peers: PeerList,
@@ -92,7 +126,6 @@ def _worker_env_delta(
     config_server: str,
     chip: Optional[int],
     extra_env: Optional[Dict[str, str]],
-    logdir: str,
 ) -> Dict[str, str]:
     env = dict(
         kfenv.worker_env(
@@ -105,18 +138,13 @@ def _worker_env_delta(
         )
     )
     if chip is not None:
-        # one TPU chip per slot, like CUDA_VISIBLE_DEVICES per GPU slot
-        # (reference: job.go:41-47); harmless when workers run on CPU
-        env["TPU_VISIBLE_DEVICES"] = str(chip)
-        env["TPU_PROCESS_BOUNDS"] = os.environ.get(
-            "TPU_PROCESS_BOUNDS", "")
+        env.update(_chip_env(chip))
     # persistent XLA compilation cache shared across worker GENERATIONS:
     # an elastic resize rebuilds mesh + jitted step in the new epoch's
     # workers; with the cache the recompile is a disk hit instead of a
-    # from-scratch XLA run (VERDICT r2 item 5)
-    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
-            os.path.abspath(logdir), ".jax-cache")
+    # from-scratch XLA run (VERDICT r2 item 5). One fixed place for
+    # every run (compile_cache.py): the path is part of the cache key
+    env[compile_cache.ENV] = compile_cache.cache_dir()
     if extra_env:
         env.update(extra_env)
     return env
@@ -158,7 +186,7 @@ def spawn_worker(
     env = dict(os.environ)
     env.update(
         _worker_env_delta(self_id, peers, version, strategy, parent,
-                          config_server, chip, extra_env, logdir)
+                          config_server, chip, extra_env)
     )
 
     os.makedirs(logdir, exist_ok=True)
@@ -226,12 +254,10 @@ class WarmPool:
     and callers fall back to a cold `spawn_worker`.
     """
 
-    def __init__(self, prog: List[str], target: int, quiet: bool = True,
-                 logdir: str = "."):
+    def __init__(self, prog: List[str], target: int, quiet: bool = True):
         self.prog = prog
         self.target = max(0, target)
         self.quiet = quiet
-        self.logdir = logdir
         self.enabled = (_is_python_prog(prog)
                         and os.environ.get("KF_PREWARM", "1") != "0")
         # warm interpreters cost ~150 MB RSS and a few seconds of
@@ -266,9 +292,7 @@ class WarmPool:
             # jax freezes this env var at IMPORT time, and prewarm
             # imports jax before the activation env arrives — so the
             # compile-cache dir must be present at spawn, not activation
-            env.setdefault(
-                "JAX_COMPILATION_CACHE_DIR",
-                os.path.join(os.path.abspath(self.logdir), ".jax-cache"))
+            env[compile_cache.ENV] = compile_cache.cache_dir()
             p = subprocess.Popen(
                 [sys.executable, "-m", "kungfu_tpu.run.prewarm", "--"]
                 + self.prog[1:],
@@ -366,7 +390,7 @@ def activate_warm(
         pass
     rank = peers.rank(self_id)
     env = _worker_env_delta(self_id, peers, version, strategy, parent,
-                            config_server, chip, extra_env, logdir)
+                            config_server, chip, extra_env)
     os.makedirs(logdir, exist_ok=True)
     log_path = os.path.join(logdir, f"worker-{rank}-{self_id.port}.log")
     try:

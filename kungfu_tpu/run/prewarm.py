@@ -68,13 +68,10 @@ def main(argv=None) -> int:
     if "JAX_COMPILATION_CACHE_DIR" in env:
         # jax froze the env var at import; late-bind via config so an
         # activation-time cache dir still takes effect
-        try:
-            import jax
+        import jax
 
-            jax.config.update("jax_compilation_cache_dir",
-                              env["JAX_COMPILATION_CACHE_DIR"])
-        except (ImportError, AttributeError, KeyError, ValueError):
-            pass  # older jax without the config key: cold compile only
+        jax.config.update("jax_compilation_cache_dir",
+                          env["JAX_COMPILATION_CACHE_DIR"])
 
     if argv[0] == "-m":
         if len(argv) < 2:

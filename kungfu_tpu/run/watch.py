@@ -120,7 +120,7 @@ class Watcher:
         # joiners activate from pre-warmed interpreters (imports already
         # paid) so a resize costs one env write, not a python+jax boot —
         # the bulk of round 2's ~6s resize latency (KF_PREWARM=0 opts out)
-        self.warm = WarmPool(prog, target=0, quiet=True, logdir=logdir)
+        self.warm = WarmPool(prog, target=0, quiet=True)
         self.procs: Dict[PeerID, Proc] = {}
         # the last stage this runner APPLIED — the recovery proposal's
         # fallback base when the config server answers 404 (restarted

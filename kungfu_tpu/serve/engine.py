@@ -30,6 +30,7 @@ is what the parity tests pin.
 
 from __future__ import annotations
 
+import warnings
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -156,8 +157,7 @@ class DecodeEngine:
             cfg, num_blocks, block_tokens)
         # KF_SERVE_KERNEL resolution happens ONCE, here: "auto" means
         # the plan's pick on TPU and the functional path on CPU;
-        # "kernel" forces the plan's pick (interpret mode off-TPU);
-        # an over-budget plan degrades to functional either way
+        # "kernel" forces the plan's pick (interpret mode off-TPU)
         self.kernel = self._resolve_kernel(kernel, block_tokens)
         self._decode = paged.make_decode_fn(cfg, kernel=self.kernel)
         self._prefill = paged.make_prefill_chunk_fn(cfg)
@@ -174,24 +174,34 @@ class DecodeEngine:
 
     def _resolve_kernel(self, knob: str, block_tokens: int) -> str:
         """Map the KF_SERVE_KERNEL knob to the decode_step kernel
-        argument, consulting `paged_plan` so an over-budget shape
-        falls back to the functional path at construction (not at
-        Mosaic compile time)."""
+        argument, consulting `paged_plan` so an over-budget shape is
+        settled at construction (not at Mosaic compile time). A plan
+        that fits neither scheme raises when a kernel was asked for
+        ("kernel") and warns under "auto": the functional path is
+        never taken in silence."""
         if knob == "functional":
             return "functional"
         import jax
 
         if knob == "auto" and jax.default_backend() != "tpu":
             return "functional"
-        if knob in ("auto", "kernel"):
-            from ..ops import paged_attn
+        if knob not in ("auto", "kernel"):
+            return knob  # explicit "resident"/"stream" (tests)
+        from ..ops import paged_attn
 
-            plan = paged_attn.paged_plan(
-                self.max_blocks, block_tokens, self.cfg.num_heads,
-                self.cfg.hidden_size // self.cfg.num_heads,
-                dtype=self.cfg.dtype)
-            return plan["scheme"]
-        return knob  # explicit "resident"/"stream" (tests)
+        plan = paged_attn.paged_plan(
+            self.max_blocks, block_tokens, self.cfg.num_heads,
+            self.cfg.hidden_size // self.cfg.num_heads,
+            dtype=self.cfg.dtype)
+        if plan["scheme"] == "functional":
+            why = (f"no paged-attention scheme fits VMEM at max_len "
+                   f"{self.max_len}, block_tokens {block_tokens} "
+                   f"(resident {plan['resident_bytes'] >> 20} MiB, "
+                   f"stream {plan['stream_bytes'] >> 20} MiB)")
+            if knob == "kernel":
+                raise ValueError(f"KF_SERVE_KERNEL=kernel: {why}")
+            warnings.warn(f"{why}; decoding with the functional gather")
+        return plan["scheme"]
 
     def warm(self) -> None:
         """Compile every signature the serving loop can hit, BEFORE

@@ -39,12 +39,7 @@ KF_SERVE_EVICTED / KF_SERVE_DONE.
 import os
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 
 import kungfu_tpu

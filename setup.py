@@ -70,8 +70,12 @@ setup(
         "kungfu_tpu": ["native/libkf.so", "native/Makefile",
                        "native/include/*.h", "native/src/*"],
     },
-    python_requires=">=3.9",
-    install_requires=["numpy", "jax", "flax", "optax"],
+    python_requires=">=3.11",
+    # the one toolchain the tree is written and checked against (the
+    # Pallas TPU names in ops/ are 0.9.0's; on a TPU host add
+    # libtpu==0.0.34, i.e. `pip install "jax[tpu]==0.9.0"`)
+    install_requires=["numpy", "jax==0.9.0", "jaxlib==0.9.0",
+                      "flax==0.12.3", "optax"],
     distclass=BinaryDistribution,
     cmdclass={
         "build_native": BuildNative,

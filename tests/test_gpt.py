@@ -575,14 +575,6 @@ class TestRemat:
     KW = dict(vocab_size=211, hidden_size=128, num_layers=2,
               num_heads=4, intermediate_size=256, max_position=48)
 
-    @pytest.mark.xfail(
-        reason="seed-reproducing: the pinned jax 0.4.x CPU backend "
-               "recomputes the fused-CE Pallas bwd under remat with a "
-               "different fusion order, so grads differ in the last "
-               "ulp — bitwise equality needs an upstream fix or a "
-               "remat-aware kernel policy (tracked since the seed; "
-               "loss equality and generation parity below still hold)",
-        strict=False)
     def test_remat_param_tree_and_grads_identical(self):
         from kungfu_tpu.models import gpt_fused_loss
 

@@ -254,9 +254,8 @@ class TestICIBucketedSyncSGD:
     def test_bitwise_equals_per_leaf(self):
         from functools import partial
 
-        import kungfu_tpu._jax_compat  # noqa: F401
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from kungfu_tpu.optimizers import sync_sgd, sync_sgd_bucketed
 

@@ -95,13 +95,6 @@ def test_coordinator_address_is_rank0():
         f"127.0.0.1:{31000 + COORDINATOR_PORT_OFFSET}"
 
 
-@pytest.mark.xfail(
-    reason="seed-reproducing: this container's jaxlib CPU PJRT client "
-           "rejects cross-process computations ('Multiprocess "
-           "computations aren't implemented on the CPU backend'), so "
-           "the 2-host bootstrap shape can only run on real TPU/GPU "
-           "backends or a jaxlib with the CPU collectives plugin",
-    strict=False)
 def test_two_process_global_mesh(tmp_path):
     base = free_port_pair_with_coordinator()
     peers = f"127.0.0.1:{base},127.0.0.1:{base + 1}"

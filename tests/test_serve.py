@@ -644,6 +644,44 @@ class TestPagedEngine:
         assert (outs["stream"].argmax(-1).tolist()
                 == outs["functional"].argmax(-1).tolist())
 
+    def test_plan_counts_padded_tiles(self):
+        """The v5e compiler refuses the resident scheme at max_len
+        2048 for GPT-2 heads (scoped VMEM): a [12, 64] bf16 head tile
+        occupies [16, 128] there. The plan must not offer it."""
+        import jax.numpy as jnp
+
+        from kungfu_tpu.ops.paged_attn import paged_plan
+
+        at = {n: paged_plan(n // 16, 16, 12, 64, dtype=jnp.bfloat16)
+              for n in (1024, 2048)}
+        assert at[1024]["scheme"] == "resident"
+        assert at[2048]["scheme"] == "stream"
+        assert at[2048]["resident_bytes"] > 16 * 2**20
+        # an 8-byte item pads like a 4-byte one (no division by zero)
+        assert (paged_plan(4, 16, 12, 64, dtype=jnp.float64)
+                ["stream_bytes"]) > at[1024]["stream_bytes"]
+
+    @pytest.mark.parametrize("knob", ["kernel", "auto"])
+    def test_no_scheme_fits_is_never_silent(self, lm, monkeypatch, knob):
+        """An over-budget plan raises when a kernel was asked for and
+        warns under "auto" on a TPU — never a silent functional path."""
+        import jax
+
+        from kungfu_tpu.ops import paged_attn
+        from kungfu_tpu.serve.engine import DecodeEngine
+
+        model, params = lm
+        monkeypatch.setattr(paged_attn, "_VMEM_BUDGET", 0)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        kw = dict(max_batch=2, block_tokens=4, max_len=32, kernel=knob)
+        if knob == "kernel":
+            with pytest.raises(ValueError, match="KF_SERVE_KERNEL=kernel"):
+                DecodeEngine(model, params, **kw)
+        else:
+            with pytest.warns(UserWarning, match="functional gather"):
+                assert DecodeEngine(model, params,
+                                    **kw).kernel == "functional"
+
     def test_kernel_token_parity_end_to_end(self, lm):
         """Whole generations through the engine with the kernel
         schemes match the functional path token for token (growth

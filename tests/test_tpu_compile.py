@@ -1,0 +1,208 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e.
+
+The CPU suite runs every kernel in interpret mode, which accepts
+programs the chip's compiler refuses (a dot with its batch dimension in
+the middle, a scratch buffer past scoped VMEM). libtpu compiles for a
+topology that is described and not attached, so these cases hand each
+kernel of the main path, at its real widths, to Mosaic itself: nothing
+runs, but what the compiler would refuse on the chip it refuses here.
+
+One file on purpose: only one process may load libtpu, and under xdist
+the worker that is handed this file is that process. The topology is
+therefore described inside a fixture, never while a module is imported.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+# GPT-2-small widths: the LM benchmark's `--size small --batch 8 --seq 1024`
+B, T, H, D = 8, 1024, 12, 64
+HIDDEN, VOCAB = 768, 50257
+# serving shape: KF_SERVE_MAX_BATCH=8, KF_KV_BLOCK_TOKENS=16, max_len 1024
+SERVE_BATCH, BLOCK_TOKENS, MAX_LEN, LAYERS = 8, 16, 1024, 12
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described (not attached) v5e 2x2, with the persistent compile
+    cache off around the module: an entry written for a described chip
+    cannot be read back without one, and the next compile would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """(2, 2) ("data", "model") over the four described chips — the
+    sequence tests use the "model" axis as their ring."""
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _kernels(compiled):
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _qkv(sharding, b=B, t=T, h=H, d=D):
+    s = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=sharding)
+    return s, s, s
+
+
+@pytest.mark.parametrize("scheme", ["resident", "stream"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_gpt2_small(one_chip, monkeypatch, scheme, grad):
+    from kungfu_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_FORCE_SCHEME", scheme)
+
+    def fwd(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True,
+                                     interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    # forward is one kernel; the backward adds dq and dkv
+    assert _kernels(_compile(fn, *_qkv(one_chip))) == (3 if grad else 1)
+
+
+def test_flash_window_16k(one_chip):
+    from kungfu_tpu.ops.flash import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=512,
+                              interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        *_qkv(one_chip, b=1, t=16384, h=8))
+    assert _kernels(compiled) == 3
+
+
+@pytest.mark.parametrize("residual", [True, False],
+                         ids=["residual", "recompute"])
+def test_fused_ce_gpt2_small(one_chip, residual):
+    from kungfu_tpu.ops.fused_ce import fused_cross_entropy
+
+    def loss(x, w, b, t):
+        return fused_cross_entropy(x, w, b, t, interpret=False,
+                                   residual=residual)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)),
+        sds((B * T, HIDDEN), jnp.bfloat16),
+        sds((HIDDEN, VOCAB), jnp.float32), sds((VOCAB,), jnp.float32),
+        sds((B * T,), jnp.int32))
+    # residual: forward + the d rebuild; recompute: forward + dW + dx
+    assert _kernels(compiled) == (2 if residual else 3)
+
+
+@pytest.mark.parametrize("dtype,max_len,scheme,plan", [
+    (jnp.bfloat16, MAX_LEN, "resident", "resident"),
+    (jnp.bfloat16, MAX_LEN, "stream", "resident"),
+    # chip_smoke.py's float32 token check: resident where float32 fits
+    (jnp.float32, MAX_LEN // 2, "resident", "resident"),
+    (jnp.float32, MAX_LEN, "stream", "stream"),
+], ids=["bf16-resident", "bf16-stream", "f32-512-resident", "f32-stream"])
+def test_paged_attention_gpt2_small_serving(one_chip, dtype, max_len,
+                                            scheme, plan):
+    from kungfu_tpu.ops.paged_attn import paged_attention, paged_plan
+
+    max_blocks = max_len // BLOCK_TOKENS
+    pool_blocks = SERVE_BATCH * max_blocks + 1
+    # what `paged_plan` offers at this shape must be what compiles
+    assert paged_plan(max_blocks, BLOCK_TOKENS, H, D,
+                      dtype=dtype)["scheme"] == plan
+
+    def sds(shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((LAYERS * pool_blocks, BLOCK_TOKENS, H, D))
+    compiled = _compile(
+        lambda q, k, v, tables, lengths: paged_attention(
+            q, k, v, tables, lengths, block_base=pool_blocks,
+            scheme=scheme, interpret=False),
+        sds((SERVE_BATCH, H, D)), pool, pool,
+        sds((SERVE_BATCH, max_blocks), jnp.int32),
+        sds((SERVE_BATCH,), jnp.int32))
+    assert _kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("mixer", ["ring", "ulysses"])
+def test_sequence_parallel_flash_4chips(mesh4, monkeypatch, mixer):
+    """4096 positions over a 4-chip ring, 1024 a chip. The mixers leave
+    `interpret` to `jax.default_backend()`, which is the CPU here, so
+    the test answers for the chip the program is compiled for."""
+    from kungfu_tpu.parallel import sequence
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ring = Mesh(mesh4.devices.reshape(4), ("seq",))
+    attend = {"ring": sequence.ring_attention,
+              "ulysses": sequence.ulysses_attention}[mixer]
+
+    def local(q, k, v):
+        return attend(q, k, v, "seq", causal=True, use_flash=True)
+
+    mapped = jax.shard_map(local, mesh=ring,
+                           in_specs=(P(None, "seq"),) * 3,
+                           out_specs=P(None, "seq"), check_vma=False)
+
+    def loss(q, k, v):
+        return mapped(q, k, v).astype(jnp.float32).sum()
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        *_qkv(NamedSharding(ring, P(None, "seq")), b=1, t=4 * T))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("collective-permute" if mixer == "ring"
+            else "all-to-all") in text
+
+
+def test_vocab_sharded_ce_4chips(mesh4):
+    from kungfu_tpu.parallel.vocab_ce import vocab_sharded_fused_ce
+
+    def loss(x, w, b, t):
+        return vocab_sharded_fused_ce(x, w, b, t, mesh=mesh4)
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh4, P(*spec)))
+
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)),
+        sds((B * T, HIDDEN), jnp.bfloat16, "data"),
+        # the head arrives replicated, as the rules tables leave it
+        # (50257 divides no model axis): the op pads and shards it
+        sds((HIDDEN, VOCAB), jnp.float32), sds((VOCAB,), jnp.float32),
+        sds((B * T,), jnp.int32, "data"))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text

@@ -19,7 +19,6 @@ jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import jax.numpy as jnp
-import kungfu_tpu._jax_compat  # noqa: F401  (jax.shard_map on 0.4.x)
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
