@@ -1,10 +1,14 @@
 """kftrace overhead benchmark: what does KF_TRACE=1 cost a step?
 
-Three measurements, least to most integrated:
+Four measurements, least to most integrated:
 
 1. **per-event cost** — µs per `span()` enter/exit and per `event()`
    against a full ring (the steady state: every emit also pays the
    drop accounting);
+1b. **profiler bridge** — a span site with JAX loaded: ns a call with
+   ``KF_TRACE`` off and no profiler session (what every untraced run
+   pays), µs a call while a `jax.profiler` session runs, ring off and
+   ring on (host latencies: a CPU reading is what they are);
 2. **instrumented step wall** — a jitted train step (GPT-2-small
    scaled config by default; `--model slp` for the elastic harness's
    trainer) run in a loop carrying EXACTLY the per-step
@@ -26,7 +30,18 @@ import argparse
 import json
 import statistics
 import sys
+import tempfile
 import time
+
+
+def _span_site_us(iters: int) -> float:
+    from kungfu_tpu import trace
+
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        with trace.span("bench.span", cat="bench"):
+            pass
+    return (time.perf_counter() - t0) / iters * 1e6
 
 
 def _per_event_cost(iters: int = 20000) -> dict:
@@ -37,11 +52,7 @@ def _per_event_cost(iters: int = 20000) -> dict:
     # pre-fill: steady state is a full ring (drop path active)
     for _ in range(4096):
         trace.event("warm")
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        with trace.span("bench.span", cat="bench"):
-            pass
-    span_us = (time.perf_counter() - t0) / iters * 1e6
+    span_us = _span_site_us(iters)
     t0 = time.perf_counter()
     for _ in range(iters):
         trace.event("bench.event", cat="bench")
@@ -49,15 +60,39 @@ def _per_event_cost(iters: int = 20000) -> dict:
     # disabled path: the cost every un-traced run pays per site
     trace._reset_for_tests()
     trace.configure(enabled_=False)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        with trace.span("bench.span", cat="bench"):
-            pass
-    disabled_ns = (time.perf_counter() - t0) / iters * 1e9
+    disabled_ns = _span_site_us(iters) * 1e3
     trace._reset_for_tests()
     return {"span_us": round(span_us, 3),
             "event_us": round(event_us, 3),
             "disabled_span_ns": round(disabled_ns, 1)}
+
+
+def _bridge_cost(iters: int = 20000) -> dict:
+    """A span site once JAX is loaded: without a profiler session
+    (`KF_TRACE` off: the one check more every untraced run pays), and
+    inside one (`jax.profiler.start_trace`, as the benchmark's
+    `--trace 1` starts it), ring off and ring on. Few spans in the
+    session: its trace is kept in memory until it stops."""
+    import jax
+
+    from kungfu_tpu import trace
+
+    trace._reset_for_tests()
+    trace.configure(enabled_=False)
+    off_ns = _span_site_us(iters) * 1e3
+    with tempfile.TemporaryDirectory() as log_dir:
+        jax.profiler.start_trace(log_dir)
+        try:
+            session_us = _span_site_us(iters // 10)
+            trace.configure(enabled_=True, capacity=4096)
+            trace.set_context(rank=0, version=0, step=0)
+            both_us = _span_site_us(iters // 10)
+        finally:
+            jax.profiler.stop_trace()
+    trace._reset_for_tests()
+    return {"disabled_span_jax_loaded_ns": round(off_ns, 1),
+            "session_span_us": round(session_us, 3),
+            "session_span_traced_us": round(both_us, 3)}
 
 
 def _step_wall(model: str, iters: int, warmup: int,
@@ -131,7 +166,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
 
-    per_event = _per_event_cost()
+    per_event = {**_per_event_cost(), **_bridge_cost()}
     off_ms = _step_wall(args.model, args.iters, args.warmup,
                         traced=False)
     on_ms = _step_wall(args.model, args.iters, args.warmup,
@@ -168,6 +203,11 @@ def main(argv=None) -> int:
         print(f"per-event: span {per_event['span_us']} µs, event "
               f"{per_event['event_us']} µs, disabled "
               f"{per_event['disabled_span_ns']} ns")
+        print(f"span site, JAX loaded: KF_TRACE off, no session "
+              f"{per_event['disabled_span_jax_loaded_ns']} ns; in a "
+              f"profiler session {per_event['session_span_us']} µs, "
+              f"with the ring on too "
+              f"{per_event['session_span_traced_us']} µs")
         print(f"step wall ({args.model}): {off_ms:.3f} ms untraced -> "
               f"{on_ms:.3f} ms traced ({overhead_pct:+.2f}%)")
         print(f"implied flagship fraction: {implied_pct:.4f}% of a "

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..trace.scopes import GRAD_SYNC
+
 
 def all_reduce(tree, axis_name: str = "data"):
     """Sum each leaf over the mesh axis (reference KungfuAllReduce, sum)."""
@@ -24,8 +26,12 @@ def all_reduce(tree, axis_name: str = "data"):
 
 
 def all_reduce_mean(tree, axis_name: str = "data"):
-    """Mean each leaf over the mesh axis — the S-SGD gradient op."""
-    return jax.tree_util.tree_map(lambda x: lax.pmean(x, axis_name), tree)
+    """Mean each leaf over the mesh axis — the S-SGD gradient op, and
+    what a data-parallel step does to its model state and its loss.
+    Under the program's scope `kf.grad_sync` (trace/scopes.py)."""
+    with jax.named_scope(GRAD_SYNC):
+        return jax.tree_util.tree_map(
+            lambda x: lax.pmean(x, axis_name), tree)
 
 
 def group_all_reduce(tensors: Sequence, axis_name: str = "data") -> List:

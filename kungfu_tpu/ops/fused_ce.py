@@ -54,6 +54,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..trace.scopes import FUSED_CE
+
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 # bias for padded vocab columns: exp(x - m) underflows to exactly 0 for
 # any finite row max m, and the value survives a bf16 round-trip
@@ -455,6 +457,7 @@ def _fused_ce_recompute(x, w, b, t, bn, bv, interpret):
     return loss
 
 
+@jax.named_scope(FUSED_CE)
 def _fcr_fwd(x, w, b, t, bn, bv, interpret):
     _, lse, tl = _fwd_pallas(x, w, b, t, bn, bv, interpret,
                              residual=False)
@@ -464,6 +467,7 @@ def _fcr_fwd(x, w, b, t, bn, bv, interpret):
     return loss, (x, w, b, lse, t, num_valid)
 
 
+@jax.named_scope(FUSED_CE)
 def _fcr_bwd(bn, bv, interpret, res, g):
     x, w, b, lse, t, num_valid = res
     scale = (g / num_valid).astype(jnp.float32)[None, None]
@@ -482,6 +486,7 @@ def _fused_ce_padded(x, w, b, t, bn, bv, interpret):
     return loss
 
 
+@jax.named_scope(FUSED_CE)
 def _fce_fwd(x, w, b, t, bn, bv, interpret):
     logits, lse, tl = _fwd_pallas(x, w, b, t, bn, bv, interpret,
                                   residual=True)
@@ -491,6 +496,7 @@ def _fce_fwd(x, w, b, t, bn, bv, interpret):
     return loss, (x, w, logits, lse, t, num_valid)
 
 
+@jax.named_scope(FUSED_CE)
 def _fce_bwd(bn, bv, interpret, res, g):
     x, w, logits, lse, t, num_valid = res
     scale = (g / num_valid).astype(jnp.float32)[None, None]
@@ -541,20 +547,24 @@ def fused_cross_entropy(hidden, kernel, bias, targets,
     v = kernel.shape[1]
     vmem = _fwd_vmem_bytes if residual else _recompute_vmem_bytes
     blocks = _pick_blocks(n, h, v, vmem) if h % 128 == 0 else None
-    if blocks is None:
-        return reference_cross_entropy(hidden, kernel, bias, targets)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    bn, bv = blocks
-    n_pad, v_pad = _round_up(n, bn), _round_up(v, bv)
-    # ordinary jnp pads/casts: their transposes (slice, cast-back) give
-    # callers unpadded gradients automatically
-    x = jnp.pad(hidden.astype(jnp.bfloat16), ((0, n_pad - n), (0, 0)))
-    w = jnp.pad(kernel.astype(jnp.bfloat16), ((0, 0), (0, v_pad - v)))
-    b = jnp.pad(bias.astype(jnp.float32), (0, v_pad - v),
-                constant_values=_PAD_BIAS)[None, :]
-    t = jnp.pad(lax.stop_gradient(targets).astype(jnp.int32),
-                (0, n_pad - n), constant_values=-1)[:, None]
+    # the program's own scope (trace/scopes.py) over everything outside
+    # the custom_vjp halves, which open it themselves: a profiler trace
+    # finds the head + CE, forward and backward, under one name
+    with jax.named_scope(FUSED_CE):
+        if blocks is None:
+            return reference_cross_entropy(hidden, kernel, bias, targets)
+        bn, bv = blocks
+        n_pad, v_pad = _round_up(n, bn), _round_up(v, bv)
+        # ordinary jnp pads/casts: their transposes (slice, cast-back)
+        # give callers unpadded gradients automatically
+        x = jnp.pad(hidden.astype(jnp.bfloat16), ((0, n_pad - n), (0, 0)))
+        w = jnp.pad(kernel.astype(jnp.bfloat16), ((0, 0), (0, v_pad - v)))
+        b = jnp.pad(bias.astype(jnp.float32), (0, v_pad - v),
+                    constant_values=_PAD_BIAS)[None, :]
+        t = jnp.pad(lax.stop_gradient(targets).astype(jnp.int32),
+                    (0, n_pad - n), constant_values=-1)[:, None]
     if residual:
         return _fused_ce_padded(x, w, b, t, bn, bv, interpret)
     return _fused_ce_recompute(x, w, b, t, bn, bv, interpret)

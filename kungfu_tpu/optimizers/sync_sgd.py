@@ -15,6 +15,7 @@ import optax
 from jax import lax
 
 from ..ops.collective import all_reduce_mean, bucket_schedule
+from ..trace.scopes import GRAD_SYNC
 
 
 def sync_sgd(
@@ -38,6 +39,7 @@ def sync_sgd(
     return optax.GradientTransformation(init, update)
 
 
+@jax.named_scope(GRAD_SYNC)  # the concatenates and slices too
 def bucketed_all_reduce_mean(grads, axis_name: str = "data",
                              bucket_bytes: int = 1 << 20):
     """pmean of a gradient pytree as fixed-byte reverse-order buckets.

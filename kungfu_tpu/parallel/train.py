@@ -18,6 +18,8 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh
 
+from ..ops.collective import all_reduce_mean
+from ..trace.scopes import OPT_UPDATE
 from .rules import replicated, stacked
 
 
@@ -27,6 +29,15 @@ def _squeeze(t):
 
 def _unsqueeze(t):
     return jax.tree_util.tree_map(lambda x: x[None], t)
+
+
+def _apply_update(tx, grads, opt_state, params):
+    """The optimizer's part of every step below, under the program's
+    own scope (`kf.opt_update`, trace/scopes.py) so a device trace
+    finds it whatever `tx` is."""
+    with jax.named_scope(OPT_UPDATE):
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
 
 
 def build_train_step_with_state(
@@ -56,16 +67,14 @@ def build_train_step_with_state(
         opt_state = _squeeze(opt_s)
         (loss, new_mstate), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, mstate, batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = _apply_update(tx, grads, opt_state, params)
         if sync_state:
-            new_mstate = jax.tree_util.tree_map(
-                lambda x: lax.pmean(x, axis_name), new_mstate)
+            new_mstate = all_reduce_mean(new_mstate, axis_name)
         return (
             _unsqueeze(params),
             _unsqueeze(new_mstate),
             _unsqueeze(opt_state),
-            lax.pmean(loss, axis_name),
+            all_reduce_mean(loss, axis_name),
         )
 
     mapped = shard_map(
@@ -138,8 +147,7 @@ def build_gspmd_train_step(
     def step(params, opt_state, batch):
         out, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(
             params, batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = _apply_update(tx, grads, opt_state, params)
         if has_aux:
             loss, metrics = out
             return params, opt_state, loss, metrics
@@ -178,11 +186,8 @@ def build_dp_replicated_train_step(
 
     def device_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        grads = jax.tree_util.tree_map(
-            lambda g: lax.pmean(g, axis_name), grads)
-        loss = lax.pmean(loss, axis_name)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        grads, loss = all_reduce_mean((grads, loss), axis_name)
+        params, opt_state = _apply_update(tx, grads, opt_state, params)
         return params, opt_state, loss
 
     mapped = shard_map(

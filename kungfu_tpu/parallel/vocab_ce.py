@@ -54,6 +54,7 @@ from ..ops.fused_ce import (_PAD_BIAS, _dw_pallas, _dx_pallas,
                             _fwd_pallas, _fwd_vmem_bytes, _pick_blocks,
                             _recompute_vmem_bytes, _residual_d_pallas,
                             _round_up, reference_cross_entropy)
+from ..trace.scopes import FUSED_CE
 
 
 class _VSConfig(NamedTuple):
@@ -105,6 +106,7 @@ def _vs_ce(cfg: _VSConfig, x, w, b, t):
     return loss
 
 
+@jax.named_scope(FUSED_CE)
 def _vs_fwd(cfg: _VSConfig, x, w, b, t):
     da, ma = cfg.data_axis, cfg.model_axis
 
@@ -139,6 +141,7 @@ def _vs_fwd(cfg: _VSConfig, x, w, b, t):
     return loss, (x, w, b, t, lse_g, num_valid, logits)
 
 
+@jax.named_scope(FUSED_CE)
 def _vs_bwd(cfg: _VSConfig, res, g):
     import numpy as np
 
@@ -228,7 +231,8 @@ def vocab_sharded_fused_ce(hidden, kernel, bias, targets, *,
     if h % 128 == 0 and n % d_data == 0:
         blocks = _pick_blocks(n // d_data, h, v_loc, vmem)
     if blocks is None:
-        return reference_cross_entropy(hidden, kernel, bias, targets)
+        with jax.named_scope(FUSED_CE):
+            return reference_cross_entropy(hidden, kernel, bias, targets)
     if interpret is None:
         interpret = mesh.devices.flat[0].platform != "tpu"
     bn, bv = blocks
@@ -242,10 +246,11 @@ def vocab_sharded_fused_ce(hidden, kernel, bias, targets, *,
     # differentiable pads/casts OUTSIDE the custom_vjp: JAX transposes
     # them to slice/cast-back, so callers see unpadded gradients in
     # their own dtypes (same convention as fused_cross_entropy)
-    x = hidden.astype(jnp.bfloat16)
-    w = jnp.pad(kernel.astype(jnp.bfloat16),
-                ((0, 0), (0, v_padg - v)))
-    b = jnp.pad(bias.astype(jnp.float32), (0, v_padg - v),
-                constant_values=_PAD_BIAS)
-    t = lax.stop_gradient(targets).astype(jnp.int32)
+    with jax.named_scope(FUSED_CE):  # the halves open it themselves
+        x = hidden.astype(jnp.bfloat16)
+        w = jnp.pad(kernel.astype(jnp.bfloat16),
+                    ((0, 0), (0, v_padg - v)))
+        b = jnp.pad(bias.astype(jnp.float32), (0, v_padg - v),
+                    constant_values=_PAD_BIAS)
+        t = lax.stop_gradient(targets).astype(jnp.int32)
     return _vs_ce(cfg, x, w, b, t)

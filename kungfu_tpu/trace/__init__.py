@@ -5,7 +5,10 @@ The process-facing API of the observability layer
 helpers — `span` / `event` / `counter` / `set_context` — which are
 no-ops until ``KF_TRACE=1`` (the same latch-once switch that enables
 the native scope counters), so the disabled cost on a hot path is one
-module-global check:
+module-global check. `span` has a second listener: while a
+`jax.profiler` session runs it also writes ``kf.<name>`` into that
+session's trace, on the clock the device operations are on
+(`scopes.py` holds the names the program puts into a profiler trace):
 
     from kungfu_tpu import trace
     with trace.span("step.compute", cat="step"):
@@ -20,7 +23,8 @@ SIGTERM — and `install_from_peer` additionally binds the SPMD context
 the explicit hook failure paths call (recovery entry, chaos faults)
 before the world changes.
 
-Submodules: `recorder` (ring/span mechanics), `collect` (shipper +
+Submodules: `recorder` (ring/span mechanics), `scopes` (the device
+scope names and the host span prefix: plain constants), `collect` (shipper +
 config-server store), `export` (Chrome/Perfetto trace JSON, validation,
 timeline summaries), `metrics` (the /metrics registry).
 """
@@ -30,10 +34,11 @@ from __future__ import annotations
 import atexit
 import os
 import signal as _signal
+import sys
 import threading
 from typing import Optional
 
-from .recorder import (DEFAULT_RING, NOOP_SPAN, TraceRecorder)
+from .recorder import (DEFAULT_RING, NOOP_SPAN, TraceRecorder, _Span)
 
 __all__ = [
     "enabled", "configure", "recorder", "span", "event", "counter",
@@ -94,10 +99,39 @@ def recorder() -> TraceRecorder:
 
 # -- hot-path helpers (no-ops unless enabled) ---------------------------------
 
+#: `jax.profiler.TraceAnnotation`, latched the first time a span site
+#: finds JAX loaded (a class reference: racing threads store the same)
+_annotation = None
+
+
+def _session_annotation():
+    """`jax.profiler.TraceAnnotation` while a profiler session is
+    running (anyone's: `jax.profiler.start_trace`, the profiler
+    server), else None. JAX is looked up, never imported: the kfrun
+    watcher and the control plane stay JAX-free. The check is the
+    profiler's own atomic flag."""
+    global _annotation
+    cls = _annotation
+    if cls is None:
+        cls = getattr(sys.modules.get("jax.profiler"),
+                      "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _annotation = cls
+    return cls if cls.is_enabled() else None
+
+
 def span(name: str, cat: str = "", **args):
-    if not enabled():
+    """A span: one ring event at close when ``KF_TRACE`` is on, and,
+    while a `jax.profiler` session runs, the same interval in that
+    session's trace as ``kf.<name>`` on the calling thread's line of
+    ``/host:CPU`` (docs/observability.md "Device scopes and the
+    profiler"). Neither listening: the shared no-op."""
+    annotate = _session_annotation()
+    rec = recorder() if enabled() else None
+    if rec is None and annotate is None:
         return NOOP_SPAN
-    return recorder().span(name, cat, **args)
+    return _Span(rec, name, cat, args or None, annotate)
 
 
 def event(name: str, cat: str = "", **args) -> None:
@@ -203,9 +237,11 @@ def install_from_peer(peer) -> Optional[TraceRecorder]:
 def _reset_for_tests() -> None:
     """Forget all process state (tests only)."""
     global _enabled, _rec, _installed, _shipper, _prev_sigterm
+    global _annotation
     with _mu:
         if _shipper is not None:
             _shipper.stop(flush=False)
+        _annotation = None
         _enabled = None
         _rec = None
         _installed = False

@@ -41,6 +41,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from .scopes import HOST_SPAN_PREFIX
+
 #: ring capacity (events). ~300 B/event -> a few MB ceiling per process.
 DEFAULT_RING = 16384
 
@@ -88,19 +90,34 @@ class _Span:
 
     The SPMD context (rank/version/step) is captured at OPEN: a span
     that straddles an epoch switch belongs to the epoch that opened
-    it (the satellite semantics tests/test_kftrace.py pins)."""
+    it (the satellite semantics tests/test_kftrace.py pins).
 
-    __slots__ = ("_rec", "name", "cat", "args", "_t0", "_ctx")
+    With an `annotate` class (`jax.profiler.TraceAnnotation`, handed in
+    by `trace.span` while a profiler session runs) the span is also
+    written into that session, on the calling thread, as
+    ``kf.<name>`` with the context's step and version: the same
+    interval on the clock the device operations are on. `rec` is None
+    where only the session listens (``KF_TRACE`` off)."""
 
-    def __init__(self, rec: "TraceRecorder", name: str, cat: str,
-                 args: Optional[Dict]):
+    __slots__ = ("_rec", "name", "cat", "args", "_t0", "_ctx",
+                 "_annotate", "_ann")
+
+    def __init__(self, rec: Optional["TraceRecorder"], name: str,
+                 cat: str, args: Optional[Dict], annotate=None):
         self._rec = rec
         self.name = name
         self.cat = cat
         self.args = args
+        self._annotate = annotate
 
     def __enter__(self):
-        self._ctx = dict(self._rec._ctx)
+        ctx = self._ctx = (dict(self._rec._ctx) if self._rec is not None
+                           else {})
+        if self._annotate is not None:
+            self._ann = self._annotate(
+                HOST_SPAN_PREFIX + self.name,
+                **{k: ctx[k] for k in ("step", "version") if k in ctx})
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -114,10 +131,13 @@ class _Span:
     def __exit__(self, *exc):
         rec = self._rec
         t1 = time.perf_counter()
-        rec._emit_raw(self.name, "X", self.cat,
-                      rec._to_us(self._t0),
-                      int((t1 - self._t0) * 1e6),
-                      self._ctx, self.args)
+        if self._annotate is not None:
+            self._ann.__exit__(*exc)
+        if rec is not None:
+            rec._emit_raw(self.name, "X", self.cat,
+                          rec._to_us(self._t0),
+                          int((t1 - self._t0) * 1e6),
+                          self._ctx, self.args)
         return False
 
 

@@ -1,0 +1,31 @@
+"""The names this program puts into a profiler trace: one table.
+
+Device side: `jax.named_scope`s the compiled step opens round a layer
+boundary, so a trace reader selects operations by a name the program
+owns and not by whatever scope path JAX and flax happen to give them.
+Host side: the prefix under which a kftrace span appears in a running
+`jax.profiler` session. docs/observability.md "Device scopes and the
+profiler" holds the same table and the rule for adding a name.
+
+Plain constants: importing this module imports nothing.
+"""
+
+#: `tx.update` + `optax.apply_updates` in every builder of
+#: `parallel/train.py`: the optimizer's arithmetic, whatever `tx` is.
+OPT_UPDATE = "kf.opt_update"
+
+#: everything a data-parallel step does to agree across workers:
+#: `ops.collective.all_reduce_mean`, `bucketed_all_reduce_mean`
+#: (concatenate, pmean, slice), the model-state and loss pmeans. Under
+#: `sync_sgd` it nests inside OPT_UPDATE: a reader of the optimizer's
+#: time takes OPT_UPDATE without GRAD_SYNC.
+GRAD_SYNC = "kf.grad_sync"
+
+#: head matmul + cross-entropy of `ops/fused_ce.py`, forward and
+#: backward: the kernels, the pads and casts round them, and the
+#: residual scheme's backward head matmuls.
+FUSED_CE = "kf.fused_ce"
+
+#: a kftrace span `name` shows in a profiler session as
+#: HOST_SPAN_PREFIX + name, on the calling thread of `/host:CPU`.
+HOST_SPAN_PREFIX = "kf."

@@ -15,9 +15,15 @@ The observability layer's unit surface (docs/observability.md):
 - the recovery decomposition from structured events;
 - chaos faults emit their structured event AND the victim's flight
   dump BEFORE the destructive action (subprocess proof);
-- the metrics registry renders consistent Prometheus text.
+- the metrics registry renders consistent Prometheus text;
+- the profiler bridge: while a `jax.profiler` session runs, a span is
+  also in that session's trace as `kf.<name>`, on the host plane,
+  inside its caller's interval, whether or not the ring records; and
+  a process that only imports `kungfu_tpu.trace` never loads JAX.
 """
 
+import glob
+import gzip
 import json
 import os
 import subprocess
@@ -345,6 +351,84 @@ def test_chaos_fault_emits_event_and_flight_dump_before_death(tmp_path):
     assert "step.marker" in names  # the pre-fault ring rode along
     ev = next(e for e in events if e["name"] == "chaos.crash_worker")
     assert ev["args"]["signal"] == "EXIT" and ev["step"] == 3
+
+
+# -- the profiler bridge --------------------------------------------------------
+
+def _host_events(log_dir):
+    """Complete events of the `/host:CPU` plane in the trace the
+    profiler wrote under `log_dir`, as `benchmark/trace_reduce.py`
+    reads them."""
+    (path,) = glob.glob(os.path.join(
+        str(log_dir), "plugins", "profile", "*", "*.trace.json.gz"))
+    with gzip.open(path, "rt") as f:
+        events = json.load(f)["traceEvents"]
+    (host,) = [e["pid"] for e in events if e.get("ph") == "M"
+               and e["name"] == "process_name"
+               and e["args"]["name"] == "/host:CPU"]
+    return [e for e in events if e.get("ph") == "X" and e["pid"] == host]
+
+
+@pytest.mark.parametrize("kf_trace", [False, True],
+                         ids=["KF_TRACE-off", "KF_TRACE-on"])
+def test_span_is_in_a_running_profiler_session(tmp_path, kf_trace):
+    import jax
+
+    rec = trace.configure(enabled_=kf_trace)
+    trace.set_context(rank=0, version=3, step=7)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("outer"):
+            with trace.span("step.hook", cat="step", foo=1) as sp:
+                assert sp.set(bar=2) is sp
+                time.sleep(0.002)
+            # not bridged: the ring's instant events and counters
+            trace.event("resize.adopted", cat="elastic")
+            trace.counter("queue", {"depth": 1})
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    (outer,) = [e for e in events if e["name"] == "outer"]
+    (hook,) = [e for e in events if e["name"] == "kf.step.hook"]
+    assert not [e for e in events if e["name"].startswith("kf.")
+                and e is not hook]
+    # the calling thread's line, inside the caller's interval: one clock
+    assert hook["tid"] == outer["tid"]
+    assert outer["ts"] <= hook["ts"]
+    assert hook["ts"] + hook["dur"] <= outer["ts"] + outer["dur"]
+    assert hook["dur"] >= 1500  # microseconds; slept 2 ms
+    if kf_trace:
+        assert hook["args"] == {"step": "7", "version": "3"}
+        (ev,) = [e for e in rec.snapshot() if e["ph"] == "X"]
+        assert ev["name"] == "step.hook" and ev["cat"] == "step"
+        assert ev["step"] == 7 and ev["version"] == 3
+        assert ev["args"] == {"foo": 1, "bar": 2}
+        assert abs(ev["dur"] - hook["dur"]) < 1000
+    else:
+        assert "args" not in hook   # no context without the ring
+        assert trace._rec is None   # and no recorder because of it
+    # the session over, a site costs what it did before
+    if not kf_trace:
+        assert trace.span("step.hook") is trace.NOOP_SPAN
+
+
+def test_importing_trace_and_opening_a_span_does_not_load_jax():
+    code = textwrap.dedent("""
+        import sys
+        from kungfu_tpu import trace
+        with trace.span("step.hook") as sp:
+            sp.set(a=1)
+        trace.configure(enabled_=True)
+        with trace.span("step.hook") as sp:
+            sp.set(a=1)
+        assert len(trace.recorder().snapshot()) == 1
+        loaded = sorted(m for m in sys.modules
+                        if m == "jax" or m.startswith(("jax.", "jaxlib")))
+        assert not loaded, loaded
+    """)
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
 
 
 # -- metrics registry ---------------------------------------------------------
