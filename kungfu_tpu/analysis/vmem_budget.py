@@ -32,7 +32,10 @@ NAME = "vmem-budget"
 FLASH_GRID = [
     # (t, d, dtype_name, causal, window)
     (1024, 64, "float32", False, None),
-    (1024, 64, "bfloat16", True, None),
+    (1024, 64, "bfloat16", True, None),    # the GPT cells: head kernels
+    (1024, 256, "bfloat16", True, None),   # head kernels at a big head dim
+    (2048, 64, "bfloat16", True, None),    # head kernels' widest step
+    (2048, 64, "float32", True, None),     # past their budget: the loops
     (2048, 128, "bfloat16", True, None),
     (4096, 64, "bfloat16", True, None),
     (4096, 256, "float32", True, None),
@@ -121,7 +124,10 @@ def check_flash(grid: Sequence = FLASH_GRID,
         isz = dtype.itemsize
         for which in ("fwd", "dq", "dkv"):
             scheme = plan[which]["scheme"]
-            if scheme == "resident":
+            if scheme == "head":
+                est = flash._head_vmem(
+                    "fwd" if which == "fwd" else "bwd", bq, d, isz, t)
+            elif scheme == "resident":
                 est = flash._RES_VMEM[which](bq, bk, d, isz, t)
             elif which == "dkv":
                 est = stream[which](bq, bk, d, isz, t)

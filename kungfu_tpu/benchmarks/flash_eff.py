@@ -34,6 +34,7 @@ B * max_blocks * block_tokens, is what moves.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import time
 
@@ -42,8 +43,12 @@ def measure_flash_efficiency(batch: int = 8, seq: int = 1024,
                              heads: int = 12, head_dim: int = 64,
                              causal: bool = True, window: int | None = None,
                              dtype: str = "bfloat16", iters: int = 20,
-                             warmup: int = 3):
-    """Achieved flash-kernel FLOP/s at one attention shape.
+                             warmup: int = 3,
+                             block_q: int | None = None,
+                             block_k: int | None = None):
+    """Achieved flash-kernel FLOP/s at one attention shape
+    (`block_q` / `block_k`: explicit tiles, for a sweep; None = the
+    auto pick the models run).
 
     Returns a meta dict: fwd_ms / fwdbwd_ms (per call), achieved
     TFLOP/s for both, `efficiency_vs_bf16_peak` (fwd+bwd — the number
@@ -65,12 +70,13 @@ def measure_flash_efficiency(batch: int = 8, seq: int = 1024,
     q, k, v = (jax.random.normal(kk, (batch, seq, heads, head_dim), dt)
                for kk in ks)
 
-    fwd = jax.jit(lambda q, k, v: flash_attention(
-        q, k, v, causal=causal, window=window))
+    attend = functools.partial(flash_attention, causal=causal,
+                               window=window, block_q=block_q,
+                               block_k=block_k)
+    fwd = jax.jit(attend)
     grad = jax.jit(jax.grad(
-        lambda q, k, v: flash_attention(
-            q, k, v, causal=causal, window=window)
-        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+        lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))
 
     def timed(fn):
         """Slope-timed per-call seconds: (t(k_hi) - t(k_lo)) over the
@@ -94,7 +100,10 @@ def measure_flash_efficiency(batch: int = 8, seq: int = 1024,
         run(k_lo)  # settle caches/dispatch before the measured pair
         t_lo = min(run(k_lo) for _ in range(2))
         t_hi = min(run(k_hi) for _ in range(2))
-        return max((t_hi - t_lo) / (k_hi - k_lo), 1e-9)
+        slope = (t_hi - t_lo) / (k_hi - k_lo)
+        # a loaded host can time the short loop longer than the long
+        # one (CPU smoke runs of a few calls): the mean call then
+        return slope if slope > 0 else t_hi / k_hi
 
     t_fwd = timed(fwd)
     t_both = timed(grad)
@@ -116,13 +125,16 @@ def measure_flash_efficiency(batch: int = 8, seq: int = 1024,
         "fwd_tflops": round(f_fwd / t_fwd / 1e12, 3),
         "fwdbwd_tflops": round(f_both / t_both / 1e12, 3),
         # fwd+bwd is what a train step pays, so it is THE efficiency
-        # number; round-5 profiling put it at ~0.20 on the flagship
-        # shape, round 6's block-skip/resident target is >= 0.35
+        # number. On a v5e at the defaults, 2026-10-01: 0.096 before
+        # PR 25's head kernels, 0.164 with them (the [B*H, T, D]
+        # transposes round the kernels included); d = 64 halves the
+        # MXU's rate and recomputed matmuls are not counted
         "efficiency_vs_bf16_peak": (
             round(f_both / t_both / peak, 4) if peak else None),
         "device_kind": jax.devices()[0].device_kind,
         "plan": flash_plan(seq, head_dim, dtype=dt, causal=causal,
-                           window=window),
+                           window=window, block_q=block_q,
+                           block_k=block_k),
     }
     return meta
 
@@ -227,6 +239,9 @@ def main(argv=None) -> int:
     ap.add_argument("--dtype", default="bfloat16",
                     choices=("bfloat16", "float32"))
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--block-q", type=int, default=None,
+                    help="explicit flash tiles (default: the auto pick)")
+    ap.add_argument("--block-k", type=int, default=None)
     ap.add_argument("--paged", action="store_true",
                     help="measure the paged-attention decode kernel's "
                          "achieved bandwidth instead")
@@ -249,7 +264,8 @@ def main(argv=None) -> int:
     meta = measure_flash_efficiency(
         args.batch, args.seq, args.heads, args.head_dim,
         causal=not args.no_causal, window=args.window,
-        dtype=args.dtype, iters=args.iters)
+        dtype=args.dtype, iters=args.iters, block_q=args.block_q,
+        block_k=args.block_k)
     print(json.dumps({
         "metric": "flash_kernel_efficiency_vs_bf16_peak",
         "value": meta["efficiency_vs_bf16_peak"],

@@ -6,30 +6,61 @@ blocks through VMEM with online-softmax accumulation so HBM traffic is
 O(T) per query block (FlashAttention, Dao et al. 2022 — on TPU the
 win is HBM bandwidth, the usual bottleneck, not SRAM reuse).
 
-Two execution schemes per kernel (fwd / dq / dkv), selected by a
+Three execution schemes per kernel (fwd / dq / dkv; the head scheme
+computes dq, dk and dv in one), selected by a
 VMEM-budget estimate in the style of `ops/fused_ce.py:_pick_blocks`
 (`flash_plan` shows the decision for a shape):
 
-- **resident** (preferred whenever the estimate fits `_VMEM_BUDGET`):
-  grid (B*H, outer-block); the streamed side (K/V for fwd/dq, Q/dO for
-  dkv) is held in VMEM at FULL length per head and the kernel loops
-  over its blocks with a `lax.fori_loop` whose bounds come from
+- **head** (causal, no window, a whole head inside `_VMEM_BUDGET` and
+  at most `_HEAD_MAX_CHUNKS` chunks — every causal T <= 2048 at
+  d = 64, the GPT cells among them): grid (B*H,); one program holds
+  the head and walks it in `_HEAD_CHUNK`-row chunks with the causal
+  schedule unrolled at trace time. What lies under a chunk's diagonal
+  is ONE wide unmasked block step, only the diagonal's square builds
+  the mask, nothing above it is touched: 62.5% of the square at
+  T = 1024, 56% at 2048, in 2*nb - 1 steps a kernel. The backward is
+  ONE kernel: with queries and keys both in VMEM, one pass over the
+  scores feeds dq, dk and dv (five matmuls a step, not 3 + 4).
+- **resident** (everything else whose estimate fits the budget): grid
+  (B*H, outer-block); the streamed side (K/V for fwd/dq, Q/dO for dkv)
+  is held in VMEM at FULL length per head and the kernel loops over
+  its blocks with a `lax.fori_loop` whose bounds come from
   `_k_span`/`_q_span` — for causal and windowed attention the trip
   count genuinely shrinks per program (causal visits the lower
   triangle only, ~half the blocks; windows visit O(window) blocks),
   and no fully-masked block is ever visited, in ALL of fwd, dq and
-  dkv. As a bonus the resident side is DMA'd once per head instead of
-  once per outer block (the streaming grid re-fetches every K/V block
-  nq times).
+  dkv. The resident side is DMA'd once per head instead of once per
+  outer block (the streaming grid re-fetches every K/V block nq
+  times).
 - **stream** (fallback past the VMEM budget — long T, big D): the
   round-5 grid (B*H, outer, inner) with VMEM-scratch-carried online
   state. Causal masking skips compute via `pl.when`; sliding windows
   narrow the inner grid dim itself (`_window_span`, affine
   front-padded index maps).
 
-Auto block sizes are budget-driven too: the largest measured-fastest
-power-of-two tile that keeps the worst kernel's VMEM estimate under
-budget (big head dims shrink blocks instead of failing to compile).
+All three run the same block steps (`_fwd_step`, `_dq_step`,
+`_dkv_step`). Their matmuls take their operands in the input's dtype
+when that is bf16 (`_operand_dtype`) and accumulate in f32; scores,
+max, exp, sums, lse and delta are f32 whatever the input.
+
+What the chip said of the loops (one TPU v5e, 2026-10-01, PR 25; B*H =
+96, T = 1024, d = 64, bf16, causal, fwd + bwd of the isolated kernel,
+`benchmarks/flash_eff.py`'s timing): one 1024 x 1024 block 2.06 ms,
+resident loops at 512 x 512 1.93, 512 x 256 2.19, 256 x 256 2.49,
+128 x 128 4.10 — each block step of a loop costs 0.5-1.2 us beside
+its matmuls (online-softmax rescale, MXU fill and drain, the [block,
+d] accumulate), so smaller tiles lose what the skipped blocks win;
+masking only the diagonal's blocks in a second `fori_loop` made every
+tiling 1-7% SLOWER. The head kernels: 1.28 ms at 256-row chunks (1.31
+at 128, 1.36 at 512) with a dq + dkv pair, 1.21 with the one-kernel
+backward. Mosaic's default-precision f32 `dot_general` already was
+one bf16 pass on the MXU: bf16 operands changed neither the time
+(2.06 -> 2.09) nor one bit of the result on bf16 inputs.
+
+Auto block sizes are budget-driven: the head kernels' chunk where they
+apply, else the largest power-of-two tile <= 1024 that keeps the worst
+kernel's VMEM estimate under budget (big head dims shrink blocks
+instead of failing to compile).
 
 Backward overhead trims (round 6): the delta precompute
 (`rowsum(dO * O)`, FlashAttention-2 eq. 4) is folded into the dq
@@ -59,9 +90,9 @@ NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 # Mosaic's scoped-vmem stack limit is 16 MB; 15 MB leaves scheduling
 # headroom (same calibration rationale as ops/fused_ce.py). The
-# estimates below are tuned so the round-5 measured-fastest config
-# (1024x1024 blocks at d=64) still fits — the budget bites only where
-# the real limit would (large T residency, large head dims).
+# estimates below are tuned so that 1024x1024 blocks at d=64 (what
+# T > 2048 and non-causal calls run) still fit — the budget bites only
+# where the real limit would (large T residency, large head dims).
 _VMEM_BUDGET = 15 * 1024 * 1024
 
 # test/bench escape hatch: force "stream" or "resident" regardless of
@@ -70,11 +101,37 @@ _VMEM_BUDGET = 15 * 1024 * 1024
 _FORCE_SCHEME = os.environ.get("KUNGFU_FLASH_SCHEME") or None
 
 
+def _operand_dtype(*dtypes):
+    """The dtype the block matmuls feed the MXU: bf16 when every input
+    is bf16 (its native operand type; `preferred_element_type` keeps
+    the accumulation f32), f32 otherwise — so f32 callers, and any
+    mixed or exotic input, run the contraction they always ran. Shared
+    with `flash_plan`, which reports it."""
+    bf16 = jnp.dtype(jnp.bfloat16)
+    return (jnp.bfloat16 if all(jnp.dtype(d) == bf16 for d in dtypes)
+            else jnp.float32)
+
+
+def _mxu(a, b, contract, op):
+    """One block matmul: operands in `op`, f32 accumulation."""
+    return lax.dot_general(a.astype(op), b.astype(op),
+                           (contract, ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))   # a @ b.T
+_NN = ((1,), (0,))   # a @ b
+_TN = ((0,), (0,))   # a.T @ b
+
+
 def _scores(q_blk, k_blk, iq, jk, *, scale, causal, block_q, block_k,
-            window=None, transpose=False):
-    """Scaled (and causal/window-masked) score block — shared by the
-    forward and both backward kernels so the masking and scaling
-    semantics cannot drift apart.
+            window=None, transpose=False, op=jnp.float32):
+    """Scaled (and causal/window-masked) f32 score block — shared by
+    the forward and both backward kernels so the masking and scaling
+    semantics cannot drift apart. q and k enter the MXU in `op`
+    (`_operand_dtype`); `scale` multiplies the f32 scores AFTER the
+    dot, so a head size whose scale is no power of two rounds nothing
+    in a bf16 operand.
 
     `transpose=False`: [block_q, block_k] (q on sublanes) — the
     forward and dq-kernel layout (dq caches the per-q lse/delta
@@ -84,39 +141,34 @@ def _scores(q_blk, k_blk, iq, jk, *, scale, causal, block_q, block_k,
     lse/delta rows (see `_flash_bwd_impl`) broadcast against scores
     with no lane<->sublane relayout, and its two accumulations become
     Mosaic-native NN contractions (the untransposed dkv pays two TN
-    forms). Measured on v5e at T=16k: this split is the fastest of
-    the four layout/orientation combinations tried (see git history
-    of this file), 7% faster end-to-end fwd+bwd than the round-3
-    [B*H, T, 128] lane-broadcast scheme it replaces.
+    forms). Measured on v5e at T=16k (round 5): this split is the
+    fastest of the four layout/orientation combinations tried (see git
+    history of this file), 7% faster end-to-end fwd+bwd than the
+    round-3 [B*H, T, 128] lane-broadcast scheme it replaces.
 
     `window` (sliding-window attention, causal only): position q
     attends to keys [q - window, q]. Self is always visible, so no row
-    is ever fully masked.
+    is ever fully masked. `causal=False, window=None` builds no mask
+    at all: the head kernels call it so for the part of a causal head
+    that lies wholly under the diagonal.
     """
     if transpose:
         shape = (block_k, block_q)
         q_dim, k_dim = 1, 0
-        s = jax.lax.dot_general(
-            k_blk.astype(jnp.float32),
-            q_blk.astype(jnp.float32) * scale,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        s = _mxu(k_blk, q_blk, _NT, op) * scale
     else:
         shape = (block_q, block_k)
         q_dim, k_dim = 0, 1
-        s = jax.lax.dot_general(
-            q_blk.astype(jnp.float32) * scale,
-            k_blk.astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        s = _mxu(q_blk, k_blk, _NT, op) * scale
     if causal or window is not None:
-        q_pos = iq * block_q + lax.broadcasted_iota(
-            jnp.int32, shape, q_dim)
-        k_pos = jk * block_k + lax.broadcasted_iota(
-            jnp.int32, shape, k_dim)
-        keep = q_pos >= k_pos
+        # q_pos - k_pos = diff - off: `diff` is the same for every
+        # block of a loop, only the scalar `off` moves with (iq, jk)
+        diff = (lax.broadcasted_iota(jnp.int32, shape, q_dim)
+                - lax.broadcasted_iota(jnp.int32, shape, k_dim))
+        off = jk * block_k - iq * block_q
+        keep = diff >= off
         if window is not None:
-            keep &= q_pos - k_pos <= window
+            keep &= diff <= off + window
         s = jnp.where(keep, s, NEG_INF)
     return s
 
@@ -128,17 +180,25 @@ def _fwd_step(q_blk, k_blk, v_blk, iq, jk, acc, m, l, *, scale, causal,
     and the streaming kernel (VMEM-scratch state) so the two schemes
     cannot drift numerically (the `_scores` discipline, applied to the
     whole block update). State shapes: acc [bq, d] f32, m/l [bq] f32.
+    Only the two matmuls' operands take `_operand_dtype`: the scores,
+    max, exp and denominator are f32 whatever the input.
     Returns the updated (acc, m, l)."""
+    op = _operand_dtype(q_blk.dtype, k_blk.dtype, v_blk.dtype)
     s = _scores(q_blk, k_blk, iq, jk, scale=scale, causal=causal,
-                block_q=block_q, block_k=block_k, window=window)
+                block_q=block_q, block_k=block_k, window=window, op=op)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     alpha = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new[:, None])
     l = l * alpha + jnp.sum(p, axis=-1)
-    acc = acc * alpha[:, None] + jax.lax.dot_general(
-        p, v_blk.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc = acc * alpha[:, None] + _mxu(p, v_blk, _NN, op)
     return acc, m_new, l
+
+
+def _fwd_init(block_q, d):
+    """The online-softmax state before the first block: (acc, m, l)."""
+    return (jnp.zeros((block_q, d), jnp.float32),
+            jnp.full((block_q,), NEG_INF, jnp.float32),
+            jnp.zeros((block_q,), jnp.float32))
 
 
 def _fwd_finish(acc, m, l, dtype, save_lse):
@@ -153,42 +213,37 @@ def _fwd_finish(acc, m, l, dtype, save_lse):
 def _dq_step(q_blk, k_blk, v_blk, do, lse_col, delta_col, iq, jk, *,
              scale, causal, block_q, block_k, window=None):
     """One K/V block's dq contribution (FlashAttention-2: p rebuilt
-    from lse; ds = p * (dp - delta); returns scale * ds @ k) — shared
-    by both backward-dq schemes."""
-    do = do.astype(jnp.float32)
+    from lse; ds = p * (dp - delta); returns ds @ k, which the caller
+    multiplies by `scale` once, after its last block) — shared by both
+    backward-dq schemes. q, k, v, do and ds are MXU operands
+    (`_operand_dtype`); p, dp, ds themselves are f32."""
+    op = _operand_dtype(q_blk.dtype, k_blk.dtype, v_blk.dtype, do.dtype)
     s = _scores(q_blk, k_blk, iq, jk, scale=scale, causal=causal,
-                block_q=block_q, block_k=block_k, window=window)
+                block_q=block_q, block_k=block_k, window=window, op=op)
     p = jnp.exp(s - lse_col)
-    dp = jax.lax.dot_general(
-        do, v_blk.astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    dp = _mxu(do, v_blk, _NT, op)
     ds = p * (dp - delta_col)
-    return scale * jax.lax.dot_general(
-        ds, k_blk.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    return _mxu(ds, k_blk, _NN, op)
 
 
 def _dkv_step(q_blk, k_blk, v_blk, do, lse_row, delta_row, iq, jk, *,
               scale, causal, block_q, block_k, window=None):
     """One Q/dO block's (dk, dv) contribution in TRANSPOSED score
-    space (q on lanes — see `_scores`): dv = pT @ do,
-    dk = scale * dsT @ q — shared by both backward-dkv schemes."""
-    do = do.astype(jnp.float32)
+    space (q on lanes — see `_scores`): dv = pT @ do, dk = dsT @ q
+    (the caller multiplies dk by `scale` once, after its last block) —
+    shared by every backward scheme. Also returns dsT as the MXU
+    operand it was cast to, for the head kernel, which derives dq from
+    the same pass."""
+    op = _operand_dtype(q_blk.dtype, k_blk.dtype, v_blk.dtype, do.dtype)
     s_t = _scores(q_blk, k_blk, iq, jk, scale=scale, causal=causal,
                   block_q=block_q, block_k=block_k, window=window,
-                  transpose=True)                     # [bk, bq]
+                  transpose=True, op=op)              # [bk, bq]
     p_t = jnp.exp(s_t - lse_row)
-    dv = jax.lax.dot_general(
-        p_t, do, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # p^T @ do
-    dp_t = jax.lax.dot_general(
-        v_blk.astype(jnp.float32), do, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (do @ v^T)^T
-    ds_t = p_t * (dp_t - delta_row)
-    dk = scale * jax.lax.dot_general(
-        ds_t, q_blk.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # ds^T @ q
-    return dk, dv
+    dv = _mxu(p_t, do, _NN, op)                       # p^T @ do
+    dp_t = _mxu(v_blk, do, _NT, op)                   # (do @ v^T)^T
+    ds_t = (p_t * (dp_t - delta_row)).astype(op)
+    dk = _mxu(ds_t, q_blk, _NN, op)                   # ds^T @ q
+    return dk, dv, ds_t
 
 
 def _diag_ok(iq, jk, causal, block_q, block_k, window=None):
@@ -346,14 +401,61 @@ def _dkv_res_vmem(bq, bk, d, isz, t):
 _RES_VMEM = {"fwd": _fwd_res_vmem, "dq": _dq_res_vmem,
              "dkv": _dkv_res_vmem}
 
+# full-length [t, d] arrays a head kernel's pipeline holds, in and out
+# (the backward is one kernel: q, k, v, dO, O in, dq, dk, dv out)
+_HEAD_ARRAYS = {"fwd": 4, "bwd": 8}
 
-def _choose_scheme(which, t, d, isz, bq, bk):
-    """'resident' when the full-length-per-head scheme fits the VMEM
-    budget (it both skips masked blocks AND fetches the streamed side
-    once per head), else 'stream'. `_FORCE_SCHEME` overrides for
-    benchmarking/tests."""
+# the head kernels unroll 2 * nb - 1 block steps at trace time; past
+# this many chunks the dynamic loops keep the program small
+_HEAD_MAX_CHUNKS = 8
+# rows a chunk: the auto pick for causal T <= 2048, by the PR 25 sweep
+# on a v5e (d = 64, fwd + bwd, the backward still a dq + dkv pair):
+# 256 beat 128 and 512 at T = 1024 (bf16 1.278 / 1.311 / 1.362 ms,
+# f32 1.653 at 256 / 1.720 at 512) and at T = 2048 (1.880 at 256,
+# 2.380 at 512); with the one-kernel backward 256 and 512 tie at
+# T = 1024 (1.207 / 1.194)
+_HEAD_CHUNK = 256
+
+
+def _head_vmem(which, block, d, isz, t):
+    """`which`: "fwd", or "bwd" for the one backward kernel."""
+    arrays = 2 * (_HEAD_ARRAYS[which] * t * d * isz + t * 4)
+    # the widest step's [block, t - block] f32 score temporaries, as
+    # the other schemes count theirs, plus the MXU-operand casts
+    n = 2 if which == "fwd" else 3
+    wide = block * (t - block) * (n * 4 + (n - 1) * isz)
+    scratch = 0 if which == "fwd" else t * d * 4 + t * 4
+    return arrays + wide + scratch
+
+
+def _head_tiles(t, d, isz):
+    """Auto tiles of a causal, window-less call that the head kernels
+    can take whole — `_HEAD_CHUNK`-row chunks, at most
+    `_HEAD_MAX_CHUNKS` of them, every kernel inside the VMEM budget
+    (`d` None: not known yet, assume it fits) — else None."""
+    c = _HEAD_CHUNK
+    if t % c or not c < t <= c * _HEAD_MAX_CHUNKS:
+        return None
+    if d is not None and any(_head_vmem(which, c, d, isz, t) > _VMEM_BUDGET
+                             for which in _HEAD_ARRAYS):
+        return None
+    return c, c
+
+
+def _choose_scheme(which, t, d, isz, bq, bk, causal=False, window=None):
+    """'head' for a causal head that unrolls into a few chunks (square
+    tiles, no window) and fits the VMEM budget whole: the statically
+    scheduled kernels do the least work a block step. Else 'resident'
+    when the full-length-per-head scheme fits (it both skips masked
+    blocks AND fetches the streamed side once per head), else
+    'stream'. `_FORCE_SCHEME` overrides for benchmarking/tests."""
     if _FORCE_SCHEME in ("stream", "resident"):
         return _FORCE_SCHEME
+    if (causal and window is None and bq == bk
+            and 1 < t // bq <= _HEAD_MAX_CHUNKS
+            and _head_vmem("fwd" if which == "fwd" else "bwd", bq, d,
+                           isz, t) <= _VMEM_BUDGET):
+        return "head"
     est = _RES_VMEM[which](bq, bk, d, isz, t)
     return "resident" if est <= _VMEM_BUDGET else "stream"
 
@@ -362,7 +464,7 @@ def _dim_semantics(n):
     """Pipelining hint: every grid dim is embarrassingly parallel
     except a streaming kernel's innermost (scratch-carried online
     state ⇒ sequential)."""
-    sem = ("parallel",) * n if n == 2 else (
+    sem = ("parallel",) * n if n <= 2 else (
         ("parallel",) * (n - 1) + ("arbitrary",))
     return pltpu.CompilerParams(dimension_semantics=sem)
 
@@ -457,10 +559,7 @@ def _fwd_res_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
             scale=scale, causal=causal, block_q=block_q,
             block_k=block_k, window=window)
 
-    acc, m, l = lax.fori_loop(lo, hi, body, (
-        jnp.zeros((block_q, d), jnp.float32),
-        jnp.full((block_q,), NEG_INF, jnp.float32),
-        jnp.zeros((block_q,), jnp.float32)))
+    acc, m, l = lax.fori_loop(lo, hi, body, _fwd_init(block_q, d))
     o, lse = _fwd_finish(acc, m, l, o_ref.dtype, lse_ref is not None)
     o_ref[0] = o
     if lse_ref is not None:
@@ -486,9 +585,10 @@ def _dq_res_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     iq = pl.program_id(1)
     q_blk = q_ref[0]
     d = q_blk.shape[-1]
-    do = do_ref[0].astype(jnp.float32)
-    delta_col = jnp.sum(do * o_ref[0].astype(jnp.float32), axis=-1,
-                        keepdims=True)                    # [bq, 1]
+    do = do_ref[0]
+    delta_col = jnp.sum(
+        do.astype(jnp.float32) * o_ref[0].astype(jnp.float32), axis=-1,
+        keepdims=True)                                    # [bq, 1]
     delta_ref[0, 0] = delta_col.reshape(1, block_q)
     lse_col = lse_ref[0, 0].reshape(block_q, 1)
     lo, hi = _k_span(iq, nk, causal=causal, window=window,
@@ -504,7 +604,7 @@ def _dq_res_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 
     acc = lax.fori_loop(lo, hi, body,
                         jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = acc.astype(dq_ref.dtype)
+    dq_ref[0] = (acc * scale).astype(dq_ref.dtype)
 
 
 def _dkv_res_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
@@ -525,7 +625,7 @@ def _dkv_res_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     def body(iq, carry):
         dk_acc, dv_acc = carry
         off = pl.multiple_of(iq * block_q, block_q)
-        dk, dv = _dkv_step(
+        dk, dv, _ = _dkv_step(
             q_ref[0, pl.ds(off, block_q), :], k_blk, v_ref[0],
             do_ref[0, pl.ds(off, block_q), :],
             lse_ref[0, iq, 0, :][None, :],    # [1, bq] lane rows
@@ -537,8 +637,145 @@ def _dkv_res_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     dk_acc, dv_acc = lax.fori_loop(lo, hi, body, (
         jnp.zeros((block_k, d), jnp.float32),
         jnp.zeros((block_k, d), jnp.float32)))
-    dk_ref[0] = dk_acc.astype(dk_ref.dtype)
+    dk_ref[0] = (dk_acc * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv_acc.astype(dv_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# head kernels (grid (B*H,), the causal schedule unrolled at trace time)
+#
+# One program holds a whole head — q, k, v (and dO, O) at full length in
+# VMEM — and walks it in chunks of `block` rows with PYTHON loops, so
+# every extent is static: a chunk's visible part under the diagonal is
+# ONE wide unmasked block step ([block, lo] scores, whatever lo is), and
+# only the [block, block] square the diagonal crosses builds the mask.
+# nb chunks cost 2*nb - 1 block steps a kernel where the resident loops
+# take nb*(nb+1)/2 — the per-step cost (online-softmax rescale, MXU
+# fill and drain, a [block, d] accumulate) is what kept smaller tiles
+# from paying on the chip (PERF.md §6, PR 25) — and the steps are the
+# `_fwd_step` / `_dkv_step` every scheme shares.
+# ---------------------------------------------------------------------------
+
+
+def _fwd_head_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
+                     block):
+    """o (and lse) of one head: a chunk of queries meets all earlier
+    keys, [0, lo), in one wide unmasked step, then its own under the
+    mask."""
+    t, d = q_ref.shape[1:]
+    for lo in range(0, t, block):
+        rows = slice(lo, lo + block)
+        q_blk = q_ref[0, rows, :]
+        step = functools.partial(_fwd_step, q_blk, scale=scale,
+                                 block_q=block)
+        carry = _fwd_init(block, d)
+        if lo:   # keys [0, lo): every pair visible
+            carry = step(k_ref[0, :lo, :], v_ref[0, :lo, :], 0, 0,
+                         *carry, causal=False, block_k=lo)
+        acc, m, l = step(k_ref[0, rows, :], v_ref[0, rows, :], 0, 0,
+                         *carry, causal=True, block_k=block)
+        o, lse = _fwd_finish(acc, m, l, o_ref.dtype, lse_ref is not None)
+        o_ref[0, rows, :] = o
+        if lse_ref is not None:
+            lse_ref[0, 0, :, rows] = lse.reshape(1, block)
+
+
+def _fwd_head_kernel_nolse(q_ref, k_ref, v_ref, o_ref, *, scale, block):
+    _fwd_head_kernel(q_ref, k_ref, v_ref, o_ref, None, scale=scale,
+                     block=block)
+
+
+def _bwd_head_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                     dq_ref, dk_ref, dv_ref, dq_acc, delta_row, *,
+                     scale, block):
+    """dq, dk and dv of one head from ONE pass over its visible
+    scores, in transposed score space (see `_scores`): a chunk of keys
+    meets its own queries under the mask and all later queries,
+    [hi, t), in one wide unmasked step. With queries and keys both
+    here, dsT serves dk (dsT @ q) and dq (ds @ k, a transposed-lhs
+    contraction) alike, so the backward is five matmuls a step where
+    the dq + dkv kernel pair of the other schemes, each rebuilding p
+    and dp, runs seven (fwd + bwd 1.28 -> 1.21 ms at T 1024, 1.88 ->
+    1.66 at 2048: PERF.md §6, PR 25). delta (rowsum(dO * O), FlashAttention-2
+    eq. 4) is a lane-major row in scratch, dq an f32 accumulator."""
+    t = q_ref.shape[1]
+    for lo in range(0, t, block):
+        rows = slice(lo, lo + block)
+        delta_row[:, rows] = jnp.sum(
+            do_ref[0, rows, :].astype(jnp.float32)
+            * o_ref[0, rows, :].astype(jnp.float32),
+            axis=-1, keepdims=True).reshape(1, block)
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+    for lo in range(0, t, block):
+        hi = lo + block
+        k_blk = k_ref[0, lo:hi, :]
+        step = functools.partial(_dkv_step, k_blk=k_blk, iq=0, jk=0,
+                                 v_blk=v_ref[0, lo:hi, :], scale=scale,
+                                 block_k=block)
+        dk = dv = 0.0
+        for rows, causal in ((slice(lo, hi), True), (slice(hi, t), False)):
+            if rows.start == rows.stop:
+                continue
+            dk_r, dv_r, ds_t = step(
+                q_blk=q_ref[0, rows, :], do=do_ref[0, rows, :],
+                lse_row=lse_ref[0, 0, :, rows],
+                delta_row=delta_row[:, rows], causal=causal,
+                block_q=rows.stop - rows.start)
+            dk, dv = dk + dk_r, dv + dv_r
+            dq_acc[rows, :] += _mxu(ds_t, k_blk, _TN, ds_t.dtype)
+        dk_ref[0, lo:hi, :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0, lo:hi, :] = dv.astype(dv_ref.dtype)
+    dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+# The head kernels' bodies are long to trace (2 * nb - 1 unrolled block
+# steps) and a model calls them once a layer with the same shapes: an
+# inlined jit keeps the traced call per (shapes, statics), so layers
+# 2..L replay it — no name of its own lands between the caller's scope
+# and `pallas_call` in the op's name.
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "block", "save_lse", "interpret"))
+def _head_fwd(qb, kb, vb, *, scale, block, save_lse, interpret):
+    """(o, lse) or o of [B*H, T, D] inputs; lse comes back [B*H,1,1,T]."""
+    bh, t, d = qb.shape
+    full = pl.BlockSpec((1, t, d), lambda i: (i, 0, 0))
+    o_shape = jax.ShapeDtypeStruct(qb.shape, qb.dtype)
+    lse_spec = pl.BlockSpec((1, 1, 1, t), lambda i: (i, 0, 0, 0))
+    lse_shape = jax.ShapeDtypeStruct((bh, 1, 1, t), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(
+            _fwd_head_kernel if save_lse else _fwd_head_kernel_nolse,
+            scale=scale, block=block),
+        grid=(bh,),
+        in_specs=[full] * 3,
+        out_specs=[full, lse_spec] if save_lse else full,
+        out_shape=[o_shape, lse_shape] if save_lse else o_shape,
+        compiler_params=_dim_semantics(1),
+        interpret=interpret,
+    )(qb, kb, vb)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "block", "interpret"))
+def _head_bwd(qb, kb, vb, dob, ob, lse, *, scale, block, interpret):
+    """(dq, dk, dv) of [B*H, T, D] inputs and the [B*H, T] lse."""
+    bh, t, d = qb.shape
+    full = pl.BlockSpec((1, t, d), lambda i: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_head_kernel, scale=scale, block=block),
+        grid=(bh,),
+        in_specs=[full] * 5 + [
+            pl.BlockSpec((1, 1, 1, t), lambda i: (i, 0, 0, 0))],
+        out_specs=[full] * 3,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (qb, kb, vb)],
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),   # dq
+                        pltpu.VMEM((1, t), jnp.float32)],  # delta
+        compiler_params=_dim_semantics(1),
+        interpret=interpret,
+    )(qb, kb, vb, dob, ob, lse.reshape(bh, 1, 1, t))
 
 
 def _plain_attention(q, k, v, causal, scale, window=None):
@@ -566,19 +803,21 @@ def flash_attention(
 
     Tiling requires T % block == 0 (and causal additionally
     block_q % block_k == 0); other shapes use the plain implementation.
-    `block_q`/`block_k` default to auto: T <= 1024 runs as ONE block
-    (any length — full-dim blocks always satisfy Mosaic's tiling rule;
-    odd lengths verified on real v5e), longer T picks the largest of
-    1024/512/256/128 dividing it (1024 fastest measured on v5e) that
-    also keeps every kernel's VMEM estimate under `_VMEM_BUDGET`
-    (large head dims shrink blocks instead of compile-OOMing), and
-    longer non-dividing T takes the plain fallback. `interpret=None`
-    auto-selects interpreter mode off-TPU so tests run on the CPU mesh.
+    `block_q`/`block_k` default to auto: a causal, window-less T that
+    is 2 to 8 chunks of 256 rows (512 ... 2048) runs the head kernels
+    at that chunk; any other T <= 1024 runs as ONE block (any length —
+    full-dim blocks always satisfy Mosaic's tiling rule; odd lengths
+    verified on real v5e), longer T picks the largest of
+    1024/512/256/128 dividing it that also keeps every kernel's VMEM
+    estimate under `_VMEM_BUDGET` (large head dims shrink blocks
+    instead of compile-OOMing), and longer non-dividing T takes the
+    plain fallback. `interpret=None` auto-selects interpreter mode
+    off-TPU so tests run on the CPU mesh.
 
-    Each kernel then runs the VMEM-resident block-skipping scheme when
-    it fits the budget, else the streaming grid — see the module
-    docstring and `flash_plan` for the decision and the per-shape
-    visited-block counts.
+    Each kernel then runs the head scheme where it applies, else the
+    VMEM-resident block-skipping loops when they fit the budget, else
+    the streaming grid — see the module docstring and `flash_plan` for
+    the decision and the per-shape visited-block counts.
 
     Backward pass: fused flash backward kernels — the forward saves only
     (q, k, v, o, lse), dq/dk/dv are computed blockwise with the
@@ -607,11 +846,16 @@ def _tiles(t, causal, block_q, block_k, window=None, *, d=None,
            itemsize=4):
     """The (block_q, block_k) actually usable for length t, or None.
 
-    `None` block sizes auto-select the largest power-of-two <= 1024
-    that divides t. Round-5 v5e sweep (fwd+bwd, b*h=144, d=64):
-    1024 beats 512 by 21-22% at t = 1024 / 2048 / 4096 (fewer
-    per-q-block prologue/epilogues and bigger matmuls); 512 had
-    previously beaten 128 by ~25%. With a sliding `window`, the cap is
+    `None` block sizes auto-select the head kernels' chunk for a
+    causal, window-less t they can take (`_head_tiles`), else the
+    largest power-of-two <= 1024 that divides t. PR 25's v5e sweep of
+    the loops (fwd+bwd, 8192 tokens x 12 heads, d=64, bf16): at
+    t = 1024 one 1024 block (2.06 ms) beats 512 x 256 and 256 x 256
+    by 6% and 21% and loses 6% to 512 x 512; at t = 2048 and 4096
+    (2.87 and 4.29 ms) 512 x 512 ties 1024 x 1024 within 2% and
+    256 x 256 loses 36% and 52% — a loop's block step costs too much
+    for small tiles (module docstring), so the loops' pick stays, and
+    t = 4096 stays with them. With a sliding `window`, the cap is
     the largest power-of-two <= window instead: past-window score area
     inside a block is masked waste, and at t=16k/window=512 the 1024
     block measured 40% SLOWER (7.04 vs 5.02 ms) than 512. When the
@@ -628,6 +872,10 @@ def _tiles(t, causal, block_q, block_k, window=None, *, d=None,
     otherwise non-tiling lengths take the plain fallback as before.
     """
     auto = block_q is None and block_k is None
+    if auto and causal and window is None:
+        head = _head_tiles(t, d, itemsize)
+        if head is not None:
+            return head
     if auto:
         cap = 1024
         if window is not None:
@@ -741,7 +989,13 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     lse_shape = jax.ShapeDtypeStruct((b * h, nq, 1, block_q),
                                      jnp.float32)
 
-    if _choose_scheme("fwd", t, d, isz, block_q, block_k) == "resident":
+    scheme = _choose_scheme("fwd", t, d, isz, block_q, block_k, causal,
+                            window)
+    if scheme == "head":
+        result = _head_fwd(_bh(q), _bh(k), _bh(v), scale=scale,
+                           block=block_q, save_lse=save_lse,
+                           interpret=interpret)
+    elif scheme == "resident":
         kernel = functools.partial(
             _fwd_res_kernel if save_lse else _fwd_res_kernel_nolse,
             scale=scale, causal=causal, block_q=block_q,
@@ -832,14 +1086,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     @pl.when(ok)
     def _():
         acc_ref[:] += _dq_step(
-            q_ref[0], k_ref[0], v_ref[0],
-            do_ref[0].astype(jnp.float32), lse_col[:], delta_col[:],
-            iq, jk, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, window=window)
+            q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_col[:],
+            delta_col[:], iq, jk, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, window=window)
 
     @pl.when(kk == nk - 1)
     def _():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
@@ -883,7 +1136,7 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ok)
     def _():
-        dk, dv = _dkv_step(
+        dk, dv, _ = _dkv_step(
             q_ref[0], k_ref[0], v_ref[0], do_ref[0],
             lse_ref[0, iq_c, 0, :][None, :],          # [1, bq] lanes
             delta_ref[0, iq_c, 0, :][None, :],
@@ -894,7 +1147,7 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(kk == nq - 1)
     def _():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
@@ -931,7 +1184,13 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                                        jnp.float32)
     dq_shape = jax.ShapeDtypeStruct((b * h, t, d), q.dtype)
 
-    if _choose_scheme("dq", t, d, isz, block_q, block_k) == "resident":
+    scheme = _choose_scheme("dq", t, d, isz, block_q, block_k, causal,
+                            window)
+    if scheme == "head":   # one kernel for dq, dk and dv
+        return tuple(_unbh(x, b, h) for x in _head_bwd(
+            qb, kb, vb, dob, ob, lse, scale=scale, block=block_q,
+            interpret=interpret))
+    if scheme == "resident":
         dq_kernel = functools.partial(
             _dq_res_kernel, scale=scale, causal=causal, block_q=block_q,
             block_k=block_k, window=window, nk=nk)
@@ -1000,7 +1259,8 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
         jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
     ]
-    if _choose_scheme("dkv", t, d, isz, block_q, block_k) == "resident":
+    if _choose_scheme("dkv", t, d, isz, block_q, block_k, causal,
+                      window) == "resident":
         dkv_kernel = functools.partial(
             _dkv_res_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, window=window, nq=nq)
@@ -1123,12 +1383,16 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
                block_q=None, block_k=None):
     """Static execution plan for `flash_attention` at this shape: block
-    sizes, per-kernel scheme, and per-kernel VISITED K/V (or Q/dO)
-    block counts — the exact fori/grid trip totals, derived from the
-    same `_k_span`/`_q_span`/`_window_span` the kernels use, so the
-    structural block-skip tests and published benchmark metadata
-    cannot drift from the implementation. `grid_blocks` is the
-    unskipped outer*inner product for comparison."""
+    sizes, the MXU operand dtype of the block matmuls
+    (`_operand_dtype`), per-kernel scheme, and per-kernel VISITED K/V
+    (or Q/dO) block counts — the exact fori/grid trip totals, derived
+    from the same `_k_span`/`_q_span`/`_window_span` the kernels use,
+    so the structural block-skip tests and published benchmark
+    metadata cannot drift from the implementation. `masked_blocks` of
+    the visited ones build the causal/window mask (every block the
+    resident and streaming loops visit; the diagonal's alone in the
+    head kernels); `grid_blocks` is the unskipped outer*inner product
+    for comparison."""
     isz = jnp.dtype(dtype).itemsize
     tiles = _tiles(t, causal, block_q, block_k, window, d=d,
                    itemsize=isz)
@@ -1136,36 +1400,32 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
         return {"scheme": "plain"}
     bq, bk = tiles
     nq, nk = t // bq, t // bk
-    plan = {"block_q": bq, "block_k": bk, "nq": nq, "nk": nk}
+    plan = {"block_q": bq, "block_k": bk, "nq": nq, "nk": nk,
+            "operand_dtype": jnp.dtype(_operand_dtype(dtype)).name}
     span = _window_span(window, bq, bk, nk) if causal else None
+    kw = dict(causal=causal, window=window, block_q=bq, block_k=bk)
     for which in ("fwd", "dq", "dkv"):
-        scheme = _choose_scheme(which, t, d, isz, bq, bk)
-        if which == "dkv":
-            grid_blocks = nk * nq
-            if scheme == "resident":
-                visited = 0
-                for jk in range(nk):
-                    lo, hi = _q_span(jk, nq, causal=causal,
-                                     window=window, block_q=bq,
-                                     block_k=bk)
-                    visited += int(hi) - int(lo)
-            else:
-                span_dkv = span if bq == bk else None
-                visited = nk * (span_dkv if span_dkv is not None
-                                else nq)
+        scheme = _choose_scheme(which, t, d, isz, bq, bk, causal, window)
+        masked = None
+        if scheme == "head":
+            # a chunk's wide step under the diagonal covers as much as
+            # the square blocks it spans: the lower triangle, of which
+            # only the diagonal's nq squares build the mask
+            visited, masked = nq * (nq + 1) // 2, nq
+        elif scheme == "resident":
+            spans = ([_q_span(jk, nq, **kw) for jk in range(nk)]
+                     if which == "dkv" else
+                     [_k_span(iq, nk, **kw) for iq in range(nq)])
+            visited = sum(int(hi) - int(lo) for lo, hi in spans)
+        elif which == "dkv":
+            visited = nk * (span if span is not None and bq == bk else nq)
         else:
-            grid_blocks = nq * nk
-            if scheme == "resident":
-                visited = 0
-                for iq in range(nq):
-                    lo, hi = _k_span(iq, nk, causal=causal,
-                                     window=window, block_q=bq,
-                                     block_k=bk)
-                    visited += int(hi) - int(lo)
-            else:
-                visited = nq * (span if span is not None else nk)
+            visited = nq * (span if span is not None else nk)
+        if masked is None:   # the loops mask every block they visit
+            masked = visited if causal else 0
         plan[which] = {"scheme": scheme, "visited_blocks": visited,
-                       "grid_blocks": grid_blocks}
+                       "masked_blocks": masked,
+                       "grid_blocks": nq * nk}
     return plan
 
 

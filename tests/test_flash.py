@@ -340,3 +340,86 @@ def test_windowed_narrowing_generalizes_to_rect_blocks():
             for a, r in zip(got_vjp(ct), ref_g):
                 np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                            atol=3e-2)
+
+
+# ---------------------------------------------------------------------------
+# bf16 inputs: the block matmuls take bf16 operands (f32 accumulation);
+# the reference is the f32 plain attention on the same (upcast) inputs
+# ---------------------------------------------------------------------------
+
+
+def _f32_reference(q, k, v, g, causal):
+    """(out, dq, dk, dv) of the plain attention computed in f32, at
+    full matmul precision, on bf16 inputs upcast to f32."""
+    d = q.shape[-1]
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(
+            lambda q, k, v: _plain_attention(q, k, v, causal, d ** -0.5),
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        return (out,) + vjp(g.astype(jnp.float32))
+
+
+def _max_errs(got, refs):
+    return [float(jnp.max(jnp.abs(a.astype(jnp.float32) - r)))
+            for a, r in zip(got, refs)]
+
+
+@pytest.mark.parametrize("scheme", ["resident", "stream", None])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("blocks", [
+    (64, 64),    # nq = nk = 4: diagonal and interior blocks both run
+    (128, 64),   # rect: two k-blocks of every q-block cross the diagonal
+    (256, 256),  # one block, the diagonal's mask only
+])
+def test_bf16_operands_match_f32_plain(monkeypatch, scheme, causal,
+                                       blocks):
+    """bf16 in: forward and jax.grad against the f32 reference, within
+    what bf16 operands (8 mantissa bits on p, ds and the inputs) and a
+    bf16 output can hold. All schemes share the block step; `None`
+    leaves the choice to the budget (causal square tiles: the head
+    kernels)."""
+    import kungfu_tpu.ops.flash as F
+
+    monkeypatch.setattr(F, "_FORCE_SCHEME", scheme)
+    q, k, v = qkv(b=1, t=256, h=2, d=64, dtype=jnp.bfloat16)
+    g = jax.random.normal(jax.random.PRNGKey(9), q.shape, q.dtype)
+    bq, bk = blocks
+    out, vjp = jax.vjp(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        block_q=bq, block_k=bk), q, k, v)
+    got = (out,) + vjp(g)
+    refs = _f32_reference(q, k, v, g, causal)
+    for name, a, r in zip("out dq dk dv".split(), got, refs):
+        assert a.dtype == jnp.bfloat16, name
+        scale = float(jnp.max(jnp.abs(r)))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(r), rtol=0,
+            atol=1e-2 * scale, err_msg=f"{scheme} {name}")
+
+
+def test_bf16_kernel_no_worse_than_the_models_plain_bf16_attention():
+    """The yardstick for 'the configuration's stated precision': against
+    the f32 reference, the kernel's error on bf16 inputs is no larger
+    than 1.5 x that of the model's own plain path
+    (`attention="local"`: flax's dot_product_attention in bfloat16),
+    for the output and each of dq, dk, dv."""
+    import flax.linen as nn
+
+    t, d = 256, 64
+    q, k, v = qkv(b=2, t=t, h=4, d=d, dtype=jnp.bfloat16)
+    g = jax.random.normal(jax.random.PRNGKey(9), q.shape, q.dtype)
+    refs = _f32_reference(q, k, v, g, True)
+
+    def run(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return _max_errs((out,) + vjp(g), refs)
+
+    mask = nn.make_causal_mask(jnp.ones((q.shape[0], t)))
+    plain = run(lambda q, k, v: nn.dot_product_attention(
+        q, k, v, mask=mask, dtype=jnp.bfloat16))
+    # auto tiles, and a tiling whose diagonal and interior blocks both run
+    for blocks in ((None, None), (64, 64)):
+        flash = run(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1]))
+        for name, f, p in zip("out dq dk dv".split(), flash, plain):
+            assert f <= 1.5 * p, (blocks, name, f, p)

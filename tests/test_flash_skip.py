@@ -62,6 +62,159 @@ def test_span_helpers_cover_exactly_the_visible_blocks(t, bq, bk,
             assert (lo <= iq < hi) == vis[iq, jk], (iq, jk)
 
 
+@pytest.mark.parametrize("t,block", [(512, 128), (1024, 256),
+                                     (1024, 512), (384, 128)])
+def test_head_kernels_unroll_the_causal_schedule(t, block):
+    """The head scheme's trace IS its schedule: a grad call runs two
+    kernels (forward; dq, dk and dv together) on a 1-D (B*H,) grid
+    whose bodies hold 2*nb - 1 block steps (one masked square a chunk
+    on the diagonal, one wide unmasked step for what lies under it) —
+    counted by their matmuls (2 a forward step; 5 a backward step, not
+    the 3 + 4 of a dq / dkv pair) and by the mask's two iotas, which
+    only the nb diagonal steps build. `flash_plan` reports the same
+    schedule in block units."""
+    nb = t // block
+    plan = F.flash_plan(t, 64, causal=True, block_q=block, block_k=block)
+    for which in ("fwd", "dq", "dkv"):
+        assert plan[which] == {
+            "scheme": "head", "visited_blocks": nb * (nb + 1) // 2,
+            "masked_blocks": nb, "grid_blocks": nb * nb}
+    q, k, v = qkv(t=t)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None, block, block).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    eqns = _pallas_eqns(jaxpr.jaxpr)
+    assert len(eqns) == 2
+    for eqn, matmuls in zip(eqns, (2, 5)):
+        assert len(eqn.params["grid_mapping"].grid) == 1
+        body = eqn.params["jaxpr"]
+        assert _count(body, "dot_general") == matmuls * (2 * nb - 1)
+        assert _count(body, "iota") == 2 * nb
+        assert _count(body, "while") == 0   # nothing left to loop over
+
+
+def test_head_kernels_trace_once_for_every_layer():
+    """A model calls the kernels once a layer at one shape: the second
+    call replays the first's trace (the unrolled bodies are the
+    expensive part of tracing a step), and lands under the caller's
+    scope with no name between it and `pallas_call`."""
+    q, k, v = qkv(t=640, h=3, d=16)   # a shape no other test traces
+    traced = []
+    real = F._fwd_head_kernel
+
+    def counting(*a, **kw):
+        traced.append(1)
+        return real(*a, **kw)
+
+    def model(q, k, v):
+        x = q
+        for i in range(3):
+            with jax.named_scope(f"CausalSelfAttention_{i}"):
+                x = flash_attention(x, k, v, causal=True, block_q=128,
+                                    block_k=128)
+        return x.sum()
+
+    import unittest.mock as mock
+    with mock.patch.object(F, "_fwd_head_kernel", counting):
+        jaxpr = jax.make_jaxpr(jax.grad(model))(q, k, v)
+    assert len(traced) == 1
+    eqns = _pallas_eqns(jaxpr.jaxpr)
+    assert len(eqns) == 6
+    stacks = {str(e.source_info.name_stack) for e in eqns}
+    # three layers, forward and backward, the scope innermost
+    assert stacks == {f(f"jvp(CausalSelfAttention_{i})") for i in range(3)
+                      for f in (str, "transpose({})".format)}
+
+
+@pytest.mark.parametrize("kw,why", [
+    (dict(causal=False), "no diagonal to schedule round"),
+    (dict(causal=True, window=64), "a window cuts blocks at both ends"),
+    (dict(causal=True, block_k=64), "rect tiles"),
+    (dict(causal=True, block_q=512, block_k=512), "one chunk"),
+    (dict(causal=True, block_q=32, block_k=32), "too many chunks"),
+])
+def test_head_scheme_leaves_other_shapes_to_the_loops(kw, why):
+    kw = dict(dict(block_q=128, block_k=128), **kw)
+    plan = F.flash_plan(512, 64, **kw)
+    assert plan["fwd"]["scheme"] == "resident", why
+
+
+@pytest.mark.parametrize("t,block,d", [(512, 128, 64), (512, 256, 64),
+                                       (384, 128, 32)])
+def test_head_scheme_matches_plain_fwd_and_grads(t, block, d):
+    """f32 numerics of the statically scheduled kernels, at the loops'
+    tolerances: same block steps, another order of visiting."""
+    assert F.flash_plan(t, d, causal=True, block_q=block,
+                        block_k=block)["dkv"]["scheme"] == "head"
+    with jax.default_matmul_precision("highest"):
+        q, k, v = qkv(t=t, d=d)
+        g = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, block_q=block, block_k=block),
+            q, k, v)
+        ref, ref_vjp = jax.vjp(
+            lambda q, k, v: _plain_attention(q, k, v, True, d ** -0.5),
+            q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+        for name, a, r in zip("dq dk dv".split(), vjp(g), ref_vjp(g)):
+            scale = float(jnp.max(jnp.abs(r))) or 1.0
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       rtol=0, atol=2e-4 * scale,
+                                       err_msg=name)
+
+
+def test_gpt_cells_plan_bf16_operands_and_an_engaged_skip():
+    """The counter that says PR 25's mechanism engages at the shape of
+    both GPT benchmark cells (T 1024, d 64, bf16, causal, auto tiles;
+    the plan is per head): bf16 operands, fewer visited than grid
+    blocks in all three kernels, and fewer masked than visited ones."""
+    plan = F.flash_plan(1024, 64, dtype=jnp.bfloat16, causal=True)
+    assert plan["operand_dtype"] == "bfloat16"
+    assert plan["block_q"] < 1024 and plan["block_q"] % plan["block_k"] == 0
+    for which in ("fwd", "dq", "dkv"):
+        k = plan[which]
+        assert k["scheme"] == "head"
+        assert k["masked_blocks"] < k["visited_blocks"] < k["grid_blocks"]
+
+
+@pytest.mark.parametrize("dtype,want", [
+    (jnp.float32, "float32"), (jnp.bfloat16, "bfloat16"),
+    (jnp.float16, "float32"),   # anything but bf16 keeps the f32 upcast
+])
+def test_plan_reports_operand_dtype(dtype, want):
+    plan = F.flash_plan(1024, 64, dtype=dtype, causal=True)
+    assert plan["operand_dtype"] == want
+
+
+@pytest.mark.parametrize("scheme", ["resident", "stream"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_kernels_feed_the_mxu_the_planned_operand_dtype(monkeypatch,
+                                                        scheme, dtype):
+    """Every dot_general inside the three kernels of a grad call takes
+    both operands in the plan's operand dtype — bf16 in, bf16 on the
+    MXU; f32 in, the f32 contraction f32 callers always ran."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", scheme)
+    q, k, v = qkv(t=256, dtype=dtype)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None, 128, 64).astype(
+            jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    eqns = _pallas_eqns(jaxpr.jaxpr)
+    assert len(eqns) == 3
+    want = F.flash_plan(256, 64, dtype=dtype, causal=True, block_q=128,
+                        block_k=64)["operand_dtype"]
+    for eqn in eqns:
+        assert {str(v.aval.dtype) for e in _eqns(eqn.params["jaxpr"])
+                if e.primitive.name == "dot_general"
+                for v in e.invars} == {want}
+
+
 def test_causal_trip_counts_shrink(monkeypatch):
     """The block-skip regression guard: under the resident scheme the
     summed fori trip counts of ALL THREE kernels equal the causal
@@ -123,18 +276,24 @@ def test_auto_blocks_shrink_under_vmem_budget():
                     itemsize=4) == (1024, 1024)
 
 
-def _pallas_eqns(jaxpr, acc=None):
-    acc = [] if acc is None else acc
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            acc.append(eqn)
+        yield eqn
         for v in eqn.params.values():
             for x in (v if isinstance(v, (list, tuple)) else (v,)):
                 if hasattr(x, "jaxpr"):          # ClosedJaxpr
-                    _pallas_eqns(x.jaxpr, acc)
+                    yield from _eqns(x.jaxpr)
                 elif hasattr(x, "eqns"):         # raw Jaxpr
-                    _pallas_eqns(x, acc)
-    return acc
+                    yield from _eqns(x)
+
+
+def _pallas_eqns(jaxpr):
+    return [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
+
+
+def _count(jaxpr, name):
+    return sum(e.primitive.name == name for e in _eqns(jaxpr))
 
 
 def test_resident_grad_runs_three_2d_kernels(monkeypatch):

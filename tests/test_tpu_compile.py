@@ -72,12 +72,17 @@ def _qkv(sharding, b=B, t=T, h=H, d=D):
     return s, s, s
 
 
-@pytest.mark.parametrize("scheme", ["resident", "stream"])
+@pytest.mark.parametrize("scheme", [None, "resident", "stream"],
+                         ids=["head", "resident", "stream"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 def test_flash_gpt2_small(one_chip, monkeypatch, scheme, grad):
     from kungfu_tpu.ops import flash
 
+    # unforced, the auto pick at this shape is the head kernels: the
+    # ones the GPT cells run
     monkeypatch.setattr(flash, "_FORCE_SCHEME", scheme)
+    plan = flash.flash_plan(T, D, dtype=jnp.bfloat16, causal=True)
+    assert plan["dkv"]["scheme"] == (scheme or "head")
 
     def fwd(q, k, v):
         return flash.flash_attention(q, k, v, causal=True,
@@ -87,8 +92,34 @@ def test_flash_gpt2_small(one_chip, monkeypatch, scheme, grad):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    # forward is one kernel; the backward adds dq and dkv
-    assert _kernels(_compile(fn, *_qkv(one_chip))) == (3 if grad else 1)
+    # forward is one kernel; the backward adds dq and dkv, which the
+    # head scheme computes in one
+    backward = 1 if scheme is None else 2
+    assert _kernels(_compile(fn, *_qkv(one_chip))) == (
+        1 + backward if grad else 1)
+
+
+@pytest.mark.parametrize("t,d,dtype", [
+    (2048, 64, jnp.bfloat16),   # the widest step the budget lets in
+    (1024, 128, jnp.float32),
+    (1024, 256, jnp.bfloat16),
+], ids=["t2048", "t1024-d128-f32", "t1024-d256"])
+def test_flash_head_kernels_at_the_budgets_edge(one_chip, t, d, dtype):
+    """The head kernels hold a whole head and a [256, t - 256] score
+    step in VMEM: where `_head_vmem` says that fits, Mosaic agrees."""
+    from kungfu_tpu.ops import flash
+
+    plan = flash.flash_plan(t, d, dtype=dtype, causal=True)
+    assert {plan[w]["scheme"] for w in ("fwd", "dq", "dkv")} == {"head"}
+
+    def loss(q, k, v):
+        return flash.flash_attention(
+            q, k, v, causal=True, interpret=False).astype(
+                jnp.float32).sum()
+
+    s = jax.ShapeDtypeStruct((2, t, 4, d), dtype, sharding=one_chip)
+    assert _kernels(_compile(jax.grad(loss, argnums=(0, 1, 2)),
+                             s, s, s)) == 2
 
 
 def test_flash_window_16k(one_chip):
