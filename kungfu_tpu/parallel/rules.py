@@ -436,6 +436,30 @@ def gpt_moe_rules(axis: str = "model") -> RuleTable:
         batch_axes=("data",))
 
 
+def glm_moe_rules(axis: str = "model") -> RuleTable:
+    """The Megatron split for `models/glm_moe.py`'s parameter paths:
+    the per-head up-projections of latent attention (`q_b`, `kv_b`)
+    column-parallel over heads and `o` row-parallel; every SwiGLU
+    (dense, shared expert) and the held experts' stacks split over
+    their width. The low-rank down-projections, norms, routers and
+    their biases, `eh_proj`, the embedding and the head replicate (a
+    sliced vocabulary rarely divides a model axis). The stacks'
+    leading dim is the held experts': an `expert` mesh axis would
+    split it, and no table here names one yet."""
+    return RuleTable(
+        name=f"glm_moe[{axis}]",
+        rules=(
+            (r".*MLAttention.*(q_b|kv_b).*kernel", spec(None, axis, None)),
+            (r".*MLAttention.*/o/kernel", spec(axis, None, None)),
+            (r".*(mlp|shared)/(gate|up)/kernel", cols(axis)),
+            (r".*(mlp|shared)/down/kernel", rows(axis)),
+            (r".*moe/w_(gate|up)", spec(None, None, axis)),
+            (r".*moe/w_down", spec(None, axis, None)),
+            _CATCH_ALL,
+        ),
+        batch_axes=("data",))
+
+
 def gpt_pp_rules(axis: str = "pipe",
                  tp_axis: Optional[str] = None) -> RuleTable:
     """Stage-stacked pipeline placement for the STACKED half of
@@ -629,6 +653,28 @@ def _template_gpt_stacked(stages: int = 2) -> Dict[str, Tuple[int, ...]]:
     return _tree_template(stacked_half)
 
 
+@lru_cache(maxsize=8)
+def _template_glm_moe() -> Dict[str, Tuple[int, ...]]:
+    """`models/glm_moe.py` at a size whose heads and widths a 2-way
+    model axis divides: one dense block, one expert block holding 2 of
+    8 experts, the MTP module. Imported here and not at the top: the
+    registry is checked lazily, and no other model's import pays for
+    this one."""
+    import jax.numpy as jnp
+
+    from ..models.glm_moe import GlmMoeConfig, GlmMoeLM
+
+    cfg = GlmMoeConfig(
+        vocab_size=251, hidden_size=64, num_heads=4, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=24, qk_rope_head_dim=8,
+        v_head_dim=32, intermediate_size=160, moe_intermediate_size=48,
+        n_routed_experts=8, num_experts_per_tok=2, num_layers=2,
+        held=(0, 2), dtype=jnp.float32)
+    shapes = jax.eval_shape(GlmMoeLM(cfg).init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 16), jnp.int32))
+    return _tree_template(shapes["params"])
+
+
 def _register_builtin_tables() -> None:
     """The shipped model-family tables at the MULTICHIP dryrun shapes
     — what `python -m kungfu_tpu.analysis` statically verifies."""
@@ -658,6 +704,10 @@ def _register_builtin_tables() -> None:
              # the dp x tp x pp family ROADMAP item 3 names
              [{"data": 2, "model": 2, "pipe": 2},
               {"model": 2, "pipe": 2}])
+    register("glm_moe", glm_moe_rules(),
+             _template_glm_moe,
+             [{"data": 4, "model": 2}, {"data": 1, "model": 2},
+              {"data": 1, "model": 1}])
     register("gpt_serve", gpt_serve_rules(),
              _template_gpt,
              # decode's (1, tp) serving mesh and the dp-replicated
@@ -685,7 +735,7 @@ def _table_universe(table: RuleTable) -> Tuple[str, ...]:
 TABLE_AXES: Dict[str, Tuple[str, ...]] = {
     f.__name__: _table_universe(f())
     for f in (bert_tp_rules, gpt_tp_rules, gpt_moe_rules,
-              gpt_pp_rules, moe_ep_rules, seq_sp_rules,
+              glm_moe_rules, gpt_pp_rules, moe_ep_rules, seq_sp_rules,
               gpt_serve_rules)
 }
 

@@ -26,6 +26,24 @@ GRAD_SYNC = "kf.grad_sync"
 #: residual scheme's backward head matmuls.
 FUSED_CE = "kf.fused_ce"
 
+#: latent attention of `models/glm_moe.py`: the low-rank projections,
+#: their norms, rotary positions, the flash (or plain) call and the
+#: output projection. The kernels stay `pallas_call`s directly under
+#: `MLAttention_<n>` inside it.
+MLA = "kf.mla"
+
+#: an expert layer's routing: router matmul, sigmoid, top-k, the sort
+#: into the row buffer, the dispatch and combine gathers.
+MOE_ROUTE = "kf.moe_route"
+
+#: an expert layer's matmuls: the grouped ones over the held experts'
+#: rows and the shared expert's.
+MOE_EXPERTS = "kf.moe_experts"
+
+#: the multi-token-prediction module whole: its two norms, `eh_proj`,
+#: its one expert block (whose MLA / MOE scopes nest inside it).
+MTP = "kf.mtp"
+
 #: a kftrace span `name` shows in a profiler session as
 #: HOST_SPAN_PREFIX + name, on the calling thread of `/host:CPU`.
 HOST_SPAN_PREFIX = "kf."

@@ -23,7 +23,8 @@ from kungfu_tpu.optimizers import sync_sgd, sync_sgd_bucketed
 from kungfu_tpu.parallel import (build_dp_replicated_train_step,
                                  build_gspmd_train_step,
                                  build_train_step_with_state, data_mesh)
-from kungfu_tpu.trace.scopes import FUSED_CE, GRAD_SYNC, OPT_UPDATE
+from kungfu_tpu.trace.scopes import (FUSED_CE, GRAD_SYNC, MLA, MOE_EXPERTS,
+                                     MOE_ROUTE, MTP, OPT_UPDATE)
 
 # what JAX itself writes round the model's forward and backward, and
 # `benchmark/metrics/fwd_bwd_ms.json` selects by
@@ -234,3 +235,82 @@ def test_vocab_sharded_head_is_under_fused_ce():
     assert not [p for p in kernels if FUSED_CE not in p]
     matmuls = [p for p in paths if primitive(p) == "dot_general"]
     assert matmuls and not [p for p in matmuls if FUSED_CE not in p]
+
+
+# -- the latent-attention / expert model's four scopes ------------------------
+
+
+@pytest.fixture(scope="module")
+def glm_paths():
+    """`models/glm_moe.py`'s step at a tiny size with every ratio kept:
+    flash attention (nope + rope == v), one dense block, one expert
+    block holding 2 of 8 experts, the MTP module, per-block
+    recomputation, the bias' sgd beside adamw, the GSPMD builder."""
+    from kungfu_tpu.models.glm_moe import (GlmMoeConfig, GlmMoeLM,
+                                           glm_moe_fused_loss,
+                                           glm_moe_optimizer)
+
+    cfg = GlmMoeConfig(
+        vocab_size=512, hidden_size=128, num_heads=2, q_lora_rank=48,
+        kv_lora_rank=32, qk_nope_head_dim=24, qk_rope_head_dim=8,
+        v_head_dim=32, intermediate_size=256, moe_intermediate_size=64,
+        n_routed_experts=8, num_experts_per_tok=2, num_layers=2,
+        held=(0, 2), dtype=jnp.bfloat16, attention="flash", remat=True)
+    model = GlmMoeLM(cfg)
+    tokens = jax.ShapeDtypeStruct((1, 128), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 16), jnp.int32))["params"])
+    tx = glm_moe_optimizer(optax.adamw(1e-4), 0.001)
+    step = build_gspmd_train_step(
+        lambda p, t: glm_moe_fused_loss(model, p, t), tx, has_aux=True)
+    return scope_paths(step, params, jax.eval_shape(tx.init, params),
+                       tokens)
+
+
+@pytest.mark.parametrize("scope, forward, backward", [
+    # what must lie under each name, forward and backward
+    (MLA, {"pallas_call", "dot_general", "cos"},
+     {"pallas_call", "dot_general"}),
+    (MOE_ROUTE, {"dot_general", "top_k", "sort", "gather"},
+     {"gather", "dot_general"}),
+    (MOE_EXPERTS, {"ragged_dot_general", "dot_general", "logistic"},
+     {"ragged_dot_general", "dot_general"}),
+    (MTP, {"pallas_call", "ragged_dot_general", "top_k", "concatenate"},
+     {"pallas_call", "ragged_dot_general"}),
+])
+def test_glm_scopes_hold_their_layers(glm_paths, scope, forward,
+                                      backward):
+    under = [p for p in glm_paths if scope in re.split(r"[/()]", p)]
+    fwd = {primitive(p) for p in under if "transpose(" not in p}
+    bwd = {primitive(p) for p in under if "transpose(" in p}
+    assert forward <= fwd, sorted(fwd)
+    assert backward <= bwd, sorted(bwd)
+
+
+def test_glm_flash_kernels_sit_directly_under_the_attention_module(
+        glm_paths):
+    # the adjacency benchmark/metrics/mla_flash_roofline.json selects by
+    flash = [p for p in glm_paths if "pallas_call" in p.split("/")
+             and FUSED_CE not in p]
+    assert len(flash) >= 6  # three attention layers, forward and backward
+    for p in flash:
+        assert re.search(r"MLAttention_\d+/pallas_call", p), p
+        assert MLA in re.split(r"[/()]", p), p
+    # both heads go through the fused kernel, outside kf.mtp
+    ce = [p for p in glm_paths if FUSED_CE in p and "pallas_call" in p]
+    assert ce and not [p for p in ce if MTP in p]
+
+
+def test_glm_expert_matmuls_are_nowhere_else(glm_paths):
+    grouped = [p for p in glm_paths
+               if primitive(p) == "ragged_dot_general"]
+    assert grouped
+    assert not [p for p in grouped
+                if MOE_EXPERTS not in re.split(r"[/()]", p)]
+    # the optimizer's arithmetic, the bias' sgd included, stays under
+    # kf.opt_update and out of the model's scopes
+    outside_model = [p for p in glm_paths if not MODEL.search(p)]
+    assert outside_model
+    assert not [p for p in outside_model
+                if OPT_UPDATE not in p.split("/")]

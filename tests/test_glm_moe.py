@@ -1,0 +1,363 @@
+"""`models/glm_moe.py` and `parallel/grouped_moe.py` against the plain
+reference (`models/glm_moe_reference.py`), on the CPU at small widths
+with every ratio of the published model kept: a rope/nope split whose
+sum is the value head size, q and kv ranks below the hidden size,
+top-k of E with a held subset, one dense block + expert blocks + the
+MTP module.
+"""
+
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from kungfu_tpu.models.glm_moe import (ROUTER_BIAS, ExpertFFN, GlmMoeConfig,
+                                       GlmMoeLM, MLAttention,
+                                       glm_moe_fused_loss, glm_moe_logits,
+                                       glm_moe_optimizer)
+from kungfu_tpu.models import glm_moe_reference as ref
+from kungfu_tpu.parallel import (build_gspmd_train_step, glm_moe_rules,
+                                 shard_params)
+from kungfu_tpu.parallel import rules as R
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def small(**kw):
+    base = dict(
+        vocab_size=256, hidden_size=64, num_heads=4, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=24, qk_rope_head_dim=8,
+        v_head_dim=32, intermediate_size=160, moe_intermediate_size=48,
+        n_routed_experts=16, num_experts_per_tok=4, num_layers=3,
+        held=(2, 2), dtype=jnp.float32)
+    base.update(kw)
+    return GlmMoeConfig(**base)
+
+
+def ref_cfg(c):
+    return dict(
+        num_attention_heads=c.num_heads, q_lora_rank=c.q_lora_rank,
+        kv_lora_rank=c.kv_lora_rank, qk_nope_head_dim=c.qk_nope_head_dim,
+        qk_rope_head_dim=c.qk_rope_head_dim, v_head_dim=c.v_head_dim,
+        num_experts_per_tok=c.num_experts_per_tok,
+        routed_scaling_factor=c.routed_scaling_factor,
+        first_k_dense_replace=c.first_k_dense_replace,
+        num_hidden_layers=c.num_layers,
+        num_nextn_predict_layers=c.num_nextn_predict_layers,
+        rope_theta=c.rope_theta, rms_norm_eps=c.rms_norm_eps,
+        held=c.held, mtp_lambda=c.mtp_lambda)
+
+
+def tokens_for(c, shape=(2, 32), seed=1):
+    return jax.random.randint(jax.random.PRNGKey(seed), shape, 0,
+                              c.vocab_size)
+
+
+def init(c, tokens, seed=0):
+    return GlmMoeLM(c).init(jax.random.PRNGKey(seed), tokens)["params"]
+
+
+def rel_err(a, b):
+    return float(jnp.linalg.norm((a - b).ravel())
+                 / (jnp.linalg.norm(b.ravel()) + 1e-30))
+
+
+def leaves_with_names(tree):
+    return [(jax.tree_util.keystr(p), x) for p, x in
+            jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+# -- (a) the system against the plain reference -------------------------------
+
+
+@pytest.fixture(scope="module")
+def f32_case():
+    c = small()
+    tokens = tokens_for(c)
+    return c, tokens, init(c, tokens)
+
+
+def test_logits_match_the_reference_in_f32(f32_case):
+    c, tokens, params = f32_case
+    with jax.default_matmul_precision("highest"):
+        logits, mtp_logits, aux = jax.jit(
+            lambda p: glm_moe_logits(GlmMoeLM(c), p, tokens))(params)
+    # the reference's objective from the system's logits: both heads
+    def ce(lg, tg):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            lg, tg).mean()
+
+    want, parts = jax.jit(lambda p: ref.reference_loss(
+        p, tokens, ref_cfg(c), q_block=16, row_block=16))(params)
+    assert float(ce(logits[:, :-1], tokens[:, 1:])) == pytest.approx(
+        float(parts["ce"]), rel=2e-6)
+    assert float(ce(mtp_logits[:, :-2], tokens[:, 2:])) == pytest.approx(
+        float(parts["ce_mtp"]), rel=2e-6)
+    assert (aux["counts"] == parts["counts"]).all()
+    assert int(aux["dropped"].sum()) == 0
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "remat"])
+def test_objective_and_gradients_match_the_reference_in_f32(
+        f32_case, remat):
+    c, tokens, params = f32_case
+    model = GlmMoeLM(dataclasses.replace(c, remat=remat))
+    with jax.default_matmul_precision("highest"):
+        (loss, m), g = jax.jit(jax.value_and_grad(
+            lambda p: glm_moe_fused_loss(model, p, tokens),
+            has_aux=True))(params)
+    (want, parts), g_want = jax.jit(jax.value_and_grad(
+        lambda p: ref.reference_loss(p, tokens, ref_cfg(c), q_block=16,
+                                     row_block=16, remat=remat),
+        has_aux=True))(params)
+    # f32 against f32, summation order only: at hidden 64 the fused
+    # head takes its plain path (its kernels run the head in bf16; the
+    # bf16 test below is at a width they take)
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
+    assert float(m["ce"]) == pytest.approx(float(parts["ce"]), rel=2e-6)
+    assert float(m["ce_mtp"]) == pytest.approx(float(parts["ce_mtp"]),
+                                               rel=2e-6)
+    for (name, got), (_, exp) in zip(leaves_with_names(g),
+                                     leaves_with_names(g_want)):
+        if ROUTER_BIAS in name:
+            continue  # no gradient's business: test (e)
+        assert rel_err(got, exp) < 2e-5, name
+
+
+def test_bf16_compute_stays_near_the_f32_reference():
+    """bf16 matmuls and residual stream, f32 statistics, router and
+    losses. The limits' reason: bf16 keeps 8 bits, so one rounding is
+    2^-9 relative; the loss averages 62 rows of ~5.5 whose logits each
+    carry some dozens of roundings, and reads 1e-3 or less here; a
+    gradient leaf sums products of rounded activations through 4
+    blocks and reads 1-3% of its norm, more where a token's top-k
+    flips. All-bf16 statistics (norms, router, softmax, loss) read
+    several times these (the benchmark's cell holds that line on the
+    chip)."""
+    c = small(dtype=jnp.bfloat16, vocab_size=512, hidden_size=128,
+              q_lora_rank=48, kv_lora_rank=32, intermediate_size=320,
+              moe_intermediate_size=96)
+    tokens = tokens_for(c, (2, 64))
+    params = init(c, tokens)
+    (loss, m), g = jax.jit(jax.value_and_grad(
+        lambda p: glm_moe_fused_loss(GlmMoeLM(c), p, tokens),
+        has_aux=True))(params)
+    (want, parts), g_want = jax.jit(jax.value_and_grad(
+        lambda p: ref.reference_loss(p, tokens, ref_cfg(c), q_block=16),
+        has_aux=True))(params)
+    assert abs(float(loss) - float(want)) < 5e-3
+    assert abs(float(m["ce_mtp"]) - float(parts["ce_mtp"])) < 5e-3
+    for path in (("embed", "embedding"), ("lm_head",),
+                 ("mtp", "eh_proj", "kernel"),
+                 ("Block_0", "mlp", "down", "kernel")):
+        got, exp = g, g_want
+        for key in path:
+            got, exp = got[key], exp[key]
+        assert rel_err(got, exp) < 0.05, path
+
+
+# -- (b) the shares add up to the uncut layer ---------------------------------
+
+
+def test_eight_shares_add_up_to_the_uncut_layer():
+    """Guide section 4: the routed parts the eight shares give, with
+    the shared expert (which every chip computes alike) counted once,
+    are the uncut reference's layer output."""
+    c = small(held=(0, 16))
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 24, c.hidden_size))
+    whole = ExpertFFN(c).init(jax.random.PRNGKey(3), x)["params"]
+    whole[ROUTER_BIAS] = 0.1 * jax.random.normal(jax.random.PRNGKey(4),
+                                                 (16,))
+    flat = x.reshape(-1, c.hidden_size)
+    with jax.default_matmul_precision("highest"):
+        uncut, counts = ref.expert_ffn(whole, flat, ref_cfg(c), False)
+        shared = ref.swiglu(*(whole["shared"][k]["kernel"]
+                              for k in ("gate", "up", "down")), flat)
+        routed = jnp.zeros_like(uncut)
+        for share in range(8):
+            held = (2 * share, 2)
+            mine = {**whole, **{k: whole[k][held[0]:held[0] + 2]
+                                for k in ("w_gate", "w_up", "w_down")}}
+            y, aux = ExpertFFN(dataclasses.replace(c, held=held)).apply(
+                {"params": mine}, x)
+            assert int(aux["dropped"]) == 0
+            assert (aux["counts"] == counts).all()  # the router is whole
+            routed += y.reshape(-1, c.hidden_size) - shared
+    np.testing.assert_allclose(routed + shared, uncut, rtol=2e-5,
+                               atol=2e-5)
+    assert int(counts.sum()) == flat.shape[0] * c.num_experts_per_tok
+
+
+# -- (c) dropless under imbalance ---------------------------------------------
+
+
+@pytest.mark.parametrize("favoured, held_rows", [
+    ((2, 8, 9, 10), 1.0),   # every token to ONE held expert (2 of 2, 3)
+    ((8, 9, 10, 11), 0.0),  # no token to a held expert
+    ((2, 3, 9, 10), 2.0),   # every token to both: the buffer is full
+], ids=["all-to-one", "none", "all-to-both"])
+def test_rigged_router_drops_nothing(favoured, held_rows):
+    c = small()
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 40, c.hidden_size))
+    params = ExpertFFN(c).init(jax.random.PRNGKey(6), x)["params"]
+    # equal scores everywhere, so the bias alone selects
+    params["router"] = jnp.zeros_like(params["router"])
+    params[ROUTER_BIAS] = jnp.zeros((16,)).at[jnp.array(favoured)].set(1.0)
+    y, aux = ExpertFFN(c).apply({"params": params}, x)
+    with jax.default_matmul_precision("highest"):
+        want, counts = ref.expert_ffn(params, x[0], ref_cfg(c), False)
+    np.testing.assert_allclose(y[0], want, rtol=2e-5, atol=2e-5)
+    assert int(aux["dropped"]) == 0
+    assert int(aux["held_assignments"]) == int(held_rows * 40)
+    assert (aux["counts"] == counts).all()
+    assert int(counts[jnp.array(favoured)].sum()) == 4 * 40
+
+
+# -- (d) latent attention through the flash kernels at d = 256 ----------------
+
+
+def test_mla_through_flash_at_head_size_256_matches_the_plain_path():
+    """The published head sizes (192 + 64 and 256), rotary included,
+    through `flash_attention` in interpret mode against the plain
+    path on the same parameters."""
+    c = small(hidden_size=128, num_heads=2, q_lora_rank=48,
+              kv_lora_rank=32, qk_nope_head_dim=192, qk_rope_head_dim=64,
+              v_head_dim=256)
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, 512, c.hidden_size))
+    params = MLAttention(c).init(jax.random.PRNGKey(8), x)["params"]
+
+    def run(attention):
+        mod = MLAttention(dataclasses.replace(c, attention=attention))
+        return jax.jit(jax.value_and_grad(
+            lambda p: (mod.apply({"params": p}, x) ** 2).sum()))(params)
+
+    (plain, g_plain), (flash, g_flash) = run("local"), run("flash")
+    assert float(flash) == pytest.approx(float(plain), rel=1e-5)
+    for (name, a), (_, b) in zip(leaves_with_names(g_flash),
+                                 leaves_with_names(g_plain)):
+        assert rel_err(a, b) < 1e-4, name
+    # and against the reference's own attention and rotary
+    with jax.default_matmul_precision("highest"):
+        want = ref.mla(params, x[0], ref_cfg(c), 128, False)
+        got = MLAttention(dataclasses.replace(c, attention="flash")).apply(
+            {"params": params}, x)[0]
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_flash_needs_one_head_size():
+    with pytest.raises(ValueError, match="one head size"):
+        small(attention="flash", v_head_dim=64)
+
+
+# -- (e) the selection bias rides in tx ---------------------------------------
+
+
+def test_bias_moves_by_gamma_against_the_load_and_weights_take_adamw():
+    c = small()
+    tokens = tokens_for(c)
+    params = init(c, tokens)
+    model = GlmMoeLM(c)
+    gamma = 0.001
+    adamw = optax.adamw(1e-3)
+
+    def loss_fn(p, t):
+        return glm_moe_fused_loss(model, p, t)
+
+    tx = glm_moe_optimizer(adamw, gamma)
+    step = build_gspmd_train_step(loss_fn, tx, donate=False, has_aux=True)
+    new, _, _, m = step(params, tx.init(params), tokens)
+    @jax.jit
+    def adamw_alone(p):
+        grads = jax.grad(lambda q: loss_fn(q, tokens)[0])(p)
+        updates, _ = adamw.update(grads, adamw.init(p), p)
+        return optax.apply_updates(p, updates)
+
+    plain = adamw_alone(params)
+    layers = ["Block_1", "Block_2", "mtp"]
+    for i, name in enumerate(layers):
+        tree = lambda t: (t[name]["block"] if name == "mtp"  # noqa: E731
+                          else t[name])["moe"]
+        counts = m["counts"][i].astype(jnp.float32)
+        want = tree(params)[ROUTER_BIAS] + gamma * jnp.sign(
+            counts.mean() - counts)
+        np.testing.assert_allclose(tree(new)[ROUTER_BIAS], want,
+                                   rtol=0, atol=1e-9)
+        assert float(jnp.abs(tree(new)[ROUTER_BIAS]).max()) == \
+            pytest.approx(gamma)
+        # the bias' surrogate term is worth nothing and touches no
+        # other leaf: the router's update is adamw's on the CE alone
+        np.testing.assert_allclose(tree(new)["router"],
+                                   tree(plain)["router"], rtol=1e-5,
+                                   atol=5e-6)
+    for (name, got), (_, exp) in zip(leaves_with_names(new),
+                                     leaves_with_names(plain)):
+        if ROUTER_BIAS not in name:
+            # adam's first step is lr * g / (|g| + eps): where g is
+            # nearly nothing two programs' rounding shows, well under
+            # one update (1e-3)
+            np.testing.assert_allclose(got, exp, rtol=1e-5, atol=5e-6,
+                                       err_msg=name)
+
+
+# -- (f) the rules table ------------------------------------------------------
+
+
+def test_rules_table_covers_every_leaf_and_splits_what_it_says():
+    from kungfu_tpu.analysis.shard_rules import check_coverage, check_mesh
+
+    registered = {"glm_moe": R.REGISTRY["glm_moe"]}
+    assert check_coverage(registered) == []
+    assert check_mesh(registered) == []
+    c = small()
+    params = init(c, tokens_for(c))
+    specs = R.plan(glm_moe_rules(), params, {"data": 1, "model": 2})
+    flat = {R.path_str(p): s for p, s in
+            jax.tree_util.tree_flatten_with_path(specs)[0]}
+    assert len(flat) == len(jax.tree_util.tree_leaves(params))
+    split = {p for p, s in flat.items() if "model" in str(s)}
+    assert "Block_1/MLAttention_0/q_b/kernel" in split
+    assert "Block_1/moe/w_down" in split and "mtp/block/moe/w_up" in split
+    assert "Block_1/moe/router" not in split and "lm_head" not in split
+    # on the one-chip mesh the adapter builds, placement is a no-op
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"))
+    placed = shard_params(params, mesh, glm_moe_rules())
+    assert jax.tree_util.tree_structure(placed) == \
+        jax.tree_util.tree_structure(params)
+
+
+# -- (h) the cell's rehearsal twin through the benchmark's command ------------
+
+
+def test_rehearsal_twin_runs_through_the_benchmark_command(tmp_path):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    out = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload",
+         "glm-4.7-flash.train-b1-t8192", "--seed", "3000000007",
+         "--seconds", "2", "--trace", "0", "--rehearse", "1"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [json.loads(line) for line in out.stdout.splitlines()
+             if line.startswith("{")]
+    result = lines[-1]
+    assert result["correct"] is False  # a rehearsal never is
+    assert result["failed"] == 0 and result["attempted"] > 0
+    window = next(x for x in lines if x.get("phase") == "window")
+    assert all(window["checks"].values()), window["checks"]
+    assert {"dropped_is_zero", "reference_objective",
+            "reference_gradients", "reference_route_counts"} <= set(
+        window["checks"])
+    counters = next(x for x in lines if x.get("phase") == "counters")
+    assert counters["dropped"] == 0
+    assert next(x for x in lines if x.get("phase") == "plan")[
+        "buffer_rows"] == 64 * 2

@@ -122,6 +122,59 @@ def test_flash_head_kernels_at_the_budgets_edge(one_chip, t, d, dtype):
                              s, s, s)) == 2
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_latent_attention_d256_t8192(one_chip, grad):
+    """The `glm-4.7-flash.train-b1-t8192` cell's call: one sequence of
+    8192, 20 heads of 256, bf16, causal. Past the head kernels
+    (`_HEAD_MAX_CHUNKS`), so the budget picks 1024 x 512 tiles on the
+    streaming grid; Mosaic takes all three kernels."""
+    from kungfu_tpu.ops import flash
+
+    plan = flash.flash_plan(8192, 256, dtype=jnp.bfloat16, causal=True)
+    assert (plan["block_q"], plan["block_k"]) == (1024, 512)
+    assert {plan[w]["scheme"] for w in ("fwd", "dq", "dkv")} == {"stream"}
+
+    def fwd(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True,
+                                     interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    shapes = _qkv(one_chip, b=1, t=8192, h=20, d=256)
+    assert _kernels(_compile(fn, *shapes)) == (3 if grad else 1)
+
+
+def test_grouped_expert_matmuls_published_widths(one_chip):
+    """The held experts' grouped SwiGLU at the cell's sizes: 8 experts
+    of 2048 x 1536 over the worst-case row buffer (8192 tokens x
+    min(4, 8) rows), forward and backward. XLA:TPU lowers each
+    `ragged_dot` to a Mosaic kernel that walks row tiles by group; if
+    a later JAX expands it into dense masked matmuls this count
+    falls."""
+    from kungfu_tpu.parallel.grouped_moe import buffer_rows, grouped_swiglu
+
+    rows, h, f, held = buffer_rows(8192, 4, (0, 8)), 2048, 1536, 8
+    assert rows == 32768
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        return grouped_swiglu(x, w_gate, w_up, w_down, sizes).astype(
+            jnp.float32).sum()
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3)),
+        arg((rows, h), jnp.bfloat16), arg((held, h, f), jnp.float32),
+        arg((held, h, f), jnp.float32), arg((held, f, h), jnp.float32),
+        arg((held,), jnp.int32))
+    # three forward, three input-gradient and three weight-gradient
+    # grouped matmuls (beside the kernels that lay out their tiles)
+    assert compiled.as_text().count("ragged-dot") >= 9
+
+
 def test_flash_window_16k(one_chip):
     from kungfu_tpu.ops.flash import flash_attention
 
