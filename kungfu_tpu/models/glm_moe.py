@@ -51,11 +51,17 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
+from ..ops.flash import FLASH_LSE, FLASH_OUT, flash_attention, flash_plan
 from ..parallel import grouped_moe as gm
 from ..trace.scopes import MLA, MOE_EXPERTS, MOE_ROUTE, MTP
 
 ROUTER_BIAS = "router_bias"  # the leaf `glm_moe_optimizer` sets apart
+# `checkpoint_name`s `MLAttention` sets for the blocks' recomputation
+# (`_KEPT`): q, k and v as they enter attention, and the `o` projection
+MLA_QKV = "kf.mla_qkv"
+MLA_O = "kf.mla_o"
 
 
 @dataclass(frozen=True)
@@ -84,7 +90,7 @@ class GlmMoeConfig:
     mtp_lambda: float = 0.3
     dtype: Any = jnp.bfloat16
     attention: str = "local"            # local | flash
-    remat: bool = False                 # recompute each block backward
+    remat: bool = False     # recompute each block backward, but `_KEPT`
 
     def __post_init__(self):
         first, count = self.held
@@ -165,15 +171,15 @@ class MLAttention(nn.Module):
              jnp.broadcast_to(k_rope[:, :, None, :],
                               k_rope.shape[:2] + (h, rope))], axis=-1)
         v = kv_up[..., nope:]
+        q, k, v = (checkpoint_name(x, MLA_QKV) for x in (q, k, v))
         if c.attention == "flash":
-            from ..ops.flash import flash_attention
-
             out = flash_attention(q, k, v, causal=True)
         else:
             from ..parallel.sequence import _local_attention
 
             out = _local_attention(q, k, v, causal=True)
-        return _dense(c.hidden_size, c, "o", axis=(-2, -1))(out)
+        return checkpoint_name(
+            _dense(c.hidden_size, c, "o", axis=(-2, -1))(out), MLA_O)
 
 
 class SwiGLU(nn.Module):
@@ -253,9 +259,41 @@ class Block(nn.Module):
         return x + y, aux
 
 
+# what a recomputed block keeps beside its input (`remat_plan` has the
+# bytes): the two residuals of flash's backward that only its forward
+# kernel can produce, so that no kernel runs twice, and what attention
+# reads and hands on, so that of the latent projections only the two
+# down-projections and their norms run again. Each name paid for its
+# bytes on the chip (PERF.md section 6, PR 28).
+_KEPT = (FLASH_OUT, FLASH_LSE, MLA_QKV, MLA_O)
+
+
 def _block(c: GlmMoeConfig, expert: bool, name: str):
-    cls = nn.remat(Block) if c.remat else Block
+    cls = nn.remat(Block, policy=jax.checkpoint_policies
+                   .save_only_these_names(*_KEPT)) if c.remat else Block
     return cls(c, expert, name=name)
+
+
+def remat_plan(c: GlmMoeConfig, batch: int, seq: int):
+    """What the blocks' recomputation holds from forward to backward
+    beside each block's input, by name and in bytes (the counterpart of
+    `ops.flash.flash_plan`; `jax.ad_checkpoint.saved_residuals` is what
+    the tests hold it to). Nothing without `remat`; flash's two names
+    only where attention runs the kernel (the plain path sets none)."""
+    kept = {}
+    if c.remat:
+        rows, isz = batch * seq * c.num_heads, jnp.dtype(c.dtype).itemsize
+        qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+        if c.attention == "flash" and "fwd" in flash_plan(
+                seq, c.v_head_dim, dtype=c.dtype, causal=True):
+            kept = {FLASH_OUT: rows * c.v_head_dim * isz,
+                    FLASH_LSE: rows * 4}
+        kept[MLA_QKV] = rows * (2 * qk + c.v_head_dim) * isz
+        kept[MLA_O] = batch * seq * c.hidden_size * isz
+    blocks = c.num_layers + c.num_nextn_predict_layers
+    per_block = sum(kept.values())
+    return {"names": tuple(kept), "bytes_per_block": per_block,
+            "blocks": blocks, "total_bytes": per_block * blocks}
 
 
 class MTPModule(nn.Module):

@@ -68,7 +68,10 @@ kernel's first pass — dq already streams dO, so the separate XLA
 reduction and its extra full read of dO/O are gone; dq emits the
 per-row delta for the dkv kernel to consume. Residuals stay at the
 input dtype end to end (bf16 in, bf16 residuals; only the [B*H, T]
-lse/delta row vectors are f32).
+lse/delta row vectors are f32). The output and the lse, which only
+the forward kernel can produce, carry `checkpoint_name`s (`FLASH_OUT`,
+`FLASH_LSE`): a `jax.checkpoint` whose policy saves them runs no forward
+kernel twice; without such a policy a name is an identity.
 
 `flash_attention` falls back to the plain jnp implementation when
 shapes don't tile (T % block != 0) or on backends without Mosaic
@@ -83,10 +86,16 @@ import os
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
+
+# `checkpoint_name`s of the forward's output and lse as the backward's
+# residuals (`_flash_fwd`), for a recomputing caller's policy to keep
+FLASH_OUT = "kf.flash_out"
+FLASH_LSE = "kf.flash_lse"
 
 # Mosaic's scoped-vmem stack limit is 16 MB; 15 MB leaves scheduling
 # headroom (same calibration rationale as ops/fused_ce.py). The
@@ -1353,6 +1362,9 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     # lse where the output should be — see test_ulysses_flash_grads).
     b, t, h, d = q.shape
     lse4 = _unbh(lse[..., None], b, h)  # [B*H, T, 1] -> [B, T, H, 1]
+    # the named `out` is the primal too: residual and value are one
+    out = checkpoint_name(out, FLASH_OUT)
+    lse4 = checkpoint_name(lse4, FLASH_LSE)
     return out, (q, k, v, out, lse4)
 
 

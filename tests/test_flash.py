@@ -423,3 +423,35 @@ def test_bf16_kernel_no_worse_than_the_models_plain_bf16_attention():
             q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1]))
         for name, f, p in zip("out dq dk dv".split(), flash, plain):
             assert f <= 1.5 * p, (blocks, name, f, p)
+
+
+@pytest.mark.parametrize("scheme", [None, "resident", "stream"],
+                         ids=["head", "resident", "stream"])
+def test_checkpoint_names_leave_nothing_outside_a_checkpoint(
+        monkeypatch, scheme):
+    """`FLASH_OUT` / `FLASH_LSE` are for a recomputing caller's policy
+    (`models/glm_moe.py::_block`). With no `jax.checkpoint` above the
+    call, which is how the GPT cells and Ulysses run it, the lowered
+    gradient is the same text with and without them (but for the
+    counter in a private function's symbol, `@_where_58`)."""
+    import re
+
+    from kungfu_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_FORCE_SCHEME", scheme)
+    q, k, v = qkv(t=512, dtype=jnp.bfloat16)
+    assert flash.flash_plan(512, 32, dtype=q.dtype, causal=True)["fwd"][
+        "scheme"] == (scheme or "head")
+
+    def lowered():
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True).astype(
+                jnp.float32).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, k, v).as_text()
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    named = lowered()
+    monkeypatch.setattr(flash, "checkpoint_name", lambda x, name: x)
+    assert lowered() == named

@@ -17,12 +17,17 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax._src.ad_checkpoint import remat_p, saved_residuals
 
-from kungfu_tpu.models.glm_moe import (ROUTER_BIAS, ExpertFFN, GlmMoeConfig,
-                                       GlmMoeLM, MLAttention,
-                                       glm_moe_fused_loss, glm_moe_logits,
-                                       glm_moe_optimizer)
+from kungfu_tpu.models import glm_moe
+from kungfu_tpu.models.glm_moe import (MLA_O, MLA_QKV, ROUTER_BIAS, Block,
+                                       ExpertFFN, GlmMoeConfig, GlmMoeLM,
+                                       MLAttention, glm_moe_fused_loss,
+                                       glm_moe_logits, glm_moe_optimizer,
+                                       remat_plan)
 from kungfu_tpu.models import glm_moe_reference as ref
+from kungfu_tpu.ops import flash
+from kungfu_tpu.ops.flash import FLASH_LSE, FLASH_OUT
 from kungfu_tpu.parallel import (build_gspmd_train_step, glm_moe_rules,
                                  shard_params)
 from kungfu_tpu.parallel import rules as R
@@ -254,6 +259,137 @@ def test_mla_through_flash_at_head_size_256_matches_the_plain_path():
 def test_flash_needs_one_head_size():
     with pytest.raises(ValueError, match="one head size"):
         small(attention="flash", v_head_dim=64)
+
+
+# -- (d') what a recomputed block keeps, by name ------------------------------
+
+
+def flash_case(dtype=jnp.float32, **kw):
+    """Two blocks (one dense, one expert) through the flash kernels
+    (T 512: the head scheme), recomputed; no MTP module and a
+    plain-path head, so every `pallas_call` in the program is flash's.
+    Returns (config, tokens, loss of the parameters)."""
+    c = small(**{**dict(attention="flash", remat=True, num_layers=2,
+                        num_nextn_predict_layers=0, dtype=dtype), **kw})
+    tokens = tokens_for(c, (1, 512))
+    model = GlmMoeLM(c)
+    return c, tokens, lambda p: glm_moe_fused_loss(model, p, tokens)[0]
+
+
+@pytest.fixture(scope="module")
+def flash_params():
+    c, tokens, _ = flash_case()
+    return init(c, tokens)
+
+
+@pytest.fixture
+def bare_remat(monkeypatch):
+    """The blocks under `nn.remat` with no policy, as before PR 28."""
+    import flax.linen as nn
+
+    monkeypatch.setattr(
+        glm_moe, "_block", lambda c, expert, name: nn.remat(Block)(
+            c, expert, name=name))
+
+
+def kernel_calls(jaxpr, inside=()):
+    """(kernel function, the primitives it sits under) of every
+    `pallas_call` in a jaxpr, sub-jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["jaxpr"].debug_info.func_name, inside))
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += kernel_calls(sub, inside + (eqn.primitive.name,))
+    return found
+
+
+@pytest.mark.parametrize("policy", ["names", "bare"])
+@pytest.mark.parametrize("scheme, fwd, bwd", [
+    (None, "_fwd_head_kernel", ["_bwd_head_kernel"]),
+    ("stream", "_kernel", ["_bwd_dq_kernel", "_bwd_dkv_kernel"]),
+], ids=["head", "stream"])
+def test_recomputed_blocks_run_flash_forward_once(
+        monkeypatch, request, flash_params, scheme, fwd, bwd, policy):
+    monkeypatch.setattr(flash, "_FORCE_SCHEME", scheme)
+    if policy == "bare":
+        request.getfixturevalue("bare_remat")
+    c, _, loss = flash_case()
+    calls = kernel_calls(
+        jax.make_jaxpr(jax.grad(loss))(flash_params).jaxpr)
+    recomputed = [k for k, inside in calls if remat_p.name in inside]
+    first = [k for k, inside in calls if remat_p.name not in inside]
+    assert first == [fwd] * c.num_layers
+    # the backward of a recomputed block is inside its checkpoint; so
+    # is the second forward, unless the policy kept what it would make
+    again = [fwd] if policy == "bare" else []
+    assert sorted(recomputed) == sorted((again + bwd) * c.num_layers)
+
+
+@pytest.mark.parametrize("case, names, arrays", [
+    ("flash", (FLASH_OUT, FLASH_LSE, MLA_QKV, MLA_O), 6),
+    ("local", (MLA_QKV, MLA_O), 4),
+    ("kept", (), 0),
+])
+def test_remat_plan_is_what_jax_keeps(request, flash_params, case, names,
+                                      arrays):
+    """`remat_plan` against `saved_residuals`: with the policy each
+    block keeps the lse, the kernel's output, q, k, v and the `o`
+    projection (a block: `arrays`) and NOTHING else beyond what a bare
+    `nn.remat` keeps. JAX lists a kept value that the forward also
+    reads under the `reduce_precision` it puts on it, so only the lse
+    shows by name and the rest is held to its bytes."""
+    kw = {"local": dict(attention="local"), "kept": dict(remat=False)}
+    c, tokens, loss = flash_case(**kw.get(case, {}))
+    plan = remat_plan(c, *tokens.shape)
+    assert plan["names"] == names and plan["blocks"] == 2
+    assert plan["total_bytes"] == 2 * plan["bytes_per_block"]
+    if not names:
+        assert plan["total_bytes"] == 0
+        return
+
+    def held(res):
+        return sorted((a.str_short(), a.size * a.dtype.itemsize)
+                      for a, why in res if "from the argument" not in why)
+
+    res = saved_residuals(loss, flash_params)
+    assert sum(f"named '{FLASH_LSE}'" in why for _, why in res) == (
+        2 if FLASH_LSE in names else 0)
+    request.getfixturevalue("bare_remat")
+    bare = saved_residuals(loss, flash_params)
+    heads = (*tokens.shape, c.num_heads, c.v_head_dim)
+    assert not any(a.shape == heads or "named" in why for a, why in bare)
+    extra = held(res)
+    for item in held(bare):
+        extra.remove(item)
+    assert len(extra) == 2 * arrays
+    assert sum(size for _, size in extra) == plan["total_bytes"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_keeping_the_names_changes_no_bit(request, flash_params, dtype):
+    """The backward reads the output and lse the first forward wrote
+    where it read a second, identical pair. Identical where a kernel
+    is a kernel: interpret mode inlines it, and XLA:CPU then carries
+    bf16 values in f32 through whichever fusions it forms, differently
+    in the recomputed forward than in the first (`jax` PR 22244), so
+    the comparison is compiled without that licence."""
+    _, _, loss = flash_case(dtype)
+
+    def run():
+        return jax.jit(jax.value_and_grad(loss)).lower(
+            flash_params).compile(compiler_options={
+                "xla_allow_excess_precision": False})(flash_params)
+
+    value, grads = run()
+    request.getfixturevalue("bare_remat")
+    value_bare, grads_bare = run()
+    assert float(value) == float(value_bare)
+    for (name, got), (_, exp) in zip(leaves_with_names(grads),
+                                     leaves_with_names(grads_bare)):
+        assert bool((got == exp).all()), name
 
 
 # -- (e) the selection bias rides in tx ---------------------------------------
