@@ -425,10 +425,8 @@ def strategy_sweep_main(args) -> None:
 
     The reference's core differentiator (pluggable all-reduce graphs)
     had never been benchmarked head-to-head in this repo; this
-    publishes the np in {2,3,4} x {STAR..MULTI_BINARY_TREE_STAR} rows
-    to BASELINE (``allreduce_strategy_catalog``) and, with --publish,
-    emits the round's BENCH_rNN.json so the run-all.sh round gate
-    stays green.
+    prints the np in {2,3,4} x {STAR..MULTI_BINARY_TREE_STAR} rows
+    and the best strategy per np (``allreduce_strategy_catalog``).
     """
     strategies = [s for s in STRATEGIES if s != "AUTO"]
     rows = []
@@ -455,26 +453,6 @@ def strategy_sweep_main(args) -> None:
                                     "seconds")} for r in rows],
     }
     print(json.dumps(result), flush=True)
-    if args.publish:
-        from .publish import publish_result
-
-        overall = max(rows, key=lambda r: r["rate_gbps"])
-        publish_result(
-            "allreduce_strategy_catalog", result,
-            parsed={
-                "metric": "allreduce_strategy_catalog_best_rate",
-                "value": overall["rate_gbps"],
-                "unit": "GB/s (ring-equivalent formula)",
-                "details": {
-                    "best": {k: overall[k]
-                             for k in ("np", "strategy", "rate_gbps")},
-                    "np": sorted({r["np"] for r in rows}),
-                    "strategies": strategies,
-                    "caveat": "1-core loopback; see BASELINE.md",
-                },
-            },
-            cmd=("python -m kungfu_tpu.benchmarks.allreduce "
-                 "--strategy-sweep --publish"))
 
 
 def main():
@@ -494,13 +472,10 @@ def main():
     ap.add_argument("--strategies", default="RING,BINARY_TREE_STAR,AUTO")
     ap.add_argument("--port-range", default="11000-12500")
     # full-catalog head-to-head (docs/collectives.md): np x all seven
-    # concrete strategies, BASELINE + BENCH_rNN via --publish
+    # concrete strategies
     ap.add_argument("--strategy-sweep", action="store_true",
                     help="driver: sweep the whole strategy catalog "
                          "head-to-head instead of --strategies")
-    ap.add_argument("--publish", action="store_true",
-                    help="with --strategy-sweep: merge into "
-                         "BASELINE.json + emit BENCH_rNN.json")
     # gradient-pipeline benchmark (docs/grad_pipeline.md):
     # {lump, bucketed} x {none, bf16, int8} with a simulated backward
     ap.add_argument("--grad-pipeline", action="store_true",

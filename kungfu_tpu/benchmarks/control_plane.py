@@ -2,9 +2,9 @@
 
 The replicated config tier (elastic/replica.py, docs/control_plane.md)
 buys survival of PERMANENT leader loss with replicate-before-ack
-delta-log replication. This module prices both sides of that trade and
-publishes the BASELINE `control_plane_replicated` +
-`control_plane_router` rows:
+delta-log replication. This module prices both sides of that trade
+(the frozen BASELINE `control_plane_replicated` / `control_plane_router`
+rows are its output):
 
 - **Replication cost vs replica count {1, 2, 3}**: membership-op
   latency (p50/p99 of `/addworker`//`/removeworker` round trips at the
@@ -44,7 +44,7 @@ publishes the BASELINE `control_plane_replicated` +
 
 Usage:  python -m kungfu_tpu.benchmarks.control_plane
             [--runs 3] [--ops 40] [--submits 120] [--lease-ms 300]
-            [--json] [--publish]
+            [--json]
 """
 
 from __future__ import annotations
@@ -735,9 +735,6 @@ def main(argv=None) -> int:
                     help="tier lease (the detect phase's knob)")
     ap.add_argument("--json", action="store_true",
                     help="emit one machine-readable JSON line")
-    ap.add_argument("--publish", action="store_true",
-                    help="merge into BASELINE.json and emit the "
-                         "round's BENCH_rNN.json")
     args = ap.parse_args(argv)
 
     cost: Dict[str, Dict[str, float]] = {}
@@ -876,96 +873,6 @@ def main(argv=None) -> int:
               f"{takeover['mid_resize']['mttr_ms']} ms; admissions/s "
               f"1->3 replicas {cost['1']['admissions_per_s']} -> "
               f"{cost['3']['admissions_per_s']}", flush=True)
-    if args.publish:
-        from .publish import publish_result
-
-        publish_result(
-            "control_plane_router",
-            {"benchmark": "control_plane_router",
-             "lease_ms": args.lease_ms,
-             "router": router, "router_chaos": router_chaos,
-             "router_scaling": router_scaling,
-             "note": result["note"]},
-            parsed={
-                "metric": "cp_router_admissions_per_s",
-                "value": router["2"]["admissions_per_s"],
-                "unit": ("admissions/s through 2 stateless routers "
-                         "coalescing into a 3-replica group-commit "
-                         "tier, 8-way concurrent burst"),
-                "details": {
-                    "routers_1": router["1"]["admissions_per_s"],
-                    "routers_2": router["2"]["admissions_per_s"],
-                    "router_scaling": router_scaling,
-                    "chaos_kill_admissions_per_s":
-                        router_chaos["admissions_per_s"],
-                    "chaos_kill_dropped": router_chaos["dropped"],
-                    "caveat": "1-core loopback; see BASELINE.md",
-                },
-            },
-            cmd=("python -m kungfu_tpu.benchmarks.control_plane "
-                 "--publish"))
-        publish_result(
-            "control_plane_replicated", result,
-            parsed={
-                "metric": "cp_leader_death_mid_resize_mttr_ms",
-                "value": takeover["mid_resize"]["mttr_ms"],
-                "unit": ("median ms, permanent leader kill riding a "
-                         "live /addworker -> first client write "
-                         "served by the new leader (3 replicas, "
-                         f"lease {args.lease_ms:.0f} ms)"),
-                "details": {
-                    "mid_traffic_mttr_ms":
-                        takeover["mid_traffic"]["mttr_ms"],
-                    "detect_ms": takeover["mid_resize"]["detect_ms"],
-                    "election_ms":
-                        takeover["mid_resize"]["election_ms"],
-                    "catchup_ms":
-                        takeover["mid_resize"]["catchup_ms"],
-                    "admissions_per_s_1_2_3": [
-                        cost["1"]["admissions_per_s"],
-                        cost["2"]["admissions_per_s"],
-                        cost["3"]["admissions_per_s"]],
-                    "admissions_per_s_n3_no_batch":
-                        no_batch["admissions_per_s"],
-                    "group_commit_speedup": group_commit_speedup,
-                    "source": source,
-                    "caveat": "1-core loopback; see BASELINE.md",
-                },
-            },
-            cmd=("python -m kungfu_tpu.benchmarks.control_plane "
-                 "--publish"))
-        publish_result(
-            "control_plane_durability",
-            {"benchmark": "control_plane_durability",
-             "lease_ms": args.lease_ms,
-             "durability": durability, "fsync_cost": fsync_cost,
-             "recovery": recovery, "tier_death": tier_death,
-             "note": result["note"]},
-            parsed={
-                "metric": "cp_wal_fsync_admissions_per_s",
-                "value": durability["fsync_on"]["admissions_per_s"],
-                "unit": ("admissions/s into a 3-replica tier with "
-                         "every replica fsyncing its WAL once per "
-                         "group-commit window, 8-way concurrent "
-                         "burst"),
-                "details": {
-                    "fsync_off_admissions_per_s":
-                        durability["fsync_off"]["admissions_per_s"],
-                    "memory_only_admissions_per_s":
-                        cost["3"]["admissions_per_s"],
-                    "fsync_cost_x": fsync_cost,
-                    "recovery": recovery,
-                    "tier_death_mttr_ms": tier_death["mttr_ms"],
-                    "tier_death_decomposition": {
-                        k: tier_death[k]
-                        for k in ("relaunch_ms", "replay_ms",
-                                  "election_ms", "catchup_ms",
-                                  "first_request_ms")},
-                    "caveat": "1-core loopback; see BASELINE.md",
-                },
-            },
-            cmd=("python -m kungfu_tpu.benchmarks.control_plane "
-                 "--publish"))
     return 0
 
 

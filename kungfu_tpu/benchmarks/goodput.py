@@ -1,4 +1,4 @@
-"""Goodput under churn: replay the standard trace suite, publish the
+"""Goodput under churn: replay the standard trace suite, print the
 decomposition.
 
 The operator-facing benchmark ROADMAP item 4 asks for: every canned
@@ -11,7 +11,7 @@ run's merged flight-recorder stream is decomposed by
 `trace.goodput.decompose` into the phase taxonomy
 (docs/observability.md). Every cell gates on the decomposition
 invariant — phases must sum to rank-active wallclock within
-tolerance — so a published goodput number can never silently ride an
+tolerance — so a goodput number can never silently ride an
 incomplete trace.
 
 The policy cell replays `straggler_transient` twice — under
@@ -27,8 +27,6 @@ Orchestrator (the only mode; every cell is a multi-process kfrun
 cluster):
 
   python -m kungfu_tpu.benchmarks.goodput --np 2 3 4
-  python -m kungfu_tpu.benchmarks.goodput --publish   # -> BASELINE.json
-                                                      #    + BENCH_rNN.json
 
 1-core-container caveat (BASELINE.md): all np workers + runner +
 config server timeshare ONE core, so wire/hook waits include core
@@ -189,34 +187,9 @@ def main(argv=None) -> int:
                     choices=list(SCENARIOS),
                     help="canned scenarios to replay")
     ap.add_argument("--port-base", type=int, default=27100)
-    ap.add_argument("--publish", action="store_true",
-                    help="merge the result into BASELINE.json and "
-                         "emit the round's BENCH_rNN.json")
-    ap.add_argument("--json", default="", help="path to BASELINE.json")
     args = ap.parse_args(argv)
 
-    result = run_goodput(args)
-    line = json.dumps(result)
-    print(line, flush=True)
-    if args.publish:
-        from .publish import publish_result
-
-        publish_result(
-            "goodput_under_churn", result,
-            parsed={
-                "metric": "scenario_goodput_ratio_mean",
-                "value": result["mean_goodput_ratio"],
-                "unit": "useful-compute fraction of rank-active wall",
-                "details": {
-                    "scenarios": args.scenarios,
-                    "np": args.np,
-                    "goodput_policy_wins": result[
-                        "policy_comparison"]["goodput_policy_wins"],
-                    "caveat": "1-core container; see BASELINE.md",
-                },
-            },
-            cmd="python -m kungfu_tpu.benchmarks.goodput --publish",
-            json_path=args.json)
+    print(json.dumps(run_goodput(args)), flush=True)
     return 0
 
 

@@ -139,8 +139,8 @@ def measure_lm_rate(size: str = "small", batch: int = 8, seq: int = 1024,
     upcast = optax.stateless(
         lambda updates, _: jax.tree_util.tree_map(
             lambda u: u.astype(jnp.float32), updates))
-    # per-leaf adamw: the flat-buffer variant (optimizers/fused.py)
-    # was profiled and REGRESSED the step 108.5 -> 131.1 ms on v5e
+    # per-leaf adamw: a whole-tree flat-buffer variant was profiled
+    # and REGRESSED the step 108.5 -> 131.1 ms on v5e
     # (concat lowers to a serial DUS loop + per-leaf relayouts); see
     # docs/benchmarks.md round-5 attribution
     tx = optax.chain(upcast, optax.adamw(1e-4))

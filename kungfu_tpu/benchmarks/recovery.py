@@ -2,8 +2,8 @@
 
 The chaos engine SIGKILLs one worker at a scheduled step inside a real
 kfrun -recover cluster (the same harness the failure-injection tests
-drive); this module decomposes the recovery timeline and publishes the
-breakdown VERDICT r5 item 7 asked for on the elastic path:
+drive); this module decomposes the recovery timeline on the elastic
+path:
 
     crash ──detect──▶ runner notices the death        (supervisor poll)
           ──propose─▶ shrunken stage PUT to config server
@@ -16,7 +16,7 @@ breakdown VERDICT r5 item 7 asked for on the elastic path:
 Usage:  python -m kungfu_tpu.benchmarks.recovery [--runs 3]
             [--np 3] [--crash-rank 1] [--crash-step 5] [--json]
         python -m kungfu_tpu.benchmarks.recovery --hier-matrix
-            [--runs 3] [--publish]
+            [--runs 3]
 
 ``--hier-matrix`` is the topology-aware death matrix (BASELINE
 `failure_recovery_mttr_hier`): np=4 over TWO emulated hosts
@@ -26,7 +26,7 @@ every leaf on its host loses its ring peer and the inter-host edge),
 a LEAF (rank 3 — the smallest blast radius), and a WHOLE HOST (the
 ``crash_host`` chaos fault — master, leaves and rings at once; the
 host's runner reaps the burst as ONE shrunken proposal). Each shape
-publishes the same kftrace-decomposed phase rows as the flat np=3
+prints the same kftrace-decomposed phase rows as the flat np=3
 benchmark, so the hierarchy's failure cost is attributable per role.
 
 Every phase is attributable to a mechanism with a knob: `detect` is the
@@ -226,27 +226,6 @@ def hier_matrix_main(args) -> int:
         "rows": rows,
     }
     print(json.dumps(result), flush=True)
-    if args.publish:
-        from .publish import publish_result
-
-        publish_result(
-            "failure_recovery_mttr_hier", result,
-            parsed={
-                "metric": "hier_host_death_mttr_ms",
-                "value": rows["host_death"]["mttr_ms"],
-                "unit": ("median ms, whole-host SIGKILL -> first "
-                         "post-recovery collective (np=4, hier+shm, "
-                         "two emulated hosts)"),
-                "details": {
-                    "master_death_mttr_ms":
-                        rows["master_death"]["mttr_ms"],
-                    "leaf_death_mttr_ms": rows["leaf_death"]["mttr_ms"],
-                    "source": source,
-                    "caveat": "1-core loopback; see BASELINE.md",
-                },
-            },
-            cmd=("python -m kungfu_tpu.benchmarks.recovery "
-                 "--hier-matrix --publish"))
     return 0
 
 
@@ -267,9 +246,6 @@ def main(argv=None) -> int:
                     help="master/leaf/whole-host death MTTR at np=4 "
                          "over two emulated hosts under KF_HIER=1 "
                          "(BASELINE failure_recovery_mttr_hier)")
-    ap.add_argument("--publish", action="store_true",
-                    help="with --hier-matrix: merge into BASELINE.json"
-                         " and emit the round's BENCH_rNN.json")
     args = ap.parse_args(argv)
     if args.hier_matrix:
         return hier_matrix_main(args)

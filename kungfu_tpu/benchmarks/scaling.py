@@ -40,10 +40,9 @@ def transport_matrix_main(args) -> int:
     (127.0.0.1 + 127.0.0.2) run the per-step fp32 gradient all-reduce
     as a post-backward lump under STAR, each cell pinning one wire
     class for the colocated pairs and flat-vs-hierarchical graphs.
-    Publishes exposed comm, step wall, and the link-class egress split
+    Prints exposed comm, step wall, and the link-class egress split
     — "socket egress drops, exposed comm shrinks" is the claim under
-    test. With --publish: BASELINE.json ``hier_collectives`` +
-    BENCH_rNN.json.
+    test (``hier_collectives``).
     """
     from .allreduce import TRANSPORT_ENV, run_grad_one, two_host_spec
 
@@ -84,36 +83,6 @@ def transport_matrix_main(args) -> int:
                    "egress_by_link_mb_per_step")} for r in rows],
     }
     print(json.dumps(result), flush=True)
-    if args.publish:
-        from .publish import publish_result
-
-        by = {(r["np"], r["mode"], r["transport"]): r for r in rows}
-        mid = sorted(sizes)[len(sizes) // 2] if len(sizes) > 1 \
-            else sizes[0]
-        flat = by[(mid, "flat", "tcp")]
-        hier = by[(mid, "hier", "shm")]
-        publish_result(
-            "hier_collectives", result,
-            parsed={
-                "metric": "hier_shm_exposed_comm_vs_flat_tcp",
-                "value": round(hier["exposed_comm_ms"]
-                               / max(1e-9, flat["exposed_comm_ms"]),
-                               3),
-                "unit": (f"np={mid} fp32-lump exposed-comm ratio "
-                         "(hier+shm / flat+tcp; <1 = faster)"),
-                "details": {
-                    "flat_tcp_exposed_ms": flat["exposed_comm_ms"],
-                    "hier_shm_exposed_ms": hier["exposed_comm_ms"],
-                    "flat_socket_egress_mb":
-                        flat["socket_egress_mb_per_step"],
-                    "hier_socket_egress_mb":
-                        hier["socket_egress_mb_per_step"],
-                    "np": sizes,
-                    "caveat": "1-core loopback; see BASELINE.md",
-                },
-            },
-            cmd=("python -m kungfu_tpu.benchmarks.scaling --dcn-grad "
-                 "--transport-matrix --publish"))
     return 0
 
 
@@ -177,9 +146,6 @@ def main(argv=None) -> int:
                     help="with --dcn-grad: np x {flat,hier} x "
                          "{tcp,unix,shm} over two simulated hosts "
                          "(docs/collectives.md)")
-    ap.add_argument("--publish", action="store_true",
-                    help="with --transport-matrix: merge into "
-                         "BASELINE.json + emit BENCH_rNN.json")
     args = ap.parse_args(argv)
 
     if args.dcn_grad and args.transport_matrix:

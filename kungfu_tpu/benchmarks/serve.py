@@ -24,7 +24,6 @@ violations, so the number cannot be bought by dropping work.
 
   python -m kungfu_tpu.benchmarks.serve                # the matrix
   python -m kungfu_tpu.benchmarks.serve --np 1 2       # subset
-  python -m kungfu_tpu.benchmarks.serve --publish      # -> BASELINE
 
 1-core loopback caveat (BASELINE.md): every replica shares one CPU
 core with the config server and each other, so ABSOLUTE latencies are
@@ -252,9 +251,6 @@ def main(argv=None) -> int:
     ap.add_argument("--gen-len", type=int, default=48)
     ap.add_argument("--timeout", type=int, default=420)
     ap.add_argument("--port-base", type=int, default=28100)
-    ap.add_argument("--publish", action="store_true",
-                    help="merge into BASELINE.json and emit the "
-                         "round's BENCH file (publish.py protocol)")
     args = ap.parse_args(argv)
     res = measure(tuple(args.np), requests=args.requests,
                   gen_len=args.gen_len, port_base=args.port_base,
@@ -285,45 +281,6 @@ def main(argv=None) -> int:
                       "value": res["resize_cell"]["p99_ms"],
                       "unit": "ms (p99 through mid-traffic resize)",
                       "details": result}), flush=True)
-    if args.publish:
-        from kungfu_tpu.benchmarks.publish import publish_result
-
-        prefix = res["prefix_cell"]
-        # the per-np timing decomposition goes INTO the published row:
-        # "where did the wall time go" (control_share is the headline —
-        # the router/group-commit work is judged by driving it down)
-        breakdown = {
-            f"np{r['np']}": {
-                "control_share": r["timing"]["control_share"],
-                "decode_s": round(r["timing"]["decode_ms"] / 1e3, 2),
-                "prefill_s": round(r["timing"]["prefill_ms"] / 1e3, 2),
-                "control_s": round(r["timing"]["control_ms"] / 1e3, 2),
-                "peak_blocks": r["timing"]["peak_blocks"],
-                "tokens_per_sec": r["tokens_per_sec"],
-            } for r in res["cells"]
-        }
-        # the breakdown also rides the BASELINE row: BENCH_rNN.json is
-        # one-headline-per-round and a later publisher overwrites it,
-        # but the BASELINE row is per-metric and persists
-        result["timing_breakdown"] = breakdown
-        publish_result(
-            "serve_elastic_latency", result,
-            parsed={"metric": "serve_p99_through_resize_ms",
-                    "value": res["resize_cell"]["p99_ms"],
-                    "unit": "ms",
-                    "timing_breakdown": breakdown,
-                    "tokens_per_sec_np2":
-                        next((r["tokens_per_sec"] for r in
-                              res["cells"] if r["np"] == 2), None),
-                    "prefix_tokens_per_sec_on":
-                        prefix["sharing_on"]["tokens_per_sec"],
-                    "prefix_tokens_per_sec_off":
-                        prefix["sharing_off"]["tokens_per_sec"],
-                    "prefix_peak_blocks_on":
-                        prefix["sharing_on"]["peak_blocks"],
-                    "prefix_peak_blocks_off":
-                        prefix["sharing_off"]["peak_blocks"]},
-            cmd="python -m kungfu_tpu.benchmarks.serve --publish")
     return 0
 
 

@@ -146,37 +146,43 @@ def _launch_cell(np_, strategy, straggler_ms, steps, batch,
     ]
     out = subprocess.run(cmd, env=env, capture_output=True, text=True,
                          timeout=timeout)
-    rates = {}
+    by_rank = {}  # rank -> that worker's MARKER record
     for line in (out.stdout + out.stderr).splitlines():
         pos = line.find(MARKER)
         if pos >= 0:
             r = json.loads(line[pos + len(MARKER):])
-            rates[r["rank"]] = r["samples_per_sec"]
-    if out.returncode != 0 or len(rates) != np_:
+            by_rank[r["rank"]] = r
+    if out.returncode != 0 or len(by_rank) != np_:
         raise RuntimeError(
             f"straggler cell {strategy}/{straggler_ms}ms failed "
-            f"rc={out.returncode}, {len(rates)}/{np_} results:\n"
+            f"rc={out.returncode}, {len(by_rank)}/{np_} results:\n"
             f"{out.stdout[-3000:]}\n{out.stderr[-1000:]}")
-    return rates
+    return by_rank
 
 
 def measure(np_=8, straggler_ms=100, steps=40, batch=64,
             strategies=("sync", "pair", "sma"),
             port_range="29100-29999", timeout=900):
-    """Returns {strategy: {"clean": rate, "straggler": rate,
-    "retention": straggler/clean}} — cluster samples/sec summed over
-    workers, worst case one straggler sleeping `straggler_ms`/step."""
+    """Returns {strategy: {"clean_samples_per_sec": rate,
+    "straggler_samples_per_sec": rate, "retention": straggler/clean,
+    "clean_wall_s": {rank: s}, "straggler_wall_s": {rank: s}}} —
+    cluster samples/sec summed over workers, worst case one straggler
+    (rank 0) sleeping `straggler_ms`/step; wall_s is each worker's own
+    clock over its timed steps."""
     results = {}
     for strategy in strategies:
         clean = _launch_cell(np_, strategy, 0, steps, batch,
                              port_range, timeout)
         slow = _launch_cell(np_, strategy, straggler_ms, steps, batch,
                             port_range, timeout)
-        c, s = sum(clean.values()), sum(slow.values())
+        c, s = (sum(r["samples_per_sec"] for r in cell.values())
+                for cell in (clean, slow))
         results[strategy] = {
             "clean_samples_per_sec": round(c, 1),
             "straggler_samples_per_sec": round(s, 1),
             "retention": round(s / c, 4),
+            "clean_wall_s": {k: r["wall_s"] for k, r in clean.items()},
+            "straggler_wall_s": {k: r["wall_s"] for k, r in slow.items()},
         }
     return results
 
@@ -203,7 +209,9 @@ def main(argv=None) -> int:
         "metric": "straggler_cluster_samples_per_sec",
         "np": args.np_, "straggler_ms": args.straggler_ms,
         "steps": args.steps, "batch": args.batch,
-        "results": res,
+        "results": {strategy: {k: v for k, v in r.items()
+                               if not k.endswith("_wall_s")}
+                    for strategy, r in res.items()},
     }))
     return 0
 

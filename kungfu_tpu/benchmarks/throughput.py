@@ -148,14 +148,11 @@ def measure_adamw_update(size: str = "small", variant: str = "per-leaf",
     attribution) runs ~3.7x above its HBM floor because of the long
     tail of small leaves — each tiny fusion pays launch + sub-cache-line
     HBM overheads. This harness isolates exactly that: grads in, update
-    applied, nothing else, for the three partitioning strategies:
+    applied, nothing else, for the two partitioning strategies:
 
     - ``per-leaf``: plain optax (the in-repo benchmark default),
     - ``grouped``: `optimizers.group_small_leaves` — small tail fused,
-      2-D leaves per-leaf in their tiled layouts,
-    - ``flat``: `optimizers.flatten_optimizer` — the whole-tree concat
-      (the documented round-5 NEGATIVE on v5e; kept as the comparison
-      endpoint).
+      2-D leaves per-leaf in their tiled layouts.
 
     Returns (ms_per_step, meta). The HBM floor is 28 B/param (read
     p,m,v,g + write p,m,v at f32); `floor_ratio` is measured/floor
@@ -167,9 +164,7 @@ def measure_adamw_update(size: str = "small", variant: str = "per-leaf",
 
     from kungfu_tpu.benchmarks.lm import SIZES
     from kungfu_tpu.models import GPTConfig, GPTLM
-    from kungfu_tpu.optimizers import (SMALL_LEAF_ELEMS,
-                                       flatten_optimizer,
-                                       group_small_leaves)
+    from kungfu_tpu.optimizers import SMALL_LEAF_ELEMS, group_small_leaves
 
     platform = jax.devices()[0].platform
     if platform == "cpu":  # smoke path
@@ -187,7 +182,6 @@ def measure_adamw_update(size: str = "small", variant: str = "per-leaf",
     tx = {
         "per-leaf": make,
         "grouped": lambda: group_small_leaves(make()),
-        "flat": lambda: flatten_optimizer(make()),
     }[variant]()
     opt = tx.init(params)
     # synthetic grads with per-leaf structure (values don't matter for
@@ -242,7 +236,7 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=0, help="per-chip batch")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--warmup", type=int, default=3)
-    ap.add_argument("--adamw", choices=("per-leaf", "grouped", "flat"),
+    ap.add_argument("--adamw", choices=("per-leaf", "grouped"),
                     default="",
                     help="measure the isolated adamw update on the GPT "
                          "tree with this leaf partitioning instead of "

@@ -58,20 +58,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
-def ensure_libkf() -> None:
-    """Build the native DCN runtime if this checkout hasn't yet."""
-    native = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "native")
-    if os.path.exists(os.path.join(native, "libkf.so")):
-        return
-    r = subprocess.run(["make", "-C", native], capture_output=True,
-                       text=True)
-    if r.returncode != 0:
-        raise RuntimeError(
-            f"libkf.so build failed rc={r.returncode}:\n"
-            f"{r.stdout[-2000:]}\n{r.stderr[-2000:]}")
-
-
 def _run_continuity_cluster(schedule: str,
                             total_steps: int,
                             start_np: int,
@@ -96,7 +82,6 @@ def _run_continuity_cluster(schedule: str,
     test_multirunner shape), so host-scoped failures have a real
     per-host supervisor to detect them. Empty = the single-runner
     single-host launch every pre-existing caller uses."""
-    ensure_libkf()
     from .config_server import ConfigServer
 
     own_server = server is None

@@ -1,18 +1,20 @@
 """ctypes bindings for libkf, the C++ DCN control plane.
 
-Loads ``libkf.so`` from ``kungfu_tpu/native/`` (built by
-``make -C kungfu_tpu/native``) and exposes a thin, typed wrapper. All
-blocking calls release the GIL (ctypes does this for foreign calls), so
-collectives can overlap with Python compute threads — the async-callback
-role the reference's cgo bridge plays (reference:
-srcs/go/libkufu-comm/main.go callOP) is covered here by calling into libkf
-from Python threads/executors instead.
+Loads ``libkf.so`` from ``kungfu_tpu/native/`` (the first ``load()`` of
+a checkout builds it there with ``make`` when it is absent) and exposes
+a thin, typed wrapper. All blocking calls release the GIL (ctypes does
+this for foreign calls), so collectives can overlap with Python compute
+threads — the async-callback role the reference's cgo bridge plays
+(reference: srcs/go/libkufu-comm/main.go callOP) is covered here by
+calling into libkf from Python threads/executors instead.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
+import subprocess
 import threading
 from typing import Optional
 
@@ -21,7 +23,6 @@ import numpy as np
 from .plan import topology as _topology
 
 _LIB_DIR = os.path.join(os.path.dirname(__file__), "native")
-_LIB_PATH = os.environ.get("KF_LIB", os.path.join(_LIB_DIR, "libkf.so"))
 
 # error codes (mirror include/kf.h)
 KF_OK = 0
@@ -120,8 +121,38 @@ def load() -> ctypes.CDLL:
         return _lib
 
 
+def _build_lib(native_dir: str) -> None:
+    """Build ``native_dir/libkf.so`` unless it is there, once however
+    many processes ask at once (xdist workers, the N workers of one
+    kfrun): the flock serialises them and the loser of the race finds
+    the file. The Makefile's rule renames a finished product into
+    place, so the name never holds half a library."""
+    with open(os.path.join(native_dir, ".libkf.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)  # dropped when the file closes
+        if os.path.exists(os.path.join(native_dir, "libkf.so")):
+            return
+        r = subprocess.run(["make", "-C", native_dir, "libkf.so"],
+                           capture_output=True, text=True)
+        if r.returncode != 0:
+            raise RuntimeError(
+                f"libkf.so build failed rc={r.returncode}:\n"
+                f"{r.stdout[-2000:]}\n{r.stderr[-2000:]}")
+
+
+def _lib_path() -> str:
+    # KF_LIB is a deployment setting: exactly that file (the TSan build
+    # in scripts/sanitize.sh), never built, OSError from dlopen if gone
+    override = os.environ.get("KF_LIB")
+    if override:
+        return override
+    path = os.path.join(_LIB_DIR, "libkf.so")
+    if not os.path.exists(path):
+        _build_lib(_LIB_DIR)
+    return path
+
+
 def _bind_lib() -> ctypes.CDLL:
-    lib = ctypes.CDLL(_LIB_PATH)
+    lib = ctypes.CDLL(_lib_path())
     P = ctypes.c_void_p
     i64 = ctypes.c_int64
     u32 = ctypes.c_uint32

@@ -81,7 +81,6 @@ shapes don't tile (T % block != 0) or on backends without Mosaic
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -104,10 +103,10 @@ FLASH_LSE = "kf.flash_lse"
 # where the real limit would (large T residency, large head dims).
 _VMEM_BUDGET = 15 * 1024 * 1024
 
-# test/bench escape hatch: force "stream" or "resident" regardless of
-# the budget decision (unset = auto). Read at trace time so tests can
-# monkeypatch the module attribute.
-_FORCE_SCHEME = os.environ.get("KUNGFU_FLASH_SCHEME") or None
+# the tests' hook: they monkeypatch this to "stream" or "resident" to
+# put a scheme the budget decision would not pick under test (None =
+# auto). Read at trace time; nothing but a test sets it.
+_FORCE_SCHEME = None
 
 
 def _operand_dtype(*dtypes):
@@ -457,7 +456,7 @@ def _choose_scheme(which, t, d, isz, bq, bk, causal=False, window=None):
     scheduled kernels do the least work a block step. Else 'resident'
     when the full-length-per-head scheme fits (it both skips masked
     blocks AND fetches the streamed side once per head), else
-    'stream'. `_FORCE_SCHEME` overrides for benchmarking/tests."""
+    'stream'. `_FORCE_SCHEME` overrides for tests."""
     if _FORCE_SCHEME in ("stream", "resident"):
         return _FORCE_SCHEME
     if (causal and window is None and bq == bk

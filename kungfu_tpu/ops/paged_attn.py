@@ -47,7 +47,6 @@ without Mosaic.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -60,10 +59,10 @@ NEG_INF = float(jnp.finfo(jnp.float32).min)
 #: is 16 MB; 15 MB leaves scheduling headroom.
 _VMEM_BUDGET = 15 * 1024 * 1024
 
-#: test/bench escape hatch: force one scheme regardless of the budget
-#: decision (unset = auto). Read at trace time so tests can
-#: monkeypatch the module attribute (the KUNGFU_FLASH_SCHEME idiom).
-_FORCE_SCHEME = os.environ.get("KUNGFU_PAGED_SCHEME") or None
+#: the tests' hook, as in ops/flash.py: monkeypatch it to force one
+#: scheme, regardless of the budget decision, through callers that
+#: pass no `scheme=` (the engine). None = auto; read at trace time.
+_FORCE_SCHEME = None
 
 
 # ---------------------------------------------------------------------------

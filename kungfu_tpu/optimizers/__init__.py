@@ -23,7 +23,7 @@ a mesh axis), so the communication compiles onto ICI.
 """
 
 from .ada_sgd import ada_sgd
-from .fused import SMALL_LEAF_ELEMS, flatten_optimizer, group_small_leaves
+from .fused import SMALL_LEAF_ELEMS, group_small_leaves
 from .async_sgd import PairAveragingState, pair_averaging
 from .monitors import (
     attach_gradient_noise_scale,
@@ -37,7 +37,6 @@ from .sync_sgd import (bucketed_all_reduce_mean, sync_sgd,
                        sync_sgd_bucketed)
 
 __all__ = [
-    "flatten_optimizer",
     "group_small_leaves",
     "SMALL_LEAF_ELEMS",
     "sync_sgd",

@@ -1,40 +1,31 @@
-"""Flat-buffer optimizer wrappers: fused updates for many-leaf trees.
+"""Grouped small-leaf optimizer wrapper: one fused update for the tail.
 
-`flatten_optimizer` wraps ANY elementwise optax transformation to run
-on a single concatenated vector per param dtype, so the whole update
-is a handful of big streaming kernels instead of one fusion per leaf.
-Conceptually the TPU analogue of the reference's fused gradient path
-(reference: srcs/python/kungfu/tensorflow/optimizers/sync_sgd.py
+`group_small_leaves` wraps an elementwise optax transformation so that
+only leaves below a size threshold — the layernorm scales/biases and
+projection biases, ~half the leaf COUNT but <1% of the BYTES — are
+concatenated into one streaming update per dtype, while every large
+2-D leaf keeps its per-leaf update in its native tiled layout (no
+relayout, no serial DUS over big buffers). Conceptually the TPU
+analogue of the reference's fused gradient path (reference:
+srcs/python/kungfu/tensorflow/optimizers/sync_sgd.py
 `nccl_fusion`/fuse): fuse many small per-tensor ops into few big ones.
 
-**Whole-tree flattening measured NEGATIVE on v5e** (docs/benchmarks.md
-round-5 attribution): the per-leaf adamw fusions were only 16.1 ms of
-the 104.6 ms GPT-2 b=12 step, and the flat variant REGRESSED the step
-to 131.1 ms — XLA lowers the 100-leaf concatenate to a serial
-dynamic-update-slice loop and relayouts every 2-D tiled leaf to the
-1-D linear layout and back. The wrapper is kept because it is correct
-(bitwise-parity tested), cheap to maintain, and the trade can flip on
-backends/shapes where concatenation is free.
-
-`group_small_leaves` is the middle point that negative result actually
-motivates (VERDICT r5: the adamw update runs ~3.7x above its HBM floor
+Why only the tail: the adamw update runs ~3.7x above its HBM floor
 because of the LONG TAIL OF SMALL LEAVES, each tiny fusion paying
-launch + sub-line HBM overheads): only leaves below a size threshold —
-the layernorm scales/biases and projection biases, ~half the leaf
-COUNT but <1% of the BYTES — are concatenated into one streaming
-update per dtype, while every large 2-D leaf keeps its per-leaf update
-in its native tiled layout (no relayout, no serial DUS over big
-buffers). The concat that regressed was the one over megabyte leaves;
-the tail concat is a few hundred KB.
+launch + sub-line HBM overheads (docs/benchmarks.md round-5
+attribution). Concatenating the WHOLE tree measured negative on v5e
+there (104.6 -> 131.1 ms GPT-2 b=12 step: XLA lowers the 100-leaf
+concatenate to a serial dynamic-update-slice loop and relayouts every
+2-D tiled leaf to the 1-D linear layout and back); the tail concat is
+a few hundred KB.
 
-Correctness (both wrappers): valid for transformations whose update
-math is elementwise per parameter (sgd, momentum, adam(w), rmsprop,
-adafactor with factored=False). NOT valid inside the wrapper for
-anything that couples elements ACROSS the tree — global-norm clipping
-sees one flat vector PER DTYPE GROUP, so on a mixed f32/bf16 tree each
-group would clip by its own norm (verified divergence in
-tests/test_gpt_optimizers.py). Compose such transforms OUTSIDE:
-``optax.chain(optax.clip_by_global_norm(c), flatten_optimizer(adam))``.
+Correctness: valid for transformations whose update math is
+elementwise per parameter (sgd, momentum, adam(w), rmsprop, adafactor
+with factored=False). NOT valid inside the wrapper for anything that
+couples elements ACROSS the tree — global-norm clipping would see the
+tail as one flat vector PER DTYPE GROUP and clip each by its own norm.
+Compose such transforms OUTSIDE:
+``optax.chain(optax.clip_by_global_norm(c), group_small_leaves(adam))``.
 Per-leaf-shape-dependent transforms (factored adafactor, lars/lamb
 trust ratios) can never be flattened; wrap those per-leaf.
 """
@@ -46,20 +37,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 import optax
-
-
-class FlatState(NamedTuple):
-    inner: Any          # {dtype_str: inner optax state on the flat vec}
-
-
-def _group_leaves(tree):
-    """leaves + treedef + {dtype: (indices, sizes, shapes)} grouping."""
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    groups = {}
-    for i, leaf in enumerate(leaves):
-        key = str(jnp.asarray(leaf).dtype)
-        groups.setdefault(key, []).append(i)
-    return leaves, treedef, groups
 
 
 def _flatten_group(leaves, idxs):
@@ -76,53 +53,6 @@ def _unflatten_group(flat, leaves_like, idxs):
     return {i: p.reshape(leaves_like[i].shape)
             for i, p in zip(idxs, parts)}
 
-
-def flatten_optimizer(inner: optax.GradientTransformation
-                      ) -> optax.GradientTransformation:
-    """Run `inner` on one flat vector per parameter dtype.
-
-    The update tree comes back with each leaf's original shape and the
-    dtype `inner` produced (optax.apply_updates casts to the param
-    dtype as usual). Gradients and params are grouped by PARAM dtype so
-    mixed trees (f32 master weights + bf16 expert stacks) stay exact.
-    """
-
-    def init(params):
-        leaves, _, groups = _group_leaves(params)
-        inner_states = {
-            key: inner.init(_flatten_group(leaves, idxs))
-            for key, idxs in groups.items()}
-        return FlatState(inner=inner_states)
-
-    def update(updates, state, params=None):
-        # ALWAYS group by param dtype (matching init); grouping by the
-        # grads' dtypes would mismatch the per-group inner states
-        # whenever grad dtype differs from param dtype (e.g. f32 grads
-        # for bf16 params). Without params the param dtypes are not
-        # observable, and silently falling back to grad-dtype grouping
-        # would corrupt the state lookup — refuse instead.
-        if params is None:
-            raise ValueError(
-                "flatten_optimizer requires params at update() time: "
-                "groups are keyed by param dtype (as at init)")
-        g_leaves, treedef, _ = _group_leaves(updates)
-        p_leaves, _, groups = _group_leaves(params)
-        new_inner = {}
-        out = [None] * len(g_leaves)
-        for key, idxs in groups.items():
-            flat_g = _flatten_group(g_leaves, idxs)
-            flat_p = _flatten_group(p_leaves, idxs)
-            flat_u, new_inner[key] = inner.update(
-                flat_g, state.inner[key], flat_p)
-            for i, u in _unflatten_group(flat_u, g_leaves, idxs).items():
-                out[i] = u
-        return (jax.tree_util.tree_unflatten(treedef, out),
-                FlatState(inner=new_inner))
-
-    return optax.GradientTransformation(init, update)
-
-
-# -- grouped small-leaf updates ---------------------------------------------
 
 #: leaves below this many elements join the flattened tail. 64k elems
 #: (256 KiB at f32) keeps every GPT layernorm/bias leaf (<= 4*hidden)
@@ -161,8 +91,8 @@ def group_small_leaves(inner: optax.GradientTransformation,
     elementwise ops, and the step counter advances identically in
     every partition (one `update` call each per step).
 
-    Same caveats as `flatten_optimizer` (module docstring): compose
-    cross-tree transforms OUTSIDE the wrapper.
+    Compose cross-tree transforms OUTSIDE the wrapper (module
+    docstring).
     """
 
     def init(params):
@@ -175,9 +105,11 @@ def group_small_leaves(inner: optax.GradientTransformation,
         )
 
     def update(updates, state, params=None):
-        # param-dtype/param-size partition, exactly as at init (see
-        # flatten_optimizer.update for why grad-keyed grouping would
-        # corrupt the state lookup)
+        # param-dtype/param-size partition, exactly as at init:
+        # grouping by the grads' dtypes would mismatch the per-group
+        # inner states whenever grad dtype differs from param dtype
+        # (f32 grads for bf16 params). Without params neither is
+        # observable — refuse instead of corrupting the state lookup.
         if params is None:
             raise ValueError(
                 "group_small_leaves requires params at update() time: "

@@ -249,6 +249,13 @@ def _request_once(target: str, method: str, body: Optional[bytes],
             if reused:
                 continue  # idle keep-alive conn died under us; resend fresh
             raise
+        except http.client.IncompleteRead as e:
+            # the server died between its headers and its body. That is
+            # a reset, and callers and the retry taxonomy know resets as
+            # OSError; an HTTPException would pass them all and kill a
+            # worker whose per-step poll met a restarting config server
+            conn.close()
+            raise ConnectionResetError(f"response cut short: {e}") from e
         except Exception:
             conn.close()
             raise

@@ -26,7 +26,6 @@ import tempfile
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..elastic.harness import ensure_libkf
 from ..plan import free_port
 
 SERVE_MARKERS = (
@@ -117,7 +116,6 @@ def run_serve_cluster(
     ledger-invariant violation."""
     import threading
 
-    ensure_libkf()
     from ..elastic.config_server import ConfigServer
 
     own_server = server is None

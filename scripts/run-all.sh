@@ -31,10 +31,6 @@ JAX_PLATFORMS=cpu python -m kungfu_tpu.analysis kungfu_tpu/ \
 # baseline discipline as kflint above
 JAX_PLATFORMS=cpu python -m kungfu_tpu.analysis.consensus \
   --baseline scripts/kfconsensus_baseline.json
-# every round must publish its headline metric (BENCH_rNN.json); a
-# round that only touched BASELINE.json leaves the perf-trajectory
-# feed blind — fail loudly and early (benchmarks/publish.py)
-JAX_PLATFORMS=cpu python -m kungfu_tpu.benchmarks.publish --check-round
 # pyproject.toml carries the ruff/mypy baselines; the container doesn't
 # ship them, so they gate only where installed (dev machines, CI)
 if python -c "import ruff" 2>/dev/null; then

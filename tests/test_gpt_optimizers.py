@@ -80,94 +80,10 @@ def test_pair_averaging_trains_gpt(setup):
     assert last < first, (first, last)
 
 
-class TestFlattenOptimizer:
-    """flatten_optimizer: bitwise parity with per-leaf optax for
-    elementwise transforms; documented divergence for cross-tree ones."""
-
-    @staticmethod
-    def _tree():
-        params = {
-            "a": jnp.ones((5, 7), jnp.float32) * 0.3,
-            "b": {"k": jnp.full((11,), 0.1, jnp.bfloat16),
-                  "m": jnp.linspace(-1, 1, 24).reshape(4, 6
-                                                       ).astype(jnp.float32)},
-        }
-        grads = jax.tree_util.tree_map(
-            lambda p: (jnp.arange(p.size).reshape(p.shape)
-                       / p.size).astype(p.dtype), params)
-        return params, grads
-
-    @pytest.mark.parametrize("make", [
-        lambda: optax.adamw(1e-3),
-        lambda: optax.sgd(0.1, momentum=0.9),
-        lambda: optax.adam(1e-2),
-    ], ids=["adamw", "sgd-momentum", "adam"])
-    def test_bitwise_parity_elementwise(self, make):
-        from kungfu_tpu.optimizers import flatten_optimizer
-
-        params, grads0 = self._tree()
-        ref_tx, flat_tx = make(), flatten_optimizer(make())
-        rp = fp = params
-        rs, fs = ref_tx.init(rp), flat_tx.init(fp)
-        for step in range(4):
-            g = jax.tree_util.tree_map(lambda g: g * (step + 1), grads0)
-            ru, rs = ref_tx.update(g, rs, rp)
-            fu, fs = flat_tx.update(g, fs, fp)
-            rp = optax.apply_updates(rp, ru)
-            fp = optax.apply_updates(fp, fu)
-        for a, b in zip(jax.tree_util.tree_leaves(rp),
-                        jax.tree_util.tree_leaves(fp)):
-            np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                          np.asarray(b, np.float32))
-
-    def test_global_norm_clip_must_compose_outside(self):
-        """Inside the wrapper, clip sees one vector per dtype group and
-        the norms differ on a mixed tree — the documented caveat. The
-        correct composition (clip outside) matches per-leaf exactly."""
-        from kungfu_tpu.optimizers import flatten_optimizer
-
-        params, grads = self._tree()
-        ref_tx = optax.chain(optax.clip_by_global_norm(0.05),
-                             optax.sgd(0.1))
-        good_tx = optax.chain(optax.clip_by_global_norm(0.05),
-                              flatten_optimizer(optax.sgd(0.1)))
-        ru, _ = ref_tx.update(grads, ref_tx.init(params), params)
-        gu, _ = good_tx.update(grads, good_tx.init(params), params)
-        for a, b in zip(jax.tree_util.tree_leaves(ru),
-                        jax.tree_util.tree_leaves(gu)):
-            np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                          np.asarray(b, np.float32))
-
-    def test_works_under_jit_train_step(self):
-        """The wrapper must trace cleanly inside a jitted train step
-        (concat/split of every leaf) and train a real model."""
-        from kungfu_tpu.models import GPTConfig, GPTLM, gpt_fused_loss
-        from kungfu_tpu.optimizers import flatten_optimizer
-        from kungfu_tpu.parallel import build_gspmd_train_step
-
-        cfg = GPTConfig(vocab_size=128, hidden_size=128, num_layers=2,
-                        num_heads=4, intermediate_size=256,
-                        max_position=32)
-        model = GPTLM(cfg)
-        toks = jax.random.randint(jax.random.PRNGKey(0), (4, 32), 0,
-                                  128)
-        params = model.init(jax.random.PRNGKey(1), toks[:1])["params"]
-        tx = flatten_optimizer(optax.adamw(1e-3))
-        opt = tx.init(params)
-        step = build_gspmd_train_step(
-            lambda p, t: gpt_fused_loss(model, p, t), tx)
-        losses = []
-        for _ in range(5):
-            params, opt, loss = step(params, opt, toks)
-            losses.append(float(loss))
-        assert losses[-1] < losses[0]
-
-
 class TestGroupSmallLeaves:
-    """group_small_leaves: the size-thresholded middle point between
-    per-leaf updates and the (measured-negative) whole-tree flat
-    buffer — only the small-leaf tail is concatenated, large leaves
-    stay per-leaf. Must be bitwise-identical to per-leaf `inner`."""
+    """group_small_leaves: only the small-leaf tail is concatenated,
+    large leaves stay per-leaf. Must be bitwise-identical to per-leaf
+    `inner`."""
 
     @staticmethod
     def _mixed_tree():

@@ -9,6 +9,8 @@ beat attempt budgets.
 
 import errno
 import io
+import socket
+import threading
 import urllib.error
 
 import pytest
@@ -180,3 +182,41 @@ def test_fetch_url_rides_policy_through_transients(tmp_path):
                          _sleep=_sleep_then_recover)
     assert fetch_url(f"file://{target}", retry=policy) == "READY"
     assert len(sleeps) == 1  # exactly one backoff bridged the gap
+
+
+def test_answer_cut_short_is_a_reset_the_policy_rides():
+    """A server stopped between its headers and its body (the config
+    server restarting under a worker's poll) must reach the caller as
+    an OSError like every other connection fault — http.client's
+    IncompleteRead is not one, and the per-step poll in
+    Peer.resize_from_url catches OSError only."""
+    from kungfu_tpu.peer import fetch_url
+
+    body = b'{"version": 1}'
+    lst = socket.socket()
+    lst.bind(("127.0.0.1", 0))
+    lst.listen(4)
+
+    def serve():
+        for whole in (False, False, True):
+            conn, _ = lst.accept()
+            with conn:
+                conn.recv(4096)
+                conn.sendall(b"HTTP/1.1 200 OK\r\nContent-Length: %d\r\n"
+                             b"Connection: close\r\n\r\n" % len(body))
+                if whole:
+                    conn.sendall(body)
+
+    t = threading.Thread(target=serve, daemon=True)
+    t.start()
+    url = f"http://127.0.0.1:{lst.getsockname()[1]}/get"
+    try:
+        with pytest.raises(ConnectionResetError, match="cut short"):
+            fetch_url(url, retry=NO_RETRY)
+        # the second cut answer is retried, the third is whole
+        assert fetch_url(url, retry=RetryPolicy(
+            attempts=3, base_ms=1)) == body.decode()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    finally:
+        lst.close()
