@@ -41,6 +41,7 @@ FLASH_GRID = [
     (4096, 256, "float32", True, None),
     (8192, 128, "bfloat16", True, None),
     (8192, 256, "bfloat16", False, None),
+    (8192, 256, "bfloat16", True, None),   # the glm cell: fused backward
     (16384, 64, "bfloat16", True, None),
     (16384, 64, "bfloat16", True, 512),
     (16384, 128, "bfloat16", True, 512),
@@ -110,9 +111,9 @@ def check_flash(grid: Sequence = FLASH_GRID,
 
     from ..ops import flash
 
+    fused_limit = (flash._BWD_STREAM_VMEM_LIMIT if budget is None
+                   else budget)
     budget = flash._VMEM_BUDGET if budget is None else budget
-    stream = {"fwd": flash._fwd_stream_vmem, "dq": flash._dq_stream_vmem,
-              "dkv": flash._dkv_stream_vmem}
     findings = []
     for t, d, dtype_name, causal, window in grid:
         dtype = jnp.dtype(dtype_name)
@@ -121,27 +122,29 @@ def check_flash(grid: Sequence = FLASH_GRID,
         if plan.get("scheme") == "plain":
             continue  # fallback path: nothing to compile, nothing to OOM
         bq, bk = plan["block_q"], plan["block_k"]
-        isz = dtype.itemsize
-        for which in ("fwd", "dq", "dkv"):
-            scheme = plan[which]["scheme"]
-            if scheme == "head":
-                est = flash._head_vmem(
-                    "fwd" if which == "fwd" else "bwd", bq, d, isz, t)
-            elif scheme == "resident":
-                est = flash._RES_VMEM[which](bq, bk, d, isz, t)
-            elif which == "dkv":
-                est = stream[which](bq, bk, d, isz, t)
-            else:
-                est = stream[which](bq, bk, d, isz)
-            if est > budget:
+        bwd = plan["bwd"]
+        # the fused streaming backward replaces dq + dkv, at its own
+        # tiles and under the limit it states
+        fused = bwd["scheme"] == "stream_fused"
+        kernels = [
+            (which, plan[which]["scheme"], (bq, bk), flash._kernel_vmem(
+                which, plan[which]["scheme"], bq, bk, d, dtype.itemsize,
+                t), budget)
+            for which in (("fwd",) if fused else ("fwd", "dq", "dkv"))]
+        if fused:
+            kernels.append(("bwd", bwd["scheme"],
+                            (bwd["block_q"], bwd["block_k"]),
+                            bwd["vmem_bytes"], fused_limit))
+        for which, scheme, blocks, est, limit in kernels:
+            if est > limit:
                 findings.append(Finding(
                     "kungfu_tpu/ops/flash.py", 1, NAME,
                     f"flash {which} plan at t={t} d={d} "
                     f"dtype={dtype_name} causal={causal} "
-                    f"window={window} picks blocks ({bq}, {bk}) "
+                    f"window={window} picks blocks {blocks} "
                     f"scheme={scheme} with VMEM estimate "
                     f"{est / 2**20:.1f} MB > budget "
-                    f"{budget / 2**20:.1f} MB — Mosaic would OOM at "
+                    f"{limit / 2**20:.1f} MB — Mosaic would OOM at "
                     "compile time"))
     return findings
 

@@ -9,8 +9,10 @@ VISIBLE-pair FLOP count (`flash_attention_flops` — masked score area
 is overhead, not work), and reports achieved TFLOP/s plus efficiency
 against the chip's bf16 peak where the device kind is known. The
 execution plan (`flash_plan`: per-kernel scheme, block sizes, visited
-vs grid blocks) rides along so a published row names exactly which
-kernel configuration produced it.
+vs grid blocks, and under "bwd" which backward ran — the fused
+streaming kernel, the head kernel or a dq + dkv pair — with its tiles
+and block matmuls a step) rides along so a published row names exactly
+which kernel configuration produced it.
 
   python -m kungfu_tpu.benchmarks.flash_eff --seq 1024 --heads 12
   python -m kungfu_tpu.benchmarks.flash_eff --seq 16384 --window 512

@@ -7,9 +7,10 @@ O(T) per query block (FlashAttention, Dao et al. 2022 — on TPU the
 win is HBM bandwidth, the usual bottleneck, not SRAM reuse).
 
 Three execution schemes per kernel (fwd / dq / dkv; the head scheme
-computes dq, dk and dv in one), selected by a
-VMEM-budget estimate in the style of `ops/fused_ce.py:_pick_blocks`
-(`flash_plan` shows the decision for a shape):
+and the fused streaming backward compute dq, dk and dv in one),
+selected by a VMEM-budget estimate in the style of
+`ops/fused_ce.py:_pick_blocks` (`flash_plan` shows the decision for a
+shape):
 
 - **head** (causal, no window, a whole head inside `_VMEM_BUDGET` and
   at most `_HEAD_MAX_CHUNKS` chunks — every causal T <= 2048 at
@@ -36,12 +37,16 @@ VMEM-budget estimate in the style of `ops/fused_ce.py:_pick_blocks`
   round-5 grid (B*H, outer, inner) with VMEM-scratch-carried online
   state. Causal masking skips compute via `pl.when`; sliding windows
   narrow the inner grid dim itself (`_window_span`, affine
-  front-padded index maps).
+  front-padded index maps). Its backward is ONE kernel
+  (`_bwd_stream_kernel`, "stream_fused" in `flash_plan`) wherever the
+  call has no window and a head's f32 dq fits the VMEM limit that
+  kernel states (`_bwd_stream_tiles`: T <= 16384 at d = 256 bf16,
+  <= 32768 at d <= 128); else the dq + dkv pair.
 
-All three run the same block steps (`_fwd_step`, `_dq_step`,
-`_dkv_step`). Their matmuls take their operands in the input's dtype
-when that is bf16 (`_operand_dtype`) and accumulate in f32; scores,
-max, exp, sums, lse and delta are f32 whatever the input.
+All run the same block steps (`_fwd_step`, `_dq_step`, `_dkv_step`).
+Their matmuls take their operands in the input's dtype when that is
+bf16 (`_operand_dtype`) and accumulate in f32; scores, max, exp, sums,
+lse and delta are f32 whatever the input.
 
 What the chip said of the loops (one TPU v5e, 2026-10-01, PR 25; B*H =
 96, T = 1024, d = 64, bf16, causal, fwd + bwd of the isolated kernel,
@@ -57,16 +62,55 @@ backward. Mosaic's default-precision f32 `dot_general` already was
 one bf16 pass on the MXU: bf16 operands changed neither the time
 (2.06 -> 2.09) nor one bit of the result on bf16 inputs.
 
+What the chip said of the streaming backward (one TPU v5e; B*H = 20,
+T = 8192, d = 256, bf16, causal — the `glm-4.7-flash` cell's call —
+fwd + bwd of the isolated call, the forward alone 6.16 ms at its 1024 x
+512. Measured 2026-10-02 by the builder of PR 30, which the driver
+refused for one incorrect run nobody has reproduced (PERF.md section 6,
+PR 31); the kernel is that one, and every line marked * measured the
+same to 0.03 ms again on 2026-10-03, PR 31): the dq + dkv pair 23.43*
+ms — each kernel rebuilds s = q k^T and dp = dO v^T and takes exp of
+the block, seven block matmuls a step where the mathematics has five.
+ONE kernel on grid (B*H, nk, nq) in transposed score space, dq summed
+into a whole head's f32 accumulator in VMEM by the transposed-lhs
+contraction dsT^T @ k: 18.59* at 1024 x 512 tiles, 17.56* at 1024 x
+1024, 18.56* at 512 x 512, 17.76 at 512 x 1024, 19.26 at 2048 x 512.
+Naming, for the steps above the diagonal, the first q-block that
+computes (so the pipeline fetches no q/dO block for a step that skips)
+took 0.85-1.6 ms more off: 17.01* / **16.71*** / 17.11* / 17.00 /
+17.82, and 17.68 at 2048 x 1024 — where `_narrowed_kv` records a max()
+in an index map as 28% slower, on an older JAX: that did not repeat.
+What lost or tied: delta computed in the kernel's first k-sweep instead
+of by one XLA reduction, +0.3 ms (18.90, 17.87: O's blocks ride the
+pipeline); dq accumulated transposed ([d, T], k^T cached once a
+k-block, every matmul NN) in place of the transposed-lhs contraction,
++0.06 (18.66, 17.63: the contraction costs nothing here, so the
+untransposed orientation with its two TN forms was not tried);
+building the mask only on the blocks the diagonal crosses, -0.07
+(16.66) for a second copy of the step: not kept. So the backward went
+17.27 -> 10.55 ms, with the transposes round it. Elsewhere (pair ->
+fused, 1024 x 1024): non-causal 35.40 -> 27.36*; f32 38.52 -> 24.01*;
+T 4096 6.43 -> 4.76; T 16384, 8 heads 36.22 -> 26.44; d = 128: T
+8192, 32 heads 22.36 -> 16.14, T 16384, 8 heads 22.68 -> 17.88*, T
+32768, 4 heads 42.61 -> 33.74. On the chip the fused kernel repeats
+itself to the bit (400 calls at the cell's shape, and five 60-step
+training runs of the cell at one seed), gives the pair's dv to the
+bit, and differs from the pair's dq in at most 0.52% and dk in 0.013%
+of elements, by a bf16 rounding of ds (delta's summation order): an L2
+distance of 1.1e-4 where either stands 2.0e-3 from flash in f32.
+
 Auto block sizes are budget-driven: the head kernels' chunk where they
 apply, else the largest power-of-two tile <= 1024 that keeps the worst
 kernel's VMEM estimate under budget (big head dims shrink blocks
-instead of failing to compile).
+instead of failing to compile). The fused streaming backward takes
+1024 x 1024 where T divides, whatever the forward's tiles.
 
-Backward overhead trims (round 6): the delta precompute
-(`rowsum(dO * O)`, FlashAttention-2 eq. 4) is folded into the dq
-kernel's first pass — dq already streams dO, so the separate XLA
+Backward overhead trims (round 6): in the dq + dkv pairs the delta
+precompute (`rowsum(dO * O)`, FlashAttention-2 eq. 4) is folded into
+the dq kernel's first pass — dq already streams dO, so the separate XLA
 reduction and its extra full read of dO/O are gone; dq emits the
-per-row delta for the dkv kernel to consume. Residuals stay at the
+per-row delta for the dkv kernel to consume (the fused streaming
+backward measured the other way round, above). Residuals stay at the
 input dtype end to end (bf16 in, bf16 residuals; only the [B*H, T]
 lse/delta row vectors are f32). The output and the lse, which only
 the forward kernel can produce, carry `checkpoint_name`s (`FLASH_OUT`,
@@ -152,7 +196,12 @@ def _scores(q_blk, k_blk, iq, jk, *, scale, causal, block_q, block_k,
     forms). Measured on v5e at T=16k (round 5): this split is the
     fastest of the four layout/orientation combinations tried (see git
     history of this file), 7% faster end-to-end fwd+bwd than the
-    round-3 [B*H, T, 128] lane-broadcast scheme it replaces.
+    round-3 [B*H, T, 128] lane-broadcast scheme it replaces. The head
+    kernel and the fused streaming backward run wholly in the
+    transposed space and take dq from the same dsT by a transposed-lhs
+    contraction: on this JAX it measured level with an NN form against
+    a cached k^T (module docstring, PR 31), where a fully transposed
+    dq kernel once lost 36% (`_bwd_dq_kernel`).
 
     `window` (sliding-window attention, causal only): position q
     attends to keys [q - window, q]. Self is always visible, so no row
@@ -385,6 +434,26 @@ def _dkv_stream_vmem(bq, bk, d, isz, t):
     return inputs + outputs + scratch + 3 * bq * bk * 4
 
 
+# The fused streaming backward holds a head's dq at full length (an f32
+# accumulator in scratch and its output block), so it states its own
+# scoped-VMEM limit, as ops/fused_ce.py does, in place of Mosaic's
+# 16 MB default (the v5e has 128 MiB). Mosaic took every shape tried
+# whose estimate is under it (the largest 63.0 MiB: T 32768, d 128,
+# f32); a call whose estimate passes it runs the dq + dkv pair.
+_BWD_STREAM_VMEM_LIMIT = 64 * 1024 * 1024
+# its auto tile, by the sweep in the module docstring: 1024 x 1024
+_BWD_STREAM_BLOCK = 1024
+
+
+def _bwd_stream_vmem(bq, bk, d, isz, t):
+    d = -(-d // 128) * 128   # a [rows, d] buffer fills whole lane tiles
+    inputs = 2 * (2 * bk * d * isz + 2 * bq * d * isz + 2 * t * 4)
+    outputs = 2 * (2 * bk * d * isz + t * d * isz)
+    scratch = 2 * bk * d * 4 + t * d * 4
+    # s/p, dp and ds in f32, and the MXU-operand casts of p and ds
+    return inputs + outputs + scratch + bq * bk * (3 * 4 + 2 * isz)
+
+
 def _fwd_res_vmem(bq, bk, d, isz, t):
     inputs = 2 * (bq * d * isz + 2 * t * d * isz)
     outputs = 2 * (bq * d * isz + bq * 4)
@@ -461,11 +530,44 @@ def _choose_scheme(which, t, d, isz, bq, bk, causal=False, window=None):
         return _FORCE_SCHEME
     if (causal and window is None and bq == bk
             and 1 < t // bq <= _HEAD_MAX_CHUNKS
-            and _head_vmem("fwd" if which == "fwd" else "bwd", bq, d,
-                           isz, t) <= _VMEM_BUDGET):
+            and _kernel_vmem(which, "head", bq, bk, d, isz, t)
+            <= _VMEM_BUDGET):
         return "head"
-    est = _RES_VMEM[which](bq, bk, d, isz, t)
+    est = _kernel_vmem(which, "resident", bq, bk, d, isz, t)
     return "resident" if est <= _VMEM_BUDGET else "stream"
+
+
+def _kernel_vmem(which, scheme, bq, bk, d, isz, t):
+    """The VMEM estimate of kernel `which` ("fwd", "dq", "dkv") under
+    `scheme` — what `_choose_scheme` and `_tiles` hold to the budget."""
+    if scheme == "head":
+        return _head_vmem("fwd" if which == "fwd" else "bwd", bq, d, isz, t)
+    if scheme == "resident":
+        return _RES_VMEM[which](bq, bk, d, isz, t)
+    if which == "dkv":
+        return _dkv_stream_vmem(bq, bk, d, isz, t)
+    return {"fwd": _fwd_stream_vmem,
+            "dq": _dq_stream_vmem}[which](bq, bk, d, isz)
+
+
+def _bwd_stream_tiles(t, d, isz, bq, bk, causal, window, auto):
+    """(block_q, block_k) of the fused streaming backward
+    (`_bwd_stream_kernel`) where a call takes it, else None: no window
+    (the pair's narrowed grids differ between dq and dkv), both of the
+    pair's kernels on the streaming grid, and a head's dq inside the
+    limit the kernel states. Its tiles are its own: `_BWD_STREAM_BLOCK`
+    square where the caller left them to `_tiles` and T divides, else
+    the call's. A function of (t, d, dtype, causal, window) and the
+    tiles alone; `flash_plan` shows it under "bwd"."""
+    if window is not None or any(
+            _choose_scheme(which, t, d, isz, bq, bk, causal, window)
+            != "stream" for which in ("dq", "dkv")):
+        return None
+    tiles = [(bq, bk)]
+    if auto and t % _BWD_STREAM_BLOCK == 0:
+        tiles.insert(0, (_BWD_STREAM_BLOCK, _BWD_STREAM_BLOCK))
+    return next((tile for tile in tiles if _bwd_stream_vmem(
+        *tile, d, isz, t) <= _BWD_STREAM_VMEM_LIMIT), None)
 
 
 def _dim_semantics(n):
@@ -830,9 +932,11 @@ def flash_attention(
     Backward pass: fused flash backward kernels — the forward saves only
     (q, k, v, o, lse), dq/dk/dv are computed blockwise with the
     FlashAttention-2 recurrence (p re-materialized per block from the
-    saved logsumexp), and the delta precompute rides inside the dq
-    kernel, so both directions are O(T) in HBM with no standalone
-    reduction pass. Non-tiling shapes fall back to the plain VJP.
+    saved logsumexp): one kernel for all three in the head scheme and,
+    window-less, on the streaming grid (`_bwd_stream_tiles`), else a
+    dq + dkv pair with the delta precompute inside the dq kernel. Both
+    directions are O(T) in HBM. Non-tiling shapes fall back to the
+    plain VJP.
 
     `window` (requires causal=True): sliding-window attention — position
     q attends to keys [q - window, q] (Mistral-style local attention).
@@ -1159,10 +1263,108 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_stream_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                       scale, causal, block_q, block_k):
+    """Grid (B*H, nk, nq), nq innermost: dq, dk and dv of one head from
+    ONE pass over its score blocks, in transposed score space as
+    `_bwd_dkv_kernel`. dk/dv accumulate over a k-block's q-sweep as
+    there; the step's dsT (`_dkv_step`) also gives the q-block's dq
+    contribution, dsT^T @ k — a transposed-lhs contraction, as in the
+    head kernel — added into an f32 accumulator that holds the WHOLE
+    head's dq in VMEM scratch, since a q-block's dq is complete only
+    after the last k-sweep: zeroed at the head's first step, scaled and
+    written to the full-head output block at its last. Both inner grid
+    dims carry state, so both are "arbitrary". Five block matmuls and
+    one exp pass a step where the dq + dkv pair runs seven and two;
+    each q-block still sums its k-blocks in ascending order in f32.
+    lse/delta arrive as the head's full lane-major row set."""
+    jk = pl.program_id(1)
+    iq = pl.program_id(2)
+    nk = pl.num_programs(1)
+    nq = pl.num_programs(2)
+
+    @pl.when(jnp.logical_and(jk == 0, iq == 0))
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(iq == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_diag_ok(iq, jk, causal, block_q, block_k))
+    def _():
+        k_blk = k_ref[0]
+        dk, dv, ds_t = _dkv_step(
+            q_ref[0], k_blk, v_ref[0], do_ref[0],
+            lse_ref[0, iq, 0, :][None, :],            # [1, bq] lanes
+            delta_ref[0, iq, 0, :][None, :],
+            iq, jk, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k)
+        dk_acc[...] += dk
+        dv_acc[...] += dv
+        rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+        dq_acc[rows, :] += _mxu(ds_t, k_blk, _TN, ds_t.dtype)
+
+    @pl.when(iq == nq - 1)
+    def _():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(jk == nk - 1, iq == nq - 1))
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _stream_bwd(qb, kb, vb, dob, lse, delta, *, scale, causal, block_q,
+                block_k, interpret):
+    """(dq, dk, dv) of [B*H, T, D] inputs and the [B*H, T] lse and
+    delta, by `_bwd_stream_kernel`."""
+    bh, t, d = qb.shape
+    nq, nk = t // block_q, t // block_k
+    kv = pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, j, 0))
+    if causal:
+        # the q-blocks before a k-block's first visible one compute
+        # nothing: name that first one for them, and the pipeline
+        # fetches it once in place of a block a step (1.6 ms of 18.6 at
+        # the glm cell's call; no pipelining lost, unlike `_span_step`'s
+        # record of a max() in an index map)
+        def qdo_j(i, j, kk):
+            lo, _ = _q_span(j, nq, causal=True, window=None,
+                            block_q=block_q, block_k=block_k)
+            return i, jnp.maximum(kk, lo), 0
+    else:
+        def qdo_j(i, j, kk):
+            return i, kk, 0
+    qdo = pl.BlockSpec((1, block_q, d), qdo_j)
+    rows = pl.BlockSpec((1, nq, 1, block_q),
+                        lambda i, j, kk: (i, 0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_stream_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid=(bh, nk, nq),
+        in_specs=[kv, kv, qdo, qdo, rows, rows],
+        out_specs=[pl.BlockSpec((1, t, d), lambda i, j, kk: (i, 0, 0)),
+                   kv, kv],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (qb, kb, vb)],
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),        # dq
+                        pltpu.VMEM((block_k, d), jnp.float32),  # dk
+                        pltpu.VMEM((block_k, d), jnp.float32)],  # dv
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_BWD_STREAM_VMEM_LIMIT),
+        interpret=interpret,
+    )(kb, vb, qb, dob, lse.reshape(bh, nq, 1, block_q),
+      delta.reshape(bh, nq, 1, block_q))
+
+
 def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                     interpret, window=None):
     b, t, h, d = q.shape
     isz = jnp.dtype(q.dtype).itemsize
+    auto = block_q is None and block_k is None
     plan = _tiles(t, causal, block_q, block_k, window, d=d, itemsize=isz)
     assert plan is not None, (
         "no flash tile fits the VMEM budget for this shape — the forward "
@@ -1171,15 +1373,27 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
     block_q, block_k = plan
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    qb, kb, vb = _bh(q), _bh(k), _bh(v)
-    dob, ob = _bh(g), _bh(o)
+    qb, kb, vb, dob = _bh(q), _bh(k), _bh(v), _bh(g)
+    fused = _bwd_stream_tiles(t, d, isz, block_q, block_k, causal, window,
+                              auto)
+    if fused is not None:
+        # one kernel for dq, dk and dv. delta (rowsum(dO * O),
+        # FlashAttention-2 eq. 4) is one XLA reduction here: computed
+        # in the kernel's first k-sweep instead it cost 0.3 ms more at
+        # the glm cell's call (O's blocks ride the pipeline)
+        delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1).transpose(0, 2, 1).reshape(b * h, t)
+        return tuple(_unbh(x, b, h) for x in _stream_bwd(
+            qb, kb, vb, dob, lse, delta, scale=scale, causal=causal,
+            block_q=fused[0], block_k=fused[1], interpret=interpret))
+    ob = _bh(o)
     # lse enters the kernels at TRUE [B*H, T] size, reshaped to
     # [B*H, nq, 1, block_q] so Mosaic's tiling rule (trailing block
     # dims equal the array dims) accepts a one-row block; the dq kernel
     # relayouts the row into VMEM column scratch once per q-block, the
     # dkv kernel works in transposed score space where the row is
-    # already lane-shaped (see _scores). delta (rowsum(dO * O),
-    # FlashAttention-2 eq. 4) is no longer precomputed by XLA at all:
+    # already lane-shaped (see _scores). For the dq + dkv pair delta is
+    # not precomputed by XLA at all:
     # the dq kernel folds it into its kk == 0 / loop prologue (dO and O
     # stream there anyway) and emits it in the same compact lane-major
     # layout for the dkv kernel. This closes the round-2 ADVICE item
@@ -1403,7 +1617,16 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
     the visited ones build the causal/window mask (every block the
     resident and streaming loops visit; the diagonal's alone in the
     head kernels); `grid_blocks` is the unskipped outer*inner product
-    for comparison."""
+    for comparison.
+
+    "bwd" says what the backward as a whole is: `scheme`
+    "stream_fused" where `_bwd_stream_tiles` takes the call (one
+    kernel, ITS tiles, `block_matmuls` 5 a block step), else the dq +
+    dkv pair's (7: each rebuilds s and dp; the head scheme's one kernel
+    5). Its `visited_blocks` are the (q-block, k-block) pairs whose
+    block step RUNS — for the streaming grids fewer than the grid
+    steps "dq" and "dkv" count, since `pl.when` skips the rest — and
+    `vmem_bytes` the largest estimate among its kernels."""
     isz = jnp.dtype(dtype).itemsize
     tiles = _tiles(t, causal, block_q, block_k, window, d=d,
                    itemsize=isz)
@@ -1437,6 +1660,28 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
         plan[which] = {"scheme": scheme, "visited_blocks": visited,
                        "masked_blocks": masked,
                        "grid_blocks": nq * nk}
+    pair = [plan[which]["scheme"] for which in ("dq", "dkv")]
+    fused = _bwd_stream_tiles(t, d, isz, bq, bk, causal, window,
+                              block_q is None and block_k is None)
+    if fused is not None:
+        bq, bk = fused
+        nq, nk = t // bq, t // bk
+        scheme, vmem = "stream_fused", _bwd_stream_vmem(bq, bk, d, isz, t)
+    else:
+        scheme = pair[0] if pair[0] == pair[1] else "+".join(pair)
+        vmem = max(_kernel_vmem(which, plan[which]["scheme"], bq, bk, d,
+                                isz, t) for which in ("dq", "dkv"))
+    spans = [_q_span(jk, nq, causal=causal, window=window, block_q=bq,
+                     block_k=bk) for jk in range(nk)]
+    visited = sum(int(hi) - int(lo) for lo, hi in spans)
+    plan["bwd"] = {
+        "scheme": scheme, "block_q": bq, "block_k": bk,
+        "visited_blocks": visited,
+        "masked_blocks": (plan["dq"]["masked_blocks"] if scheme == "head"
+                          else visited if causal else 0),
+        "grid_blocks": nq * nk,
+        "block_matmuls": 5 if scheme in ("head", "stream_fused") else 7,
+        "vmem_bytes": vmem}
     return plan
 
 

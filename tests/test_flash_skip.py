@@ -190,23 +190,25 @@ def test_plan_reports_operand_dtype(dtype, want):
     assert plan["operand_dtype"] == want
 
 
-@pytest.mark.parametrize("scheme", ["resident", "stream"])
+@pytest.mark.parametrize("scheme,window,kernels", [
+    ("resident", None, 3), ("stream", None, 2),   # 2: the fused backward
+    ("stream", 64, 3)], ids=["resident", "stream", "stream-window"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_kernels_feed_the_mxu_the_planned_operand_dtype(monkeypatch,
-                                                        scheme, dtype):
-    """Every dot_general inside the three kernels of a grad call takes
-    both operands in the plan's operand dtype — bf16 in, bf16 on the
-    MXU; f32 in, the f32 contraction f32 callers always ran."""
+def test_kernels_feed_the_mxu_the_planned_operand_dtype(
+        monkeypatch, scheme, window, kernels, dtype):
+    """Every dot_general inside the kernels of a grad call takes both
+    operands in the plan's operand dtype — bf16 in, bf16 on the MXU;
+    f32 in, the f32 contraction f32 callers always ran."""
     monkeypatch.setattr(F, "_FORCE_SCHEME", scheme)
     q, k, v = qkv(t=256, dtype=dtype)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, True, None, 128, 64).astype(
-            jnp.float32).sum()
+        return flash_attention(q, k, v, True, None, 128, 64, None,
+                               window).astype(jnp.float32).sum()
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     eqns = _pallas_eqns(jaxpr.jaxpr)
-    assert len(eqns) == 3
+    assert len(eqns) == kernels
     want = F.flash_plan(256, 64, dtype=dtype, causal=True, block_q=128,
                         block_k=64)["operand_dtype"]
     for eqn in eqns:
@@ -317,19 +319,305 @@ def test_resident_grad_runs_three_2d_kernels(monkeypatch):
 
 
 def test_stream_grad_also_folds_delta(monkeypatch):
-    """The streaming fallback folds delta into the dq kernel's kk==0
-    prologue too: still exactly three pallas_calls, 3-D grids."""
+    """The streaming dq + dkv pair (what a windowed call past the
+    budget runs) folds delta into the dq kernel's kk==0 prologue too:
+    still exactly three pallas_calls, 3-D grids."""
     monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
     q, k, v = qkv(t=512)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, True, None, 128, 128).sum()
+        return flash_attention(q, k, v, True, None, 128, 128, None,
+                               128).sum()
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     eqns = _pallas_eqns(jaxpr.jaxpr)
     assert len(eqns) == 3
     for eqn in eqns:
         assert len(eqn.params["grid_mapping"].grid) == 3
+
+
+# -- the fused streaming backward (PR 31) -------------------------------------
+
+
+def _grads(q, k, v, g, **kw):
+    _, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, **kw),
+                     q, k, v)
+    return vjp(g)
+
+
+def _assert_grads_close(got, want, tol):
+    """dq, dk, dv to `tol` of each reference gradient's largest entry."""
+    for name, a, r in zip("dq dk dv".split(), got, want):
+        scale = float(jnp.max(jnp.abs(r))) or 1.0
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(r), rtol=0,
+                                   atol=tol * scale, err_msg=name)
+
+
+def _pair_only(monkeypatch):
+    """The dq + dkv pair wherever the fused backward would engage."""
+    monkeypatch.setattr(F, "_bwd_stream_tiles", lambda *a: None)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_stream_grad_is_one_backward_kernel(monkeypatch, causal):
+    """Forward + ONE backward kernel on grid (B*H, nk, nq) with three
+    outputs (dq, dk, dv) and five dot_generals — `flash_plan`'s
+    `block_matmuls` — where the pair's two hold seven."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    q, k, v = qkv(t=512)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal, None, 256, 128).sum()
+
+    def dots(eqn):
+        return _count(eqn.params["jaxpr"], "dot_general")
+
+    def bwd_kernels():
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        return _pallas_eqns(jaxpr.jaxpr)[1:]
+
+    plan = F.flash_plan(512, 64, causal=causal, block_q=256, block_k=128)
+    assert plan["bwd"]["scheme"] == "stream_fused"
+    (bwd,) = bwd_kernels()
+    assert bwd.params["grid_mapping"].grid == (2, 4, 2)
+    assert len(bwd.outvars) == 3
+    assert dots(bwd) == plan["bwd"]["block_matmuls"] == 5
+    _pair_only(monkeypatch)
+    plan = F.flash_plan(512, 64, causal=causal, block_q=256, block_k=128)
+    assert plan["bwd"]["scheme"] == "stream"
+    assert (sum(dots(e) for e in bwd_kernels())
+            == plan["bwd"]["block_matmuls"] == 7)
+
+
+@pytest.mark.parametrize("d,blocks,dtype,tol", [
+    (64, (128, 128), jnp.float32, 2e-4),
+    (64, (128, 128), jnp.bfloat16, 3e-2),
+    (64, (256, 128), jnp.float32, 2e-4),
+    (64, (256, 128), jnp.bfloat16, 3e-2),
+    (128, (128, 128), jnp.float32, 2e-4),   # the lane-filling head sizes:
+    (256, (128, 128), jnp.float32, 2e-4),   # 128, and the glm cell's 256
+], ids=["square-f32", "square-bf16", "rect-f32", "rect-bf16", "d128",
+        "d256"])
+@pytest.mark.parametrize("causal", [True, False])
+def test_fused_backward_matches_plain(monkeypatch, causal, d, blocks, dtype,
+                                      tol):
+    """dq, dk, dv of the fused kernel against plain attention's: the
+    clamped q/dO index map, the whole-head dq accumulator and the XLA
+    delta all have to be right for these to agree."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    bq, bk = blocks
+    assert F.flash_plan(512, d, dtype=dtype, causal=causal, block_q=bq,
+                        block_k=bk)["bwd"]["scheme"] == "stream_fused"
+    with jax.default_matmul_precision("highest"):
+        q, k, v = qkv(t=512, d=d, dtype=dtype)
+        g = jax.random.normal(jax.random.PRNGKey(9), q.shape, dtype)
+        got = _grads(q, k, v, g, causal=causal, block_q=bq, block_k=bk)
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        _, ref_vjp = jax.vjp(
+            lambda q, k, v: _plain_attention(q, k, v, causal, d ** -0.5),
+            *f32)
+        assert {a.dtype for a in got} == {jnp.dtype(dtype)}
+        _assert_grads_close(got, ref_vjp(g.astype(jnp.float32)), tol)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128)],
+                         ids=["square", "rect"])
+@pytest.mark.parametrize("causal", [True, False])
+def test_fused_backward_matches_the_pair(monkeypatch, causal, blocks):
+    """Same work, same numbers: on the same f32 inputs the fused
+    kernel's gradients are the dq + dkv pair's to 1e-5 (each q-block
+    still sums its k-blocks in ascending order in f32)."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    q, k, v = qkv(t=512, d=64)
+    g = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+    kw = dict(causal=causal, block_q=blocks[0], block_k=blocks[1])
+    fused = _grads(q, k, v, g, **kw)
+    _pair_only(monkeypatch)
+    for name, a, b in zip("dq dk dv".split(), fused,
+                          _grads(q, k, v, g, **kw)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=1e-5, err_msg=name)
+
+
+def test_fused_backward_takes_its_own_tiles(monkeypatch):
+    """Auto tiles: the forward keeps `_tiles`' pick, the fused backward
+    runs `_BWD_STREAM_BLOCK` squares where T divides — and still gives
+    plain attention's gradients (T 2048: a 2 x 2 grid of 1024s)."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    plan = F.flash_plan(2048, 64, causal=True)
+    assert plan["bwd"]["scheme"] == "stream_fused"
+    assert (plan["bwd"]["block_q"], plan["bwd"]["block_k"]) == (1024, 1024)
+    assert plan["bwd"]["visited_blocks"] == 3
+    with jax.default_matmul_precision("highest"):
+        q, k, v = qkv(t=2048, h=1, d=64)
+        g = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+        _, ref_vjp = jax.vjp(
+            lambda q, k, v: _plain_attention(q, k, v, True, 64 ** -0.5),
+            q, k, v)
+        _assert_grads_close(_grads(q, k, v, g, causal=True), ref_vjp(g),
+                            2e-4)
+
+
+# -- the streaming kernels under the TPU-faithful interpreter (PR 31) ---------
+#
+# `interpret=True` runs a kernel's body as plain JAX on blocks sliced
+# from the whole arrays, one grid step after another: no buffer, no
+# copy, no clock. `pltpu.InterpretParams` models the chip's memories:
+# every block moves by a DMA between HBM and a VMEM buffer under a
+# semaphore (waited for where the kernel waits, or at once with
+# `dma_execution_mode="eager"`), buffers start as NaN, and with
+# `detect_races` every read and write is checked against a vector
+# clock. A block a skipped step left stale, a copy that lands late, or
+# two steps racing on one buffer show here and not there.
+
+_TPU_INTERPRETERS = {
+    "nan-races": dict(uninitialized_memory="nan", detect_races=True),
+    "eager-dma": dict(uninitialized_memory="nan",
+                      dma_execution_mode="eager"),
+}
+
+
+def _under_tpu_interpreter(mode, fn):
+    """`fn(interpret)`'s result under the TPU interpreter in `mode`, and
+    whether it reported a race."""
+    from jax._src.pallas.mosaic.interpret import (
+        interpret_pallas_call as tpu_interpret)
+    from jax.experimental.pallas import tpu as pltpu
+
+    tpu_interpret.reset_tpu_interpret_mode_state()
+    got = jax.block_until_ready(
+        fn(pltpu.InterpretParams(**_TPU_INTERPRETERS[mode])))
+    races = tpu_interpret.races
+    return got, bool(races is not None and races.races_found)
+
+
+@pytest.mark.parametrize("mode", list(_TPU_INTERPRETERS))
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("backward", ["fused", "pair"])
+def test_stream_kernels_under_the_tpu_interpreter(monkeypatch, backward,
+                                                  causal, mode):
+    """The forward `stream` kernel with the fused backward, and with
+    `_bwd_dq_kernel` + `_bwd_dkv_kernel`, where uninitialised VMEM reads
+    as NaN and DMAs are modelled: every value finite, bit-equal to the
+    plain interpreter's, no race (B*H 2, T 512, d 128, 128 x 128: a 4 x
+    4 grid a head, so causal calls skip six steps and the clamped q/dO
+    index map repeats a block)."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    if backward == "pair":
+        _pair_only(monkeypatch)
+    assert F.flash_plan(512, 128, causal=causal, block_q=128, block_k=128)[
+        "bwd"]["scheme"] == ("stream_fused" if backward == "fused"
+                             else "stream")
+    q, k, v = qkv(t=512, d=128)
+    g = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+
+    def run(interpret):
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal, None, 128,
+                                            128, interpret), q, k, v)
+        return (out, *vjp(g))
+
+    got, raced = _under_tpu_interpreter(mode, run)
+    assert not raced
+    for name, a, b in zip("out dq dk dv".split(), got, run(True)):
+        assert bool(jnp.isfinite(a).all()), name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+
+
+def test_tpu_interpreter_sees_an_unzeroed_accumulator():
+    """The instrument's control: the fused kernel's shape of fault — a
+    VMEM accumulator summed into over grid steps and never zeroed —
+    reads NaN (as it does under this JAX's plain interpreter, whose
+    scratch starts as NaN too), so `isfinite` above means something."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(x_ref, o_ref, acc):
+        acc[...] += x_ref[...]           # no `pl.when(i == 0)` zeroing
+        o_ref[...] = acc[...]
+
+    def run(interpret):
+        return pl.pallas_call(
+            kernel, grid=(2,),
+            in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
+            interpret=interpret)(jnp.ones((16, 128), jnp.float32))
+
+    got, _ = _under_tpu_interpreter("nan-races", run)
+    assert bool(jnp.isnan(got).all()) and bool(jnp.isnan(run(True)).all())
+
+
+@pytest.mark.parametrize("kw,scheme,why", [
+    (dict(t=8192, d=256, dtype=jnp.bfloat16, causal=True), "stream_fused",
+     "the glm-4.7-flash cell's call"),
+    (dict(t=8192, d=256, dtype=jnp.bfloat16), "stream_fused",
+     "non-causal: every step computes"),
+    (dict(t=16384, d=128, dtype=jnp.bfloat16, causal=True, window=512),
+     "stream", "a window: the pair's narrowed grids differ"),
+    (dict(t=32768, d=256, dtype=jnp.bfloat16, causal=True), "stream",
+     "a head's f32 dq (32 MB) and its output block pass the limit"),
+    (dict(t=65536, d=64, dtype=jnp.bfloat16, causal=True), "stream",
+     "d = 64 fills whole 128-lane tiles in VMEM: 32 MB again"),
+    (dict(t=1024, d=64, dtype=jnp.bfloat16, causal=True), "head",
+     "the GPT cells keep the head kernels"),
+    (dict(t=4096, d=128, dtype=jnp.bfloat16, causal=True), "resident",
+     "the resident loops where they fit"),
+])
+def test_plan_says_which_backward_a_shape_takes(kw, scheme, why):
+    """The choice is a function of (t, d, dtype, causal, window)
+    alone, and `flash_plan` shows it."""
+    bwd = F.flash_plan(kw.pop("t"), kw.pop("d"), **kw)["bwd"]
+    assert bwd["scheme"] == scheme, why
+    assert bwd["block_matmuls"] == (5 if scheme in ("stream_fused", "head")
+                                    else 7)
+    if scheme == "stream_fused":
+        assert bwd["vmem_bytes"] <= F._BWD_STREAM_VMEM_LIMIT
+    else:
+        assert bwd["vmem_bytes"] <= F._VMEM_BUDGET
+
+
+def test_forced_stream_windowed_call_takes_the_pair(monkeypatch):
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    plan = F.flash_plan(2048, 64, causal=True, window=256, block_q=256,
+                        block_k=256)
+    assert plan["bwd"]["scheme"] == "stream"
+    assert plan["bwd"]["block_matmuls"] == 7
+
+
+@pytest.mark.parametrize("blocks,visited,grid", [
+    (None, 36, 64),           # the fused kernel's own 1024 x 1024
+    ((1024, 512), 72, 128),   # at the forward's tiles
+    ((512, 512), 136, 256),
+])
+def test_bwd_plan_counts_the_steps_the_kernel_computes(blocks, visited,
+                                                       grid):
+    """At the glm cell's shape: `visited_blocks` of "bwd" is the
+    number of grid steps whose `pl.when(_diag_ok(...))` holds, which
+    is the number of blocks with a visible pair; every one of them
+    builds the mask. The clamped q/dO index map names, for every
+    computing step, the step's own q-block."""
+    t = 8192
+    bq, bk = blocks or (None, None)
+    bwd = F.flash_plan(t, 256, dtype=jnp.bfloat16, causal=True,
+                       block_q=bq, block_k=bk)["bwd"]
+    bq, bk = bwd["block_q"], bwd["block_k"]
+    nq, nk = t // bq, t // bk
+    ok = np.array([[bool(F._diag_ok(iq, jk, True, bq, bk))
+                    for jk in range(nk)] for iq in range(nq)])
+    assert (ok == _visible_block_mask(t, bq, bk, None)).all()
+    assert bwd["visited_blocks"] == bwd["masked_blocks"] == ok.sum() \
+        == visited
+    assert bwd["grid_blocks"] == nq * nk == grid
+    for jk in range(nk):
+        lo, _ = F._q_span(jk, nq, causal=True, window=None, block_q=bq,
+                          block_k=bk)
+        fetched = [max(iq, lo) for iq in range(nq)]
+        assert all(fetched[iq] == iq for iq in range(nq) if ok[iq, jk])
+        assert len(set(fetched)) == ok[:, jk].sum()
 
 
 @pytest.mark.parametrize("scheme", ["resident", "stream"])
@@ -400,3 +688,4 @@ def test_flash_efficiency_smoke():
     assert meta["fwdbwd_tflops"] >= 0
     assert meta["efficiency_vs_bf16_peak"] is None  # CPU smoke
     assert meta["plan"]["fwd"]["scheme"] in ("resident", "stream")
+    assert meta["plan"]["bwd"]["block_matmuls"] in (5, 7)

@@ -228,10 +228,18 @@ def test_rigged_router_drops_nothing(favoured, held_rows):
 # -- (d) latent attention through the flash kernels at d = 256 ----------------
 
 
-def test_mla_through_flash_at_head_size_256_matches_the_plain_path():
+@pytest.mark.parametrize("scheme", [None, "stream"],
+                         ids=["head", "stream"])
+def test_mla_through_flash_at_head_size_256_matches_the_plain_path(
+        monkeypatch, scheme):
     """The published head sizes (192 + 64 and 256), rotary included,
     through `flash_attention` in interpret mode against the plain
-    path on the same parameters."""
+    path on the same parameters — by the head kernels (T 512) and,
+    forced, by the streaming forward and its one fused backward
+    kernel, which is what the cell's T 8192 runs."""
+    monkeypatch.setattr(flash, "_FORCE_SCHEME", scheme)
+    assert flash.flash_plan(512, 256, causal=True)["bwd"]["scheme"] == (
+        "stream_fused" if scheme else "head")
     c = small(hidden_size=128, num_heads=2, q_lora_rank=48,
               kv_lora_rank=32, qk_nope_head_dim=192, qk_rope_head_dim=64,
               v_head_dim=256)
@@ -243,8 +251,8 @@ def test_mla_through_flash_at_head_size_256_matches_the_plain_path():
         return jax.jit(jax.value_and_grad(
             lambda p: (mod.apply({"params": p}, x) ** 2).sum()))(params)
 
-    (plain, g_plain), (flash, g_flash) = run("local"), run("flash")
-    assert float(flash) == pytest.approx(float(plain), rel=1e-5)
+    (plain, g_plain), (fused, g_flash) = run("local"), run("flash")
+    assert float(fused) == pytest.approx(float(plain), rel=1e-5)
     for (name, a), (_, b) in zip(leaves_with_names(g_flash),
                                  leaves_with_names(g_plain)):
         assert rel_err(a, b) < 1e-4, name
@@ -308,7 +316,7 @@ def kernel_calls(jaxpr, inside=()):
 @pytest.mark.parametrize("policy", ["names", "bare"])
 @pytest.mark.parametrize("scheme, fwd, bwd", [
     (None, "_fwd_head_kernel", ["_bwd_head_kernel"]),
-    ("stream", "_kernel", ["_bwd_dq_kernel", "_bwd_dkv_kernel"]),
+    ("stream", "_kernel", ["_bwd_stream_kernel"]),
 ], ids=["head", "stream"])
 def test_recomputed_blocks_run_flash_forward_once(
         monkeypatch, request, flash_params, scheme, fwd, bwd, policy):

@@ -601,6 +601,35 @@ def test_vmem_quiet_on_real_budget():
     assert vmem_budget.check_fused_ce() == []
 
 
+def test_vmem_holds_the_fused_flash_backward_to_the_limit_it_states(
+        monkeypatch):
+    """The glm cell's call is in the grid, and its ONE backward kernel
+    (a whole head's f32 dq in VMEM: 40 MiB, far over the 15 MiB budget
+    of every other flash kernel) is held to `_BWD_STREAM_VMEM_LIMIT`,
+    the `vmem_limit_bytes` it hands Mosaic: quiet as the tree stands,
+    and named once that limit is under its estimate."""
+    from kungfu_tpu.ops import flash
+
+    cell = (8192, 256, "bfloat16", True, None)
+    assert cell in vmem_budget.FLASH_GRID
+    assert vmem_budget.check_flash(grid=[cell]) == []
+    estimate = flash.flash_plan(8192, 256, dtype="bfloat16",
+                                causal=True)["bwd"]["vmem_bytes"]
+    assert flash._VMEM_BUDGET < estimate <= flash._BWD_STREAM_VMEM_LIMIT
+    monkeypatch.setattr(flash, "_BWD_STREAM_VMEM_LIMIT", flash._VMEM_BUDGET)
+    # the engage rule reads the same limit: no tile of the fused kernel
+    # fits now, the call falls to the pair, and the pass stays quiet ...
+    assert flash.flash_plan(8192, 256, dtype="bfloat16", causal=True)[
+        "bwd"]["scheme"] == "stream"
+    assert vmem_budget.check_flash(grid=[cell]) == []
+    # ... and a kernel that engaged anyway is what it names
+    monkeypatch.setattr(flash, "_bwd_stream_tiles",
+                        lambda *a: (1024, 1024))
+    (finding,) = vmem_budget.check_flash(grid=[cell])
+    assert "flash bwd plan" in finding.message
+    assert "stream_fused" in finding.message
+
+
 def test_vmem_paged_decode_fires_under_tiny_budget():
     # the serving decode kernel's plan grid rides the same contract:
     # an impossible budget must surface as lint, not a Mosaic OOM

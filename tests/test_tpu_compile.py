@@ -93,8 +93,9 @@ def test_flash_gpt2_small(one_chip, monkeypatch, scheme, grad):
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
     # forward is one kernel; the backward adds dq and dkv, which the
-    # head scheme computes in one
-    backward = 1 if scheme is None else 2
+    # head scheme and the fused streaming backward compute in one
+    backward = 2 if scheme == "resident" else 1
+    assert plan["bwd"]["block_matmuls"] == (7 if backward == 2 else 5)
     assert _kernels(_compile(fn, *_qkv(one_chip))) == (
         1 + backward if grad else 1)
 
@@ -127,12 +128,18 @@ def test_flash_latent_attention_d256_t8192(one_chip, grad):
     """The `glm-4.7-flash.train-b1-t8192` cell's call: one sequence of
     8192, 20 heads of 256, bf16, causal. Past the head kernels
     (`_HEAD_MAX_CHUNKS`), so the budget picks 1024 x 512 tiles on the
-    streaming grid; Mosaic takes all three kernels."""
+    streaming grid for the forward, and the backward is the ONE fused
+    kernel at its own 1024 x 1024 with a whole head's f32 dq in VMEM;
+    Mosaic takes both, the second under the `vmem_limit_bytes` it
+    states."""
     from kungfu_tpu.ops import flash
 
     plan = flash.flash_plan(8192, 256, dtype=jnp.bfloat16, causal=True)
     assert (plan["block_q"], plan["block_k"]) == (1024, 512)
     assert {plan[w]["scheme"] for w in ("fwd", "dq", "dkv")} == {"stream"}
+    assert plan["bwd"]["scheme"] == "stream_fused"
+    assert (plan["bwd"]["block_q"], plan["bwd"]["block_k"]) == (1024, 1024)
+    assert plan["bwd"]["vmem_bytes"] <= flash._BWD_STREAM_VMEM_LIMIT
 
     def fwd(q, k, v):
         return flash.flash_attention(q, k, v, causal=True,
@@ -143,7 +150,41 @@ def test_flash_latent_attention_d256_t8192(one_chip, grad):
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
     shapes = _qkv(one_chip, b=1, t=8192, h=20, d=256)
-    assert _kernels(_compile(fn, *shapes)) == (3 if grad else 1)
+    compiled = _compile(fn, *shapes)
+    assert _kernels(compiled) == (2 if grad else 1)
+    # the scoped VMEM the fused kernel states, and nothing else does
+    stated = f'"size":"{flash._BWD_STREAM_VMEM_LIMIT}"'
+    assert compiled.as_text().count(stated) == (1 if grad else 0)
+
+
+@pytest.mark.parametrize("t,d,dtype,causal,scheme,kernels", [
+    # the largest estimate under the limit (63.0 MiB): Mosaic agrees
+    (32768, 128, jnp.float32, True, "stream_fused", 2),
+    (8192, 256, jnp.float32, False, "stream_fused", 2),
+    (16384, 256, jnp.bfloat16, True, "stream_fused", 2),
+    # a head's f32 dq no longer fits: the streaming dq + dkv pair
+    (32768, 256, jnp.bfloat16, True, "stream", 3),
+    (65536, 64, jnp.bfloat16, True, "stream", 3),
+], ids=["t32k-d128-f32", "t8k-d256-f32-full", "t16k-d256", "t32k-d256-pair",
+        "t64k-d64-pair"])
+def test_flash_streaming_backward_at_the_limits_edge(
+        one_chip, t, d, dtype, causal, scheme, kernels):
+    """Where `_bwd_stream_vmem` says a head's dq fits the limit the
+    fused kernel states, Mosaic takes it; past it the pair runs, and
+    Mosaic takes that."""
+    from kungfu_tpu.ops import flash
+
+    plan = flash.flash_plan(t, d, dtype=dtype, causal=causal)
+    assert plan["bwd"]["scheme"] == scheme
+
+    def loss(q, k, v):
+        return flash.flash_attention(
+            q, k, v, causal=causal, interpret=False).astype(
+                jnp.float32).sum()
+
+    s = jax.ShapeDtypeStruct((1, t, 2, d), dtype, sharding=one_chip)
+    assert _kernels(_compile(jax.grad(loss, argnums=(0, 1, 2)),
+                             s, s, s)) == kernels
 
 
 def test_grouped_expert_matmuls_published_widths(one_chip):
