@@ -30,7 +30,8 @@ from .rules import (PlanError, RuleTable, bert_tp_rules, glm_moe_rules,
                     gpt_moe_rules,
                     gpt_pp_rules, gpt_serve_rules, gpt_tp_rules,
                     match_partition_rules,
-                    moe_ep_rules, reshard, seq_sp_rules, shard_params,
+                    moe_ep_rules, ouro_rules, reshard, seq_sp_rules,
+                    shard_params,
                     spec_diff, tree_specs)
 from .vocab_ce import vocab_sharded_fused_ce
 from .train import (build_dp_replicated_train_step, build_eval_step,
@@ -65,6 +66,7 @@ __all__ = [
     "gpt_tp_rules",
     "gpt_moe_rules",
     "glm_moe_rules",
+    "ouro_rules",
     "gpt_pp_rules",
     "gpt_serve_rules",
     "moe_ep_rules",

@@ -460,6 +460,24 @@ def glm_moe_rules(axis: str = "model") -> RuleTable:
         batch_axes=("data",))
 
 
+def ouro_rules(axis: str = "model") -> RuleTable:
+    """The Megatron split for `models/ouro.py`'s parameter paths: q, k
+    and v column-parallel over heads and `o` row-parallel, the SwiGLU
+    split over its width. One table serves every pass: the passes share
+    the one `stack/` subtree. Norms, the exit gate, the embedding and
+    the head replicate."""
+    return RuleTable(
+        name=f"ouro[{axis}]",
+        rules=(
+            (r".*RotaryAttention.*/(q|k|v)/kernel", spec(None, axis, None)),
+            (r".*RotaryAttention.*/o/kernel", spec(axis, None, None)),
+            (r".*mlp/(gate|up)/kernel", cols(axis)),
+            (r".*mlp/down/kernel", rows(axis)),
+            _CATCH_ALL,
+        ),
+        batch_axes=("data",))
+
+
 def gpt_pp_rules(axis: str = "pipe",
                  tp_axis: Optional[str] = None) -> RuleTable:
     """Stage-stacked pipeline placement for the STACKED half of
@@ -675,6 +693,23 @@ def _template_glm_moe() -> Dict[str, Tuple[int, ...]]:
     return _tree_template(shapes["params"])
 
 
+@lru_cache(maxsize=8)
+def _template_ouro() -> Dict[str, Tuple[int, ...]]:
+    """`models/ouro.py` at a size whose heads and width a 2-way model
+    axis divides: two blocks, the exit gate, the untied head. Imported
+    here and not at the top, as `_template_glm_moe`."""
+    import jax.numpy as jnp
+
+    from ..models.ouro import OuroConfig, OuroLM
+
+    cfg = OuroConfig(vocab_size=251, hidden_size=64, num_heads=4,
+                     head_dim=16, intermediate_size=160, num_layers=2,
+                     dtype=jnp.float32)
+    shapes = jax.eval_shape(OuroLM(cfg).init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 16), jnp.int32))
+    return _tree_template(shapes["params"])
+
+
 def _register_builtin_tables() -> None:
     """The shipped model-family tables at the MULTICHIP dryrun shapes
     — what `python -m kungfu_tpu.analysis` statically verifies."""
@@ -708,6 +743,10 @@ def _register_builtin_tables() -> None:
              _template_glm_moe,
              [{"data": 4, "model": 2}, {"data": 1, "model": 2},
               {"data": 1, "model": 1}])
+    register("ouro", ouro_rules(),
+             _template_ouro,
+             [{"data": 4, "model": 2}, {"data": 1, "model": 2},
+              {"data": 1, "model": 1}])
     register("gpt_serve", gpt_serve_rules(),
              _template_gpt,
              # decode's (1, tp) serving mesh and the dp-replicated
@@ -735,7 +774,8 @@ def _table_universe(table: RuleTable) -> Tuple[str, ...]:
 TABLE_AXES: Dict[str, Tuple[str, ...]] = {
     f.__name__: _table_universe(f())
     for f in (bert_tp_rules, gpt_tp_rules, gpt_moe_rules,
-              glm_moe_rules, gpt_pp_rules, moe_ep_rules, seq_sp_rules,
+              glm_moe_rules, ouro_rules, gpt_pp_rules, moe_ep_rules,
+              seq_sp_rules,
               gpt_serve_rules)
 }
 

@@ -44,6 +44,17 @@ MOE_EXPERTS = "kf.moe_experts"
 #: its one expert block (whose MLA / MOE scopes nest inside it).
 MTP = "kf.mtp"
 
+#: one pass of `models/ouro.py`'s stack: every block once and the final
+#: norm. Opened inside the loop over passes, so it stands once in the
+#: HLO of a rolled loop and once a pass in the trace; the forward, the
+#: recomputed forward and the backward of every pass carry it.
+LOOP_STACK = "kf.loop_stack"
+
+#: what the looped model does with the passes: the exit gate after each,
+#: sigmoid, the exit distribution, its entropy and the weighting of the
+#: passes' cross-entropies (the head + CE calls stay under FUSED_CE).
+LOOP_EXIT = "kf.loop_exit"
+
 #: a kftrace span `name` shows in a profiler session as
 #: HOST_SPAN_PREFIX + name, on the calling thread of `/host:CPU`.
 HOST_SPAN_PREFIX = "kf."

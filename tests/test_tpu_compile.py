@@ -157,6 +157,53 @@ def test_flash_latent_attention_d256_t8192(one_chip, grad):
     assert compiled.as_text().count(stated) == (1 if grad else 0)
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_looped_model_d128_t4096(one_chip, grad):
+    """The `ouro-2.6b.train-b1-t4096` cell's call, 32 times a step: one
+    sequence of 4096, 16 heads of 128, bf16, causal. Past the head
+    kernels, inside the budget of the resident loops: 1024 x 512 tiles,
+    a forward kernel and the dq + dkv pair."""
+    from kungfu_tpu.ops import flash
+
+    plan = flash.flash_plan(4096, 128, dtype=jnp.bfloat16, causal=True)
+    assert (plan["block_q"], plan["block_k"]) == (1024, 512)
+    assert {plan[w]["scheme"] for w in ("fwd", "dq", "dkv")} == {
+        "resident"}
+    assert plan["bwd"]["scheme"] == "resident"
+
+    def fwd(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True,
+                                     interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    compiled = _compile(fn, *_qkv(one_chip, b=1, t=4096, h=16, d=128))
+    assert _kernels(compiled) == (3 if grad else 1)
+
+
+def test_row_cross_entropy_looped_model_head(one_chip):
+    """One of the cell's four head + CE calls: 4095 rows of 2048
+    against the whole 49152-row vocabulary, every row's loss under a
+    cotangent of its own (`ops/fused_ce_rows.py`): the forward kernel
+    and the d rebuild, as `fused_cross_entropy(residual=True)`."""
+    from kungfu_tpu.ops.fused_ce_rows import fused_cross_entropy_rows
+
+    def loss(x, w, t, r):
+        return (fused_cross_entropy_rows(x, w, t, interpret=False)
+                * r).sum()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 3)),
+        sds((4095, 2048), jnp.bfloat16), sds((2048, 49152), jnp.float32),
+        sds((4095,), jnp.int32), sds((4095,), jnp.float32))
+    assert _kernels(compiled) == 2
+
+
 @pytest.mark.parametrize("t,d,dtype,causal,scheme,kernels", [
     # the largest estimate under the limit (63.0 MiB): Mosaic agrees
     (32768, 128, jnp.float32, True, "stream_fused", 2),
