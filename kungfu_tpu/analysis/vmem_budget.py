@@ -38,6 +38,7 @@ FLASH_GRID = [
     (2048, 64, "float32", True, None),     # past their budget: the loops
     (2048, 128, "bfloat16", True, None),
     (4096, 64, "bfloat16", True, None),
+    (4096, 128, "bfloat16", True, None),   # the ouro cell: loops + fused bwd
     (4096, 256, "float32", True, None),
     (8192, 128, "bfloat16", True, None),
     (8192, 256, "bfloat16", False, None),
@@ -123,8 +124,9 @@ def check_flash(grid: Sequence = FLASH_GRID,
             continue  # fallback path: nothing to compile, nothing to OOM
         bq, bk = plan["block_q"], plan["block_k"]
         bwd = plan["bwd"]
-        # the fused streaming backward replaces dq + dkv, at its own
-        # tiles and under the limit it states
+        # the fused backward replaces dq + dkv behind the loops and
+        # the streaming grid alike, at its own tiles and under the
+        # limit it states
         fused = bwd["scheme"] == "stream_fused"
         kernels = [
             (which, plan[which]["scheme"], (bq, bk), flash._kernel_vmem(

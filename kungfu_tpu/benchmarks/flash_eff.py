@@ -10,8 +10,9 @@ is overhead, not work), and reports achieved TFLOP/s plus efficiency
 against the chip's bf16 peak where the device kind is known. The
 execution plan (`flash_plan`: per-kernel scheme, block sizes, visited
 vs grid blocks, and under "bwd" which backward ran — the fused
-streaming kernel, the head kernel or a dq + dkv pair — with its tiles
-and block matmuls a step) rides along so a published row names exactly
+kernel (window-less calls past the head kernels), the head kernel or a
+dq + dkv pair (windowed calls) — with its tiles and block matmuls a
+step) rides along so a published row names exactly
 which kernel configuration produced it.
 
   python -m kungfu_tpu.benchmarks.flash_eff --seq 1024 --heads 12

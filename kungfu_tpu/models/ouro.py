@@ -81,7 +81,10 @@ class RotaryAttention(nn.Module):
     """Causal self-attention, rotary on the whole head. The flash
     kernels are `pallas_call`s directly under this module's name: the
     benchmark's `loop_flash_roofline` selects
-    `RotaryAttention_<n>/pallas_call`."""
+    `RotaryAttention_<n>/pallas_call`. Two a layer application, a
+    forward and ONE backward, at the published T 4096 as at short T
+    (`ops.flash.flash_plan`'s "bwd": `stream_fused` behind the resident
+    forward there, the head kernels' own up to T 2048)."""
 
     config: OuroConfig
 

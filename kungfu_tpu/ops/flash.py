@@ -7,7 +7,7 @@ O(T) per query block (FlashAttention, Dao et al. 2022 — on TPU the
 win is HBM bandwidth, the usual bottleneck, not SRAM reuse).
 
 Three execution schemes per kernel (fwd / dq / dkv; the head scheme
-and the fused streaming backward compute dq, dk and dv in one),
+and the fused backward compute dq, dk and dv in one),
 selected by a VMEM-budget estimate in the style of
 `ops/fused_ce.py:_pick_blocks` (`flash_plan` shows the decision for a
 shape):
@@ -32,16 +32,21 @@ shape):
   and no fully-masked block is ever visited, in ALL of fwd, dq and
   dkv. The resident side is DMA'd once per head instead of once per
   outer block (the streaming grid re-fetches every K/V block nq
-  times).
+  times). The forward of every such call runs here; the dq + dkv pair
+  of loops serves WINDOWED calls only (and window-less ones that
+  `_tiles` gives several blocks under 512 rows), since window-less the
+  one-kernel backward below takes the call (PR 33).
 - **stream** (fallback past the VMEM budget — long T, big D): the
   round-5 grid (B*H, outer, inner) with VMEM-scratch-carried online
   state. Causal masking skips compute via `pl.when`; sliding windows
   narrow the inner grid dim itself (`_window_span`, affine
-  front-padded index maps). Its backward is ONE kernel
-  (`_bwd_stream_kernel`, "stream_fused" in `flash_plan`) wherever the
-  call has no window and a head's f32 dq fits the VMEM limit that
-  kernel states (`_bwd_stream_tiles`: T <= 16384 at d = 256 bf16,
-  <= 32768 at d <= 128); else the dq + dkv pair.
+  front-padded index maps).
+
+Window-less, the backward of a resident or streaming call is ONE
+kernel (`_bwd_stream_kernel`, "stream_fused" in `flash_plan`) on the
+grid (B*H, nk, nq) wherever a head's f32 dq fits the VMEM limit that
+kernel states (`_bwd_stream_tiles`: T <= 16384 at d = 256 bf16, <=
+32768 at d <= 128); else the dq + dkv pair of the call's scheme.
 
 All run the same block steps (`_fwd_step`, `_dq_step`, `_dkv_step`).
 Their matmuls take their operands in the input's dtype when that is
@@ -99,11 +104,45 @@ bit, and differs from the pair's dq in at most 0.52% and dk in 0.013%
 of elements, by a bf16 rounding of ds (delta's summation order): an L2
 distance of 1.1e-4 where either stands 2.0e-3 from flash in f32.
 
+What the chip said where the pair was the RESIDENT one (one TPU v5e,
+2026-10-04, PR 33; bf16 and causal unless said; fwd + bwd of the
+isolated call, transposes included, forward at the call's own tiles;
+pair -> the fused kernel at its 1024 x 1024). B*H 16, T 4096, d 128 —
+the `ouro-2.6b` cell's call, forward alone 0.91 ms at 1024 x 512 on
+the loops: 3.02 -> **2.37**, so the backward went 2.11 -> 1.46 ms;
+other tiles of the fused kernel 2.44 (1024 x 512), 2.45 (512 x 512),
+2.44 (512 x 1024), 2.57 (2048 x 1024 and 2048 x 2048), 3.80 (256 x
+256): the square of 1024 stays the pick at T 4096 too, though four
+of its ten computing steps there lie on the diagonal. T 2048, d 128:
+32 heads 2.07 -> 1.64 (1.62 at 512 x 512); non-causal, 16 heads (what
+`parallel/sequence.py`'s Ulysses heads send) 1.23 -> 0.95. T 4096, d
+128, non-causal 4.37 -> 3.42. T 2048, d 64, f32, 48 heads 3.31 ->
+2.73. T 2048, d 256, 20 heads (dq past the budget, dkv inside: a
+mixed pair) 2.01 -> 1.53 (1.44 at 512 x 512). ONE block (auto tiles
+at T <= 1024: non-causal, or causal outside the head kernels), fused
+at the call's tile: T 1024 non-causal d 64, 96 heads 1.73 -> 1.54; d
+128, 64 heads 1.51 -> 1.21; T 512 non-causal, 192 heads 1.15 -> 1.08;
+causal T 256, 384 heads 1.63 -> 1.36; T 128, 768 heads 1.55 -> 1.37;
+T 1000 (no power of two), 96 heads 2.06 -> 1.60. **What lost**: T
+1152, d 64, 96 heads, which `_tiles` gives 128 x 128 blocks (a 9 x 9
+grid a head): 5.06 -> 5.32, a grid step costing more than a trip of
+the loops (at 384 x 384 the fused kernel reads 3.04, at one 1152
+block 3.06: tiles nobody picks for it yet); such calls keep the pair
+(`_bwd_stream_tiles`). Already fused before PR 33, their pairs being
+past the budget and so on the streaming grid: d 64 at T 4096 x 24
+heads (4.36 -> 3.40; 3.49 at 1024 x 512, 3.50 at 512 x 512) and T 8192
+x 12 (7.13 -> 5.58), f32 d 128 at T 4096 (3.77 -> 2.89). At the
+cell's call the fused kernel repeats itself to the bit (60 draws, q
+scaled 0.5 to 8), gives the resident pair's dv to the bit, and stands
+from its dq and dk by an L2 distance of at most 3.2e-5 and 3.7e-5
+(0.52% and 0.009% of elements differ, by one bf16 rounding of ds),
+where either stands 2.0e-3 from flash in f32.
+
 Auto block sizes are budget-driven: the head kernels' chunk where they
 apply, else the largest power-of-two tile <= 1024 that keeps the worst
 kernel's VMEM estimate under budget (big head dims shrink blocks
-instead of failing to compile). The fused streaming backward takes
-1024 x 1024 where T divides, whatever the forward's tiles.
+instead of failing to compile). The fused backward takes 1024 x 1024
+where T divides, whatever the forward's tiles, else the call's.
 
 Backward overhead trims (round 6): in the dq + dkv pairs the delta
 precompute (`rowsum(dO * O)`, FlashAttention-2 eq. 4) is folded into
@@ -551,23 +590,33 @@ def _kernel_vmem(which, scheme, bq, bk, d, isz, t):
 
 
 def _bwd_stream_tiles(t, d, isz, bq, bk, causal, window, auto):
-    """(block_q, block_k) of the fused streaming backward
-    (`_bwd_stream_kernel`) where a call takes it, else None: no window
-    (the pair's narrowed grids differ between dq and dkv), both of the
-    pair's kernels on the streaming grid, and a head's dq inside the
-    limit the kernel states. Its tiles are its own: `_BWD_STREAM_BLOCK`
-    square where the caller left them to `_tiles` and T divides, else
-    the call's. A function of (t, d, dtype, causal, window) and the
-    tiles alone; `flash_plan` shows it under "bwd"."""
-    if window is not None or any(
-            _choose_scheme(which, t, d, isz, bq, bk, causal, window)
-            != "stream" for which in ("dq", "dkv")):
+    """(block_q, block_k) of the fused backward (`_bwd_stream_kernel`)
+    where a call takes it, else None: no window (the pair's narrowed
+    grids differ between dq and dkv), a pair on the loops or on the
+    streaming grid in its place (the head scheme has its own one-kernel
+    backward), and a head's dq inside the limit the kernel states. Its
+    tiles are its own: `_BWD_STREAM_BLOCK` square where the caller left
+    them to `_tiles` and T divides, else the call's. A function of
+    (t, d, dtype, causal, window) and the tiles alone; `flash_plan`
+    shows it under "bwd"."""
+    pair = {_choose_scheme(which, t, d, isz, bq, bk, causal, window)
+            for which in ("dq", "dkv")}
+    if window is not None or "head" in pair:
         return None
     tiles = [(bq, bk)]
     if auto and t % _BWD_STREAM_BLOCK == 0:
         tiles.insert(0, (_BWD_STREAM_BLOCK, _BWD_STREAM_BLOCK))
-    return next((tile for tile in tiles if _bwd_stream_vmem(
+    tile = next((tile for tile in tiles if _bwd_stream_vmem(
         *tile, d, isz, t) <= _BWD_STREAM_VMEM_LIMIT), None)
+    if tile and auto and "resident" in pair and tile[1] < 512 and t > tile[1]:
+        # a T over 1024 that 512 does not divide gets 128- or 256-row
+        # blocks from `_tiles`: a grid step of the fused kernel then
+        # costs more than a trip of the resident loops (T 1152, d 64, 96
+        # heads, 128 x 128: the pair 5.06 ms, fused 5.32; 256 rows were
+        # not measured), so such a call keeps the loops. ONE block of
+        # any size gains (module docstring).
+        return None
+    return tile
 
 
 def _dim_semantics(n):
@@ -933,8 +982,9 @@ def flash_attention(
     (q, k, v, o, lse), dq/dk/dv are computed blockwise with the
     FlashAttention-2 recurrence (p re-materialized per block from the
     saved logsumexp): one kernel for all three in the head scheme and,
-    window-less, on the streaming grid (`_bwd_stream_tiles`), else a
-    dq + dkv pair with the delta precompute inside the dq kernel. Both
+    window-less, behind the loops and the streaming grid alike
+    (`_bwd_stream_tiles`), else a dq + dkv pair with the delta
+    precompute inside the dq kernel. Both
     directions are O(T) in HBM. Non-tiling shapes fall back to the
     plain VJP.
 

@@ -191,8 +191,9 @@ def test_plan_reports_operand_dtype(dtype, want):
 
 
 @pytest.mark.parametrize("scheme,window,kernels", [
-    ("resident", None, 3), ("stream", None, 2),   # 2: the fused backward
-    ("stream", 64, 3)], ids=["resident", "stream", "stream-window"])
+    ("resident", None, 2), ("stream", None, 2),   # 2: the fused backward
+    ("resident", 64, 3), ("stream", 64, 3)],      # 3: a window keeps the pair
+    ids=["resident", "stream", "resident-window", "stream-window"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_kernels_feed_the_mxu_the_planned_operand_dtype(
         monkeypatch, scheme, window, kernels, dtype):
@@ -299,16 +300,18 @@ def _count(jaxpr, name):
 
 
 def test_resident_grad_runs_three_2d_kernels(monkeypatch):
-    """Structural: a fwd+bwd trace under the resident scheme contains
-    exactly three pallas_calls (fwd, dq, dkv) — no standalone delta
-    pass — each on a 2-D (B*H, blocks) grid, i.e. the block loop with
-    its dynamic trip count lives INSIDE the kernel. The dq call emits
-    two outputs (dq + the folded delta row set for dkv)."""
+    """Structural: a fwd+bwd trace of a WINDOWED call under the
+    resident scheme (a window keeps the dq + dkv pair) contains exactly
+    three pallas_calls (fwd, dq, dkv) — no standalone delta pass — each
+    on a 2-D (B*H, blocks) grid, i.e. the block loop with its dynamic
+    trip count lives INSIDE the kernel. The dq call emits two outputs
+    (dq + the folded delta row set for dkv)."""
     monkeypatch.setattr(F, "_FORCE_SCHEME", "resident")
     q, k, v = qkv(t=512)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, True, None, 128, 128).sum()
+        return flash_attention(q, k, v, True, None, 128, 128, None,
+                               128).sum()
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     eqns = _pallas_eqns(jaxpr.jaxpr)
@@ -316,6 +319,25 @@ def test_resident_grad_runs_three_2d_kernels(monkeypatch):
     for eqn in eqns:
         assert len(eqn.params["grid_mapping"].grid) == 2
         assert len(eqn.outvars) == 2  # (o,lse) / (dq,delta) / (dk,dv)
+
+
+def test_resident_windowless_grad_is_a_loop_and_one_backward(monkeypatch):
+    """Without a window the resident forward (2-D grid, the k-loop
+    inside) is followed by ONE backward kernel on the 3-D grid
+    (B*H, nk, nq) with dq, dk and dv as outputs (PR 33)."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "resident")
+    q, k, v = qkv(t=512)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None, 128, 128).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    fwd, bwd = _pallas_eqns(jaxpr.jaxpr)
+    assert fwd.params["grid_mapping"].grid == (2, 4)
+    assert _count(fwd.params["jaxpr"], "while") == 1
+    assert bwd.params["grid_mapping"].grid == (2, 4, 4)
+    assert len(bwd.outvars) == 3
+    assert _count(bwd.params["jaxpr"], "while") == 0
 
 
 def test_stream_grad_also_folds_delta(monkeypatch):
@@ -359,12 +381,14 @@ def _pair_only(monkeypatch):
     monkeypatch.setattr(F, "_bwd_stream_tiles", lambda *a: None)
 
 
+@pytest.mark.parametrize("scheme", ["stream", "resident"])
 @pytest.mark.parametrize("causal", [True, False])
-def test_stream_grad_is_one_backward_kernel(monkeypatch, causal):
+def test_stream_grad_is_one_backward_kernel(monkeypatch, causal, scheme):
     """Forward + ONE backward kernel on grid (B*H, nk, nq) with three
     outputs (dq, dk, dv) and five dot_generals — `flash_plan`'s
-    `block_matmuls` — where the pair's two hold seven."""
-    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    `block_matmuls` — where the pair's two hold seven: whichever of the
+    loops or the streaming grid the pair would have run on."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", scheme)
     q, k, v = qkv(t=512)
 
     def loss(q, k, v):
@@ -385,28 +409,34 @@ def test_stream_grad_is_one_backward_kernel(monkeypatch, causal):
     assert dots(bwd) == plan["bwd"]["block_matmuls"] == 5
     _pair_only(monkeypatch)
     plan = F.flash_plan(512, 64, causal=causal, block_q=256, block_k=128)
-    assert plan["bwd"]["scheme"] == "stream"
+    assert plan["bwd"]["scheme"] == scheme
     assert (sum(dots(e) for e in bwd_kernels())
             == plan["bwd"]["block_matmuls"] == 7)
 
 
-@pytest.mark.parametrize("d,blocks,dtype,tol", [
-    (64, (128, 128), jnp.float32, 2e-4),
-    (64, (128, 128), jnp.bfloat16, 3e-2),
-    (64, (256, 128), jnp.float32, 2e-4),
-    (64, (256, 128), jnp.bfloat16, 3e-2),
-    (128, (128, 128), jnp.float32, 2e-4),   # the lane-filling head sizes:
-    (256, (128, 128), jnp.float32, 2e-4),   # 128, and the glm cell's 256
+@pytest.mark.parametrize("scheme,d,blocks,dtype,tol", [
+    ("stream", 64, (128, 128), jnp.float32, 2e-4),
+    ("stream", 64, (128, 128), jnp.bfloat16, 3e-2),
+    ("stream", 64, (256, 128), jnp.float32, 2e-4),
+    ("stream", 64, (256, 128), jnp.bfloat16, 3e-2),
+    ("stream", 128, (128, 128), jnp.float32, 2e-4),   # lane-filling heads:
+    ("stream", 256, (128, 128), jnp.float32, 2e-4),   # 128, the glm cell's 256
+    # the `ouro-2.6b` cell's form: the forward on the resident loops at
+    # bq = 2 bk over four q-blocks, d 128, the backward fused (PR 33)
+    ("resident", 128, (128, 64), jnp.float32, 2e-4),
+    ("resident", 128, (128, 64), jnp.bfloat16, 3e-2),
 ], ids=["square-f32", "square-bf16", "rect-f32", "rect-bf16", "d128",
-        "d256"])
+        "d256", "resident-fwd-f32", "resident-fwd-bf16"])
 @pytest.mark.parametrize("causal", [True, False])
-def test_fused_backward_matches_plain(monkeypatch, causal, d, blocks, dtype,
-                                      tol):
+def test_fused_backward_matches_plain(monkeypatch, causal, scheme, d, blocks,
+                                      dtype, tol):
     """dq, dk, dv of the fused kernel against plain attention's: the
     clamped q/dO index map, the whole-head dq accumulator and the XLA
     delta all have to be right for these to agree."""
-    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    monkeypatch.setattr(F, "_FORCE_SCHEME", scheme)
     bq, bk = blocks
+    assert F.flash_plan(512, d, dtype=dtype, causal=causal, block_q=bq,
+                        block_k=bk)["fwd"]["scheme"] == scheme
     assert F.flash_plan(512, d, dtype=dtype, causal=causal, block_q=bq,
                         block_k=bk)["bwd"]["scheme"] == "stream_fused"
     with jax.default_matmul_precision("highest"):
@@ -421,19 +451,24 @@ def test_fused_backward_matches_plain(monkeypatch, causal, d, blocks, dtype,
         _assert_grads_close(got, ref_vjp(g.astype(jnp.float32)), tol)
 
 
-@pytest.mark.parametrize("blocks", [(128, 128), (256, 128)],
-                         ids=["square", "rect"])
+@pytest.mark.parametrize("scheme,d,blocks", [
+    ("stream", 64, (128, 128)), ("stream", 64, (256, 128)),
+    ("resident", 128, (128, 64)),   # against `_dq_res_kernel` + `_dkv_res_kernel`
+], ids=["square", "rect", "resident-pair"])
 @pytest.mark.parametrize("causal", [True, False])
-def test_fused_backward_matches_the_pair(monkeypatch, causal, blocks):
+def test_fused_backward_matches_the_pair(monkeypatch, causal, scheme, d,
+                                         blocks):
     """Same work, same numbers: on the same f32 inputs the fused
     kernel's gradients are the dq + dkv pair's to 1e-5 (each q-block
     still sums its k-blocks in ascending order in f32)."""
-    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
-    q, k, v = qkv(t=512, d=64)
+    monkeypatch.setattr(F, "_FORCE_SCHEME", scheme)
+    q, k, v = qkv(t=512, d=d)
     g = jax.random.normal(jax.random.PRNGKey(9), q.shape)
     kw = dict(causal=causal, block_q=blocks[0], block_k=blocks[1])
+    assert F.flash_plan(512, d, **kw)["bwd"]["scheme"] == "stream_fused"
     fused = _grads(q, k, v, g, **kw)
     _pair_only(monkeypatch)
+    assert F.flash_plan(512, d, **kw)["bwd"]["scheme"] == scheme
     for name, a, b in zip("dq dk dv".split(), fused,
                           _grads(q, k, v, g, **kw)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
@@ -494,21 +529,25 @@ def _under_tpu_interpreter(mode, fn):
 
 @pytest.mark.parametrize("mode", list(_TPU_INTERPRETERS))
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("backward", ["fused", "pair"])
+@pytest.mark.parametrize("backward", ["fused", "pair", "resident-fused"])
 def test_stream_kernels_under_the_tpu_interpreter(monkeypatch, backward,
                                                   causal, mode):
     """The forward `stream` kernel with the fused backward, and with
-    `_bwd_dq_kernel` + `_bwd_dkv_kernel`, where uninitialised VMEM reads
-    as NaN and DMAs are modelled: every value finite, bit-equal to the
-    plain interpreter's, no race (B*H 2, T 512, d 128, 128 x 128: a 4 x
-    4 grid a head, so causal calls skip six steps and the clamped q/dO
+    `_bwd_dq_kernel` + `_bwd_dkv_kernel`, and the `resident` forward
+    with the fused backward (what a window-less call inside the budget
+    runs since PR 33), where uninitialised VMEM reads as NaN and DMAs
+    are modelled: every value finite, bit-equal to the plain
+    interpreter's, no race (B*H 2, T 512, d 128, 128 x 128: a 4 x 4
+    grid a head, so causal calls skip six steps and the clamped q/dO
     index map repeats a block)."""
-    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    scheme = "resident" if backward == "resident-fused" else "stream"
+    monkeypatch.setattr(F, "_FORCE_SCHEME", scheme)
     if backward == "pair":
         _pair_only(monkeypatch)
-    assert F.flash_plan(512, 128, causal=causal, block_q=128, block_k=128)[
-        "bwd"]["scheme"] == ("stream_fused" if backward == "fused"
-                             else "stream")
+    plan = F.flash_plan(512, 128, causal=causal, block_q=128, block_k=128)
+    assert plan["fwd"]["scheme"] == scheme
+    assert plan["bwd"]["scheme"] == ("stream" if backward == "pair"
+                                     else "stream_fused")
     q, k, v = qkv(t=512, d=128)
     g = jax.random.normal(jax.random.PRNGKey(9), q.shape)
 
@@ -564,8 +603,24 @@ def test_tpu_interpreter_sees_an_unzeroed_accumulator():
      "d = 64 fills whole 128-lane tiles in VMEM: 32 MB again"),
     (dict(t=1024, d=64, dtype=jnp.bfloat16, causal=True), "head",
      "the GPT cells keep the head kernels"),
-    (dict(t=4096, d=128, dtype=jnp.bfloat16, causal=True), "resident",
-     "the resident loops where they fit"),
+    (dict(t=4096, d=128, dtype=jnp.bfloat16, causal=True), "stream_fused",
+     "the ouro-2.6b cell's call: the forward on the loops, ONE backward"),
+    (dict(t=4096, d=128, dtype=jnp.bfloat16, causal=True, window=512),
+     "resident", "a window keeps the resident dq + dkv loops"),
+    (dict(t=2048, d=128, dtype=jnp.bfloat16), "stream_fused",
+     "non-causal inside the budget: what Ulysses heads send"),
+    (dict(t=4096, d=64, dtype=jnp.bfloat16, causal=True), "stream_fused",
+     "d = 64 at T 4096: its pair was past the budget, fused before PR 33"),
+    (dict(t=2048, d=256, dtype=jnp.bfloat16, causal=True), "stream_fused",
+     "dq past the budget and dkv inside it: no mixed pair any more"),
+    (dict(t=1024, d=64, dtype=jnp.bfloat16), "stream_fused",
+     "one block: non-causal T <= 1024"),
+    (dict(t=1000, d=64, dtype=jnp.bfloat16, causal=True), "stream_fused",
+     "one block of no power of two"),
+    (dict(t=1152, d=64, dtype=jnp.bfloat16, causal=True), "resident",
+     "81 blocks of 128 x 128 a head: the loops' trips cost less (measured)"),
+    (dict(t=1280, d=64, dtype=jnp.bfloat16), "resident",
+     "25 blocks of 256 x 256: not measured, keeps the pair"),
 ])
 def test_plan_says_which_backward_a_shape_takes(kw, scheme, why):
     """The choice is a function of (t, d, dtype, causal, window)
@@ -580,6 +635,35 @@ def test_plan_says_which_backward_a_shape_takes(kw, scheme, why):
         assert bwd["vmem_bytes"] <= F._VMEM_BUDGET
 
 
+_HEAD_1024 = {"scheme": "head", "visited_blocks": 10, "masked_blocks": 4,
+              "grid_blocks": 16}
+_STREAM_8192 = {"scheme": "stream", "visited_blocks": 128,
+                "masked_blocks": 128, "grid_blocks": 128}
+
+
+@pytest.mark.parametrize("t,d,want", [
+    (1024, 64, {   # both GPT cells' call
+        "block_q": 256, "block_k": 256, "nq": 4, "nk": 4,
+        "operand_dtype": "bfloat16", "fwd": _HEAD_1024, "dq": _HEAD_1024,
+        "dkv": _HEAD_1024,
+        "bwd": {"scheme": "head", "block_q": 256, "block_k": 256,
+                "visited_blocks": 10, "masked_blocks": 4, "grid_blocks": 16,
+                "block_matmuls": 5, "vmem_bytes": 5517312}}),
+    (8192, 256, {   # the glm-4.7-flash cell's call
+        "block_q": 1024, "block_k": 512, "nq": 8, "nk": 16,
+        "operand_dtype": "bfloat16", "fwd": _STREAM_8192,
+        "dq": _STREAM_8192, "dkv": _STREAM_8192,
+        "bwd": {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
+                "visited_blocks": 36, "masked_blocks": 36, "grid_blocks": 64,
+                "block_matmuls": 5, "vmem_bytes": 42074112}}),
+], ids=["gpt-cells", "glm-cell"])
+def test_plans_of_the_cells_that_must_not_move(t, d, want):
+    """`flash_plan` at the shapes of the cells PR 33 leaves alone, key
+    for key against a literal copied from its parent commit: widening
+    the fused backward's engage rule moved neither."""
+    assert F.flash_plan(t, d, dtype=jnp.bfloat16, causal=True) == want
+
+
 def test_forced_stream_windowed_call_takes_the_pair(monkeypatch):
     monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
     plan = F.flash_plan(2048, 64, causal=True, window=256, block_q=256,
@@ -588,22 +672,24 @@ def test_forced_stream_windowed_call_takes_the_pair(monkeypatch):
     assert plan["bwd"]["block_matmuls"] == 7
 
 
-@pytest.mark.parametrize("blocks,visited,grid", [
-    (None, 36, 64),           # the fused kernel's own 1024 x 1024
-    ((1024, 512), 72, 128),   # at the forward's tiles
-    ((512, 512), 136, 256),
+@pytest.mark.parametrize("t,d,blocks,visited,grid", [
+    (8192, 256, None, 36, 64),   # the fused kernel's own 1024 x 1024
+    (8192, 256, (1024, 512), 72, 128),   # at the forward's tiles
+    (8192, 256, (512, 512), 136, 256),
+    (4096, 128, None, 10, 16),   # the ouro-2.6b cell's call
+    (4096, 128, (1024, 512), 20, 32),
 ])
-def test_bwd_plan_counts_the_steps_the_kernel_computes(blocks, visited,
-                                                       grid):
-    """At the glm cell's shape: `visited_blocks` of "bwd" is the
-    number of grid steps whose `pl.when(_diag_ok(...))` holds, which
-    is the number of blocks with a visible pair; every one of them
-    builds the mask. The clamped q/dO index map names, for every
-    computing step, the step's own q-block."""
-    t = 8192
+def test_bwd_plan_counts_the_steps_the_kernel_computes(t, d, blocks,
+                                                       visited, grid):
+    """At the glm and `ouro-2.6b` cells' shapes: `visited_blocks` of
+    "bwd" is the number of grid steps whose `pl.when(_diag_ok(...))`
+    holds, which is the number of blocks with a visible pair; every one
+    of them builds the mask. The clamped q/dO index map names, for
+    every computing step, the step's own q-block."""
     bq, bk = blocks or (None, None)
-    bwd = F.flash_plan(t, 256, dtype=jnp.bfloat16, causal=True,
+    bwd = F.flash_plan(t, d, dtype=jnp.bfloat16, causal=True,
                        block_q=bq, block_k=bk)["bwd"]
+    assert bwd["scheme"] == "stream_fused" and bwd["block_matmuls"] == 5
     bq, bk = bwd["block_q"], bwd["block_k"]
     nq, nk = t // bq, t // bk
     ok = np.array([[bool(F._diag_ok(iq, jk, True, bq, bk))

@@ -601,26 +601,34 @@ def test_vmem_quiet_on_real_budget():
     assert vmem_budget.check_fused_ce() == []
 
 
+@pytest.mark.parametrize("cell,pair,squeezed", [
+    # the glm cell: 40 MiB, its pair on the streaming grid
+    ((8192, 256, "bfloat16", True, None), "stream", 15 * 2**20),
+    # the ouro cell (PR 33): 24 MiB, its pair on the resident loops; the
+    # kernel at the call's 1024 x 512 would still fit 15 MiB
+    ((4096, 128, "bfloat16", True, None), "resident", 8 * 2**20),
+], ids=["glm-cell", "ouro-cell"])
 def test_vmem_holds_the_fused_flash_backward_to_the_limit_it_states(
-        monkeypatch):
-    """The glm cell's call is in the grid, and its ONE backward kernel
-    (a whole head's f32 dq in VMEM: 40 MiB, far over the 15 MiB budget
-    of every other flash kernel) is held to `_BWD_STREAM_VMEM_LIMIT`,
-    the `vmem_limit_bytes` it hands Mosaic: quiet as the tree stands,
-    and named once that limit is under its estimate."""
+        monkeypatch, cell, pair, squeezed):
+    """A cell's call is in the grid, and its ONE backward kernel (a
+    whole head's f32 dq in VMEM, far over the 15 MiB budget of every
+    other flash kernel) is held to `_BWD_STREAM_VMEM_LIMIT`, the
+    `vmem_limit_bytes` it hands Mosaic: quiet as the tree stands, and
+    named once that limit is under its estimate."""
     from kungfu_tpu.ops import flash
 
-    cell = (8192, 256, "bfloat16", True, None)
+    t, d, dtype, causal, _ = cell
     assert cell in vmem_budget.FLASH_GRID
     assert vmem_budget.check_flash(grid=[cell]) == []
-    estimate = flash.flash_plan(8192, 256, dtype="bfloat16",
-                                causal=True)["bwd"]["vmem_bytes"]
-    assert flash._VMEM_BUDGET < estimate <= flash._BWD_STREAM_VMEM_LIMIT
-    monkeypatch.setattr(flash, "_BWD_STREAM_VMEM_LIMIT", flash._VMEM_BUDGET)
+    bwd = flash.flash_plan(t, d, dtype=dtype, causal=causal)["bwd"]
+    assert bwd["scheme"] == "stream_fused"
+    assert (flash._VMEM_BUDGET < bwd["vmem_bytes"]
+            <= flash._BWD_STREAM_VMEM_LIMIT)
+    monkeypatch.setattr(flash, "_BWD_STREAM_VMEM_LIMIT", squeezed)
     # the engage rule reads the same limit: no tile of the fused kernel
     # fits now, the call falls to the pair, and the pass stays quiet ...
-    assert flash.flash_plan(8192, 256, dtype="bfloat16", causal=True)[
-        "bwd"]["scheme"] == "stream"
+    assert flash.flash_plan(t, d, dtype=dtype, causal=causal)[
+        "bwd"]["scheme"] == pair
     assert vmem_budget.check_flash(grid=[cell]) == []
     # ... and a kernel that engaged anyway is what it names
     monkeypatch.setattr(flash, "_bwd_stream_tiles",

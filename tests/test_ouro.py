@@ -295,27 +295,30 @@ def test_row_cross_entropy_kernels_match_plain_xla(n, v, sure):
 # -- (d) attention through the flash kernels at head size 128 -----------------
 
 
-@pytest.mark.parametrize("scheme", [None, "resident"],
+@pytest.mark.parametrize("scheme, seq", [(None, 512), ("resident", 1024)],
                          ids=["head", "resident"])
-def test_flash_at_head_size_128_matches_the_plain_path(monkeypatch, scheme):
+def test_flash_at_head_size_128_matches_the_plain_path(monkeypatch, scheme,
+                                                       seq):
     """The published head size, rotary included, through
     `flash_attention` in interpret mode against the plain path on the
     same parameters: by the head kernels and, forced, by the resident
-    loops over several blocks, which is what the cell's T 4096 runs."""
+    loops over several blocks with the ONE fused backward kernel behind
+    them, which is what the cell's T 4096 runs."""
     if scheme is None:  # what the cell's call reads, unforced
         cell = flash.flash_plan(4096, 128, dtype=jnp.bfloat16, causal=True)
         assert (cell["block_q"], cell["block_k"]) == (1024, 512)
-        assert {cell[w]["scheme"] for w in ("fwd", "dq", "dkv")} == {
-            "resident"}
-        assert cell["bwd"]["scheme"] == "resident"
-        assert cell["bwd"]["block_matmuls"] == 7
-        assert (cell["fwd"]["visited_blocks"],
-                cell["fwd"]["grid_blocks"]) == (20, 32)
+        assert cell["fwd"] == {"scheme": "resident", "visited_blocks": 20,
+                               "masked_blocks": 20, "grid_blocks": 32}
+        assert cell["bwd"]["scheme"] == "stream_fused"
+        assert cell["bwd"]["block_matmuls"] == 5
+        assert cell["bwd"]["vmem_bytes"] <= flash._BWD_STREAM_VMEM_LIMIT
     monkeypatch.setattr(flash, "_FORCE_SCHEME", scheme)
-    plan = flash.flash_plan(512, 128, causal=True)
-    assert plan["bwd"]["scheme"] == (scheme or "head") and plan["nq"] == 2
+    plan = flash.flash_plan(seq, 128, causal=True)
+    assert plan["fwd"]["scheme"] == (scheme or "head")
+    assert plan["nq"] == seq // 256
+    assert plan["bwd"]["scheme"] == ("stream_fused" if scheme else "head")
     c = small(hidden_size=128, num_heads=2, head_dim=128)
-    x = jax.random.normal(jax.random.PRNGKey(7), (1, 512, c.hidden_size))
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, seq, c.hidden_size))
     params = RotaryAttention(c).init(jax.random.PRNGKey(8), x)["params"]
 
     def run(attention):
@@ -339,14 +342,14 @@ def test_flash_at_head_size_128_matches_the_plain_path(monkeypatch, scheme):
 # -- (e) recomputation inside the loop ----------------------------------------
 
 
-def flash_case(**kw):
+def flash_case(seq=512, **kw):
     """Two blocks, four passes, through the flash kernels (T 512: the
     head scheme), each layer application recomputed. At hidden 64 the
     head + CE takes its plain path, so every `pallas_call` in the
     program is flash's."""
     c = small(**{**dict(attention="flash", remat=True, hidden_size=64,
                         num_heads=2, head_dim=32), **kw})
-    tokens = tokens_for(c, (1, 512))
+    tokens = tokens_for(c, (1, seq))
     model = OuroLM(c)
     return c, tokens, lambda p: ouro_fused_loss(model, p, tokens)[0]
 
@@ -358,21 +361,29 @@ def flash_params():
 
 
 @pytest.mark.parametrize("policy", ["names", "bare"])
+@pytest.mark.parametrize("scheme, seq, fwd, bwd", [
+    (None, 512, "_fwd_head_kernel", "_bwd_head_kernel"),
+    # past the head kernels (the cell's T 4096, or forced): the resident
+    # forward and ONE backward kernel an application, where a dq + dkv
+    # pair ran until PR 33
+    ("resident", 1024, "_fwd_res_kernel", "_bwd_stream_kernel"),
+    (None, 4096, "_fwd_res_kernel", "_bwd_stream_kernel"),
+], ids=["head", "resident", "t4096"])
 def test_recomputation_runs_flash_forward_once_an_application(
-        monkeypatch, flash_params, policy):
+        monkeypatch, flash_params, scheme, seq, fwd, bwd, policy):
+    monkeypatch.setattr(flash, "_FORCE_SCHEME", scheme)
     if policy == "bare":  # `jax.checkpoint` with no policy keeps no name
         monkeypatch.setattr(ouro, "_KEPT", ())
-    c, _, loss = flash_case()
+    c, _, loss = flash_case(seq)
     applications = c.num_layers * c.total_ut_steps
-    assert loop_plan(c, 1, 512)["layer_applications"] == applications == 8
+    assert loop_plan(c, 1, seq)["layer_applications"] == applications == 8
     calls = kernel_calls(
         jax.make_jaxpr(jax.grad(loss))(flash_params).jaxpr)
     recomputed = [k for k, inside in calls if remat_p.name in inside]
     first = [k for k, inside in calls if remat_p.name not in inside]
-    assert first == ["_fwd_head_kernel"] * applications
-    again = ["_fwd_head_kernel"] if policy == "bare" else []
-    assert sorted(recomputed) == sorted(
-        (again + ["_bwd_head_kernel"]) * applications)
+    assert first == [fwd] * applications
+    again = [fwd] if policy == "bare" else []
+    assert sorted(recomputed) == sorted((again + [bwd]) * applications)
 
 
 @pytest.mark.parametrize("case, names", [
