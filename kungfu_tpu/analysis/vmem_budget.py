@@ -40,7 +40,8 @@ FLASH_GRID = [
     (4096, 64, "bfloat16", True, None),
     (4096, 128, "bfloat16", True, None),   # the ouro cell: loops + fused bwd
     (4096, 256, "float32", True, None),
-    (8192, 128, "bfloat16", True, None),
+    (8192, 128, "bfloat16", True, None),   # the trinity-mini cell's full layers
+    (8192, 128, "bfloat16", True, 2047),   # its sliding layers: 512 x 512 loops
     (8192, 256, "bfloat16", False, None),
     (8192, 256, "bfloat16", True, None),   # the glm cell: fused backward
     (16384, 64, "bfloat16", True, None),

@@ -37,7 +37,6 @@ B * max_blocks * block_tokens, is what moves.
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import time
 
@@ -48,10 +47,15 @@ def measure_flash_efficiency(batch: int = 8, seq: int = 1024,
                              dtype: str = "bfloat16", iters: int = 20,
                              warmup: int = 3,
                              block_q: int | None = None,
-                             block_k: int | None = None):
+                             block_k: int | None = None,
+                             kv_heads: int | None = None,
+                             repeat_kv: bool = False):
     """Achieved flash-kernel FLOP/s at one attention shape
     (`block_q` / `block_k`: explicit tiles, for a sweep; None = the
-    auto pick the models run).
+    auto pick the models run). `kv_heads` < `heads`: grouped K/V heads,
+    k and v made at that count; with `repeat_kv` the timed call repeats
+    them to `heads` first, as a caller without grouped kernels would
+    (the comparison of PERF.md section 6, PR 34).
 
     Returns a meta dict: fwd_ms / fwdbwd_ms (per call), achieved
     TFLOP/s for both, `efficiency_vs_bf16_peak` (fwd+bwd — the number
@@ -70,12 +74,17 @@ def measure_flash_efficiency(batch: int = 8, seq: int = 1024,
         iters, warmup = min(iters, 2), min(warmup, 1)
     dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q, k, v = (jax.random.normal(kk, (batch, seq, heads, head_dim), dt)
-               for kk in ks)
+    kv_heads = kv_heads or heads
+    q, k, v = (jax.random.normal(kk, (batch, seq, n, head_dim), dt)
+               for kk, n in zip(ks, (heads, kv_heads, kv_heads)))
 
-    attend = functools.partial(flash_attention, causal=causal,
-                               window=window, block_q=block_q,
-                               block_k=block_k)
+    def attend(q, k, v):
+        if repeat_kv:
+            k, v = (jnp.repeat(x, heads // kv_heads, axis=2)
+                    for x in (k, v))
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               block_q=block_q, block_k=block_k)
+
     fwd = jax.jit(attend)
     grad = jax.jit(jax.grad(
         lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
@@ -121,7 +130,8 @@ def measure_flash_efficiency(batch: int = 8, seq: int = 1024,
             if dtype == "bfloat16" else None)
     meta = {
         "platform": platform, "batch": batch, "seq": seq,
-        "heads": heads, "head_dim": head_dim, "causal": causal,
+        "heads": heads, "kv_heads": kv_heads, "repeat_kv": repeat_kv,
+        "head_dim": head_dim, "causal": causal,
         "window": window, "dtype": dtype, "iters": iters,
         "fwd_ms": round(t_fwd * 1000, 3),
         "fwdbwd_ms": round(t_both * 1000, 3),
@@ -137,7 +147,9 @@ def measure_flash_efficiency(batch: int = 8, seq: int = 1024,
         "device_kind": jax.devices()[0].device_kind,
         "plan": flash_plan(seq, head_dim, dtype=dt, causal=causal,
                            window=window, block_q=block_q,
-                           block_k=block_k),
+                           block_k=block_k,
+                           q_per_kv=1 if repeat_kv
+                           else heads // kv_heads),
     }
     return meta
 
@@ -236,6 +248,10 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=1024)
     ap.add_argument("--heads", type=int, default=12)
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="grouped K/V heads (default: --heads)")
+    ap.add_argument("--repeat-kv", action="store_true",
+                    help="repeat K/V to --heads before the call")
     ap.add_argument("--head-dim", type=int, default=64)
     ap.add_argument("--window", type=int, default=None)
     ap.add_argument("--no-causal", action="store_true")
@@ -268,7 +284,8 @@ def main(argv=None) -> int:
         args.batch, args.seq, args.heads, args.head_dim,
         causal=not args.no_causal, window=args.window,
         dtype=args.dtype, iters=args.iters, block_q=args.block_q,
-        block_k=args.block_k)
+        block_k=args.block_k, kv_heads=args.kv_heads,
+        repeat_kv=args.repeat_kv)
     print(json.dumps({
         "metric": "flash_kernel_efficiency_vs_bf16_peak",
         "value": meta["efficiency_vs_bf16_peak"],

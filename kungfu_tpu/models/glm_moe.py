@@ -197,9 +197,15 @@ class SwiGLU(nn.Module):
 class ExpertFFN(nn.Module):
     """Shared expert + this chip's share of the routed ones. Returns
     (y, aux): the bias' zero-valued loss term, the step's counts over
-    all experts, and `grouped_moe.held_counters`."""
+    all experts, and `grouped_moe.held_counters`.
 
-    config: GlmMoeConfig
+    The tree's one expert layer: `models/afmoe.py` runs it too. Of its
+    `config` it reads `n_routed_experts` (the router's width),
+    `num_experts_per_tok`, `routed_scaling_factor`, `held`,
+    `moe_intermediate_size`, `n_shared_experts`, and `SwiGLU`'s
+    `hidden_size` and `dtype`: any config with those fields serves."""
+
+    config: Any
 
     @nn.compact
     def __call__(self, x):

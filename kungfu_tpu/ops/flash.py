@@ -35,7 +35,8 @@ shape):
   times). The forward of every such call runs here; the dq + dkv pair
   of loops serves WINDOWED calls only (and window-less ones that
   `_tiles` gives several blocks under 512 rows), since window-less the
-  one-kernel backward below takes the call (PR 33).
+  one-kernel backward below takes the call (PR 33). A model's sliding
+  layers run all three here (T 8192, d 128, window 2047: 512 x 512).
 - **stream** (fallback past the VMEM budget — long T, big D): the
   round-5 grid (B*H, outer, inner) with VMEM-scratch-carried online
   state. Causal masking skips compute via `pl.when`; sliding windows
@@ -137,6 +138,46 @@ scaled 0.5 to 8), gives the resident pair's dv to the bit, and stands
 from its dq and dk by an L2 distance of at most 3.2e-5 and 3.7e-5
 (0.52% and 0.009% of elements differ, by one bf16 rounding of ds),
 where either stands 2.0e-3 from flash in f32.
+
+Grouped K/V heads (PR 34): q is [B, T, H, D] and k, v are [B, T, H_kv,
+D] with H_kv dividing H; query head h reads K/V head h // (H / H_kv).
+Nothing repeats K/V in HBM: rows fold as b * H + h, so the K/V block
+specs of every scheme name row i // group in their index maps
+(`_kv_row`), and a resident kernel's full-length K/V block keeps its
+index over a group's consecutive rows, so it is fetched once a group.
+The backward kernels' grids run over QUERY heads and emit one dk and dv
+each in the input's dtype; a K/V head's gradient is their sum over its
+group, one f32 XLA reduction outside the kernel (`_unbh_kv`). A kernel
+that summed a group itself on the fused backward's grid would hold
+`group` heads' f32 dq in VMEM (32 MB of scratch and as much of output
+block at the cell's call where one head's is 4); the mirror-image
+kernel (dk, dv whole in VMEM, dq by blocks) would not, and is not
+written. What the chip said (one TPU v5e, 2026-10-04, PR 34; B 1, T
+8192, 32 query heads on 4, d 128, bf16, causal, fwd + bwd of the
+isolated call with the transposes and the group sum): window-less 15.30
+ms against 15.65 with K/V repeated to 32 heads first (forward 5.80 /
+6.00); window 2047 at 512 x 512 10.88 / 11.12; outputs and all three
+gradients equal to the bit either way.
+
+Windows as a model calls them (PR 34; `window` counts the keys BEFORE
+self: position q attends to keys [q - window, q], window + 1 of them.
+A checkpoint whose config says `sliding_window: W` in the HF sense, W
+keys counting self, passes `window = W - 1`). A windowed call never
+takes the head kernels nor the fused backward: forward, dq and dkv run
+on the resident loops where they fit, else on the narrowed streaming
+grid, seven block matmuls a step. Its auto tiles stay SQUARE: at T
+8192, d 128, window 2047 the budget shrink used to give 1024 x 512,
+which put dq and dkv past the loops' budget and on the streaming grid,
+where the dkv narrows only at block_q == block_k and so walked all 128
+(k-block, q-block) steps a head, 80 of them fetching 512 KB to compute
+nothing: 13.79 ms (forward 3.76, dq 4.42, dkv 5.60) for 44% of the
+pairs of a full-causal call that takes 15.30. Tiles tried, same call:
+**512 x 512 10.88** (3.09 / 3.68 / 4.10; all three on the loops, 70 of
+256 blocks), 1024 x 1024 12.01 (streaming, all narrowed, 24 of 64), 512
+x 256 13.02, 1024 x 256 13.73, 256 x 256 16.09, 2048 x 512 17.55. For
+scale, the full-causal call with the dq + dkv pair forced: 20.79 (dq
+6.80, dkv 8.20) where the fused kernel's whole backward is 9.50: what
+a fused backward for windows could be sized against.
 
 Auto block sizes are budget-driven: the head kernels' chunk where they
 apply, else the largest power-of-two tile <= 1024 that keeps the worst
@@ -899,7 +940,9 @@ def _bwd_head_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 def _head_fwd(qb, kb, vb, *, scale, block, save_lse, interpret):
     """(o, lse) or o of [B*H, T, D] inputs; lse comes back [B*H,1,1,T]."""
     bh, t, d = qb.shape
+    kv_of = _kv_row(bh // kb.shape[0])
     full = pl.BlockSpec((1, t, d), lambda i: (i, 0, 0))
+    full_kv = pl.BlockSpec((1, t, d), lambda i: (kv_of(i), 0, 0))
     o_shape = jax.ShapeDtypeStruct(qb.shape, qb.dtype)
     lse_spec = pl.BlockSpec((1, 1, 1, t), lambda i: (i, 0, 0, 0))
     lse_shape = jax.ShapeDtypeStruct((bh, 1, 1, t), jnp.float32)
@@ -908,7 +951,7 @@ def _head_fwd(qb, kb, vb, *, scale, block, save_lse, interpret):
             _fwd_head_kernel if save_lse else _fwd_head_kernel_nolse,
             scale=scale, block=block),
         grid=(bh,),
-        in_specs=[full] * 3,
+        in_specs=[full, full_kv, full_kv],
         out_specs=[full, lse_spec] if save_lse else full,
         out_shape=[o_shape, lse_shape] if save_lse else o_shape,
         compiler_params=_dim_semantics(1),
@@ -919,16 +962,19 @@ def _head_fwd(qb, kb, vb, *, scale, block, save_lse, interpret):
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "scale", "block", "interpret"))
 def _head_bwd(qb, kb, vb, dob, ob, lse, *, scale, block, interpret):
-    """(dq, dk, dv) of [B*H, T, D] inputs and the [B*H, T] lse."""
+    """(dq, dk, dv) of [B*H, T, D] queries, [B*H_kv, T, D] keys and
+    values and the [B*H, T] lse; dk and dv a QUERY head (`_unbh_kv`)."""
     bh, t, d = qb.shape
+    kv_of = _kv_row(bh // kb.shape[0])
     full = pl.BlockSpec((1, t, d), lambda i: (i, 0, 0))
+    full_kv = pl.BlockSpec((1, t, d), lambda i: (kv_of(i), 0, 0))
     return pl.pallas_call(
         functools.partial(_bwd_head_kernel, scale=scale, block=block),
         grid=(bh,),
-        in_specs=[full] * 5 + [
-            pl.BlockSpec((1, 1, 1, t), lambda i: (i, 0, 0, 0))],
+        in_specs=[full, full_kv, full_kv, full, full,
+                  pl.BlockSpec((1, 1, 1, t), lambda i: (i, 0, 0, 0))],
         out_specs=[full] * 3,
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+        out_shape=[jax.ShapeDtypeStruct(qb.shape, x.dtype)
                    for x in (qb, kb, vb)],
         scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),   # dq
                         pltpu.VMEM((1, t), jnp.float32)],  # delta
@@ -942,6 +988,9 @@ def _plain_attention(q, k, v, causal, scale, window=None):
     # mixers (sequence.py has no pallas dependency; this module does)
     from ..parallel.sequence import _local_attention
 
+    group = q.shape[2] // k.shape[2]
+    if group > 1:   # grouped K/V heads: query head h reads h // group
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     return _local_attention(q, k, v, causal=causal, scale=scale,
                             window=window)
 
@@ -958,7 +1007,12 @@ def flash_attention(
     interpret: bool | None = None,
     window: int | None = None,
 ) -> jnp.ndarray:
-    """Attention over [B, T, H, D] without materializing [T, T] scores.
+    """Attention over q [B, T, H, D] and k, v [B, T, H_kv, D] without
+    materializing [T, T] scores. H_kv divides H (grouped K/V heads;
+    H_kv == H is plain multi-head attention): query head h reads K/V
+    head h // (H / H_kv), callers do NOT repeat K/V, and dk, dv come
+    back at k's and v's shape, summed over each group in f32. The plain
+    fallback takes the same shapes.
 
     Tiling requires T % block == 0 (and causal additionally
     block_q % block_k == 0); other shapes use the plain implementation.
@@ -989,7 +1043,13 @@ def flash_attention(
     plain VJP.
 
     `window` (requires causal=True): sliding-window attention — position
-    q attends to keys [q - window, q] (Mistral-style local attention).
+    q attends to keys [q - window, q] (Mistral-style local attention):
+    `window` counts the keys BEFORE self. HF configs' `sliding_window`
+    counts self too (key j visible iff 0 <= i - j < sliding_window), so
+    a model with `sliding_window: 2048` passes `window=2047`
+    (`models/afmoe.py`; `tests/test_afmoe.py` pins the edge). A
+    windowed call's auto tiles are square (512 x 512 at d = 128 and
+    256, T 8192), its backward the dq + dkv pair: module docstring.
     Out-of-window blocks stream no DMA and spend no FLOPs — O(T *
     window) compute AND data movement — via the resident loop bounds
     (`_k_span`/`_q_span`), or, on the streaming fallback, via the
@@ -1022,8 +1082,10 @@ def _tiles(t, causal, block_q, block_k, window=None, *, d=None,
     inside a block is masked waste, and at t=16k/window=512 the 1024
     block measured 40% SLOWER (7.04 vs 5.02 ms) than 512. When the
     head dim `d` is known, auto blocks additionally shrink (bk first,
-    then bq, powers of two, floor 128) until the WORST streaming
-    kernel's VMEM estimate fits `_VMEM_BUDGET` — the fused_ce
+    then bq, powers of two, floor 128; a WINDOWED call both at once, so
+    that it stays square: 512 x 512 at d = 128 and d = 256) until the
+    WORST streaming kernel's VMEM estimate fits `_VMEM_BUDGET` — the
+    fused_ce
     `_pick_blocks` discipline, so big-D shapes trade tile size for
     compilability instead of OOMing in Mosaic. Explicit sizes are
     respected as given (no budget shrink); mixing one explicit size
@@ -1072,7 +1134,14 @@ def _tiles(t, causal, block_q, block_k, window=None, *, d=None,
                        _dkv_stream_vmem(bq, bk, d, itemsize, t))
 
         while _worst(block_q, block_k) > _VMEM_BUDGET:
-            if block_k > 128 and _pow2(block_k):
+            if (window is not None and block_q == block_k
+                    and block_k > 128 and _pow2(block_k)):
+                # a windowed call stays square: at block_q = 2 block_k
+                # the streaming dkv cannot narrow its grid and walks
+                # every q-block of every k-block (module docstring, PR
+                # 34), and the resident loops fit less often
+                block_q = block_k = block_k // 2
+            elif block_k > 128 and _pow2(block_k):
                 block_k //= 2
             elif block_q > 128 and _pow2(block_q):
                 block_q //= 2
@@ -1084,7 +1153,7 @@ def _tiles(t, causal, block_q, block_k, window=None, *, d=None,
     return block_q, block_k
 
 
-def _narrowed_kv(causal, window, block_q, block_k, nk, kb, vb):
+def _narrowed_kv(causal, window, block_q, block_k, nk, kb, vb, kv_of):
     """Streaming-scheme sliding-window narrowing, shared by the
     forward and dq paths (which MUST agree on which blocks stream):
     returns (span, kv index map, K/V inputs). With a window, the inner
@@ -1095,15 +1164,16 @@ def _narrowed_kv(causal, window, block_q, block_k, nk, kb, vb):
     proportionality allows ~8x). K/V are front-padded by span-m blocks
     (m = bq//bk, affine for any m — see `_window_span`) so the map
     stays AFFINE — a max() in the map was measured to defeat Mosaic's
-    DMA prefetch pipelining (~28% slower; see `_kernel`)."""
+    DMA prefetch pipelining (~28% slower; see `_kernel`). `kv_of` maps
+    a query head's grid row to its K/V head's (`_kv_row`)."""
     span = (_window_span(window, block_q, block_k, nk)
             if causal else None)
     if span is None:
-        return None, (lambda i, j, kk: (i, kk, 0)), kb, vb
+        return None, (lambda i, j, kk: (kv_of(i), kk, 0)), kb, vb
     m_ratio = block_q // block_k
     kv_pad = (span - m_ratio) * block_k
     return (span,
-            lambda i, j, kk: (i, j * m_ratio + kk, 0),
+            lambda i, j, kk: (kv_of(i), j * m_ratio + kk, 0),
             jnp.pad(kb, ((0, 0), (kv_pad, 0), (0, 0))),
             jnp.pad(vb, ((0, 0), (kv_pad, 0), (0, 0))))
 
@@ -1117,6 +1187,44 @@ def _bh(x):
 def _unbh(x, b, h):
     bh_, t, d = x.shape
     return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+
+def _kv_row(group):
+    """Grid row i of the queries' [B*H, T, D] -> the row of its K/V head
+    in [B*H_kv, T, D]: with H = H_kv * group, query head h reads K/V
+    head h // group, and rows fold as b * H + h, so the row is i //
+    group. The K/V block specs take it in their index maps: nothing
+    repeats K/V in HBM, and the resident schemes' full-length K/V block
+    keeps its index over a group's `group` consecutive rows, so the
+    pipeline fetches it once a group. Ungrouped, the identity."""
+    if group == 1:
+        return lambda i: i
+    return lambda i: lax.div(i, jnp.int32(group))
+
+
+def _unbh_kv(x, b, h_kv):
+    """The kernels' dk or dv, one a QUERY head [B*H, T, D], ->
+    [B, T, H_kv, D]: a K/V head's gradient is the sum over its group's
+    query heads, taken here in f32 by one XLA reduction (the backward
+    kernels' grids run over query heads; a kernel that summed a group
+    itself would hold `group` heads' dq in VMEM: module docstring)."""
+    bh_, t, d = x.shape
+    group = bh_ // (b * h_kv)
+    if group > 1:
+        x = x.reshape(b * h_kv, group, t, d).astype(jnp.float32).sum(
+            axis=1).astype(x.dtype)
+    return _unbh(x, b, h_kv)
+
+
+def _kv_group(q, k, v):
+    """Query heads a K/V head (1 ungrouped), after checking the
+    contract: k and v alike, [B, T, H_kv, D] with H_kv dividing H."""
+    if k.shape != v.shape or k.shape[:2] != q.shape[:2] or (
+            k.shape[3] != q.shape[3] or q.shape[2] % k.shape[2]):
+        raise ValueError(
+            f"flash_attention takes q [B, T, H, D] and k, v [B, T, H_kv, "
+            f"D] with H_kv dividing H; got {q.shape}, {k.shape}, {v.shape}")
+    return q.shape[2] // k.shape[2]
 
 
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
@@ -1134,6 +1242,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
         if window < 0:
             raise ValueError(f"window must be >= 0, got {window}")
     b, t, h, d = q.shape
+    kv_of = _kv_row(_kv_group(q, k, v))
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     isz = jnp.dtype(q.dtype).itemsize
@@ -1170,8 +1279,8 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
             grid=(b * h, nq),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+                pl.BlockSpec((1, t, d), lambda i, j: (kv_of(i), 0, 0)),
+                pl.BlockSpec((1, t, d), lambda i, j: (kv_of(i), 0, 0)),
             ],
             out_specs=[o_spec, lse_spec] if save_lse else o_spec,
             out_shape=[o_shape, lse_shape] if save_lse else o_shape,
@@ -1180,7 +1289,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
         )(_bh(q), _bh(k), _bh(v))
     else:
         span, kv_j, kb_in, vb_in = _narrowed_kv(
-            causal, window, block_q, block_k, nk, _bh(k), _bh(v))
+            causal, window, block_q, block_k, nk, _bh(k), _bh(v), kv_of)
         kernel = functools.partial(
             _kernel if save_lse else _kernel_nolse, scale=scale,
             causal=causal, block_q=block_q, block_k=block_k,
@@ -1369,10 +1478,14 @@ def _bwd_stream_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
 def _stream_bwd(qb, kb, vb, dob, lse, delta, *, scale, causal, block_q,
                 block_k, interpret):
-    """(dq, dk, dv) of [B*H, T, D] inputs and the [B*H, T] lse and
-    delta, by `_bwd_stream_kernel`."""
+    """(dq, dk, dv) of [B*H, T, D] queries, [B*H_kv, T, D] keys and
+    values and the [B*H, T] lse and delta, by `_bwd_stream_kernel`; dk
+    and dv a QUERY head (`_unbh_kv`)."""
     bh, t, d = qb.shape
     nq, nk = t // block_q, t // block_k
+    kv_of = _kv_row(bh // kb.shape[0])
+    kv_in = pl.BlockSpec((1, block_k, d),
+                         lambda i, j, kk: (kv_of(i), j, 0))
     kv = pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, j, 0))
     if causal:
         # the q-blocks before a k-block's first visible one compute
@@ -1394,10 +1507,10 @@ def _stream_bwd(qb, kb, vb, dob, lse, delta, *, scale, causal, block_q,
         functools.partial(_bwd_stream_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(bh, nk, nq),
-        in_specs=[kv, kv, qdo, qdo, rows, rows],
+        in_specs=[kv_in, kv_in, qdo, qdo, rows, rows],
         out_specs=[pl.BlockSpec((1, t, d), lambda i, j, kk: (i, 0, 0)),
                    kv, kv],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+        out_shape=[jax.ShapeDtypeStruct(qb.shape, x.dtype)
                    for x in (qb, kb, vb)],
         scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),        # dq
                         pltpu.VMEM((block_k, d), jnp.float32),  # dk
@@ -1413,6 +1526,8 @@ def _stream_bwd(qb, kb, vb, dob, lse, delta, *, scale, causal, block_q,
 def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                     interpret, window=None):
     b, t, h, d = q.shape
+    h_kv = k.shape[2]
+    kv_of = _kv_row(h // h_kv)
     isz = jnp.dtype(q.dtype).itemsize
     auto = block_q is None and block_k is None
     plan = _tiles(t, causal, block_q, block_k, window, d=d, itemsize=isz)
@@ -1433,9 +1548,11 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         # the glm cell's call (O's blocks ride the pipeline)
         delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1).transpose(0, 2, 1).reshape(b * h, t)
-        return tuple(_unbh(x, b, h) for x in _stream_bwd(
+        dq, dk, dv = _stream_bwd(
             qb, kb, vb, dob, lse, delta, scale=scale, causal=causal,
-            block_q=fused[0], block_k=fused[1], interpret=interpret))
+            block_q=fused[0], block_k=fused[1], interpret=interpret)
+        return (_unbh(dq, b, h), _unbh_kv(dk, b, h_kv),
+                _unbh_kv(dv, b, h_kv))
     ob = _bh(o)
     # lse enters the kernels at TRUE [B*H, T] size, reshaped to
     # [B*H, nq, 1, block_q] so Mosaic's tiling rule (trailing block
@@ -1459,9 +1576,11 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
     scheme = _choose_scheme("dq", t, d, isz, block_q, block_k, causal,
                             window)
     if scheme == "head":   # one kernel for dq, dk and dv
-        return tuple(_unbh(x, b, h) for x in _head_bwd(
+        dq, dk, dv = _head_bwd(
             qb, kb, vb, dob, ob, lse, scale=scale, block=block_q,
-            interpret=interpret))
+            interpret=interpret)
+        return (_unbh(dq, b, h), _unbh_kv(dk, b, h_kv),
+                _unbh_kv(dv, b, h_kv))
     if scheme == "resident":
         dq_kernel = functools.partial(
             _dq_res_kernel, scale=scale, causal=causal, block_q=block_q,
@@ -1471,8 +1590,8 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
             grid=(b * h, nq),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+                pl.BlockSpec((1, t, d), lambda i, j: (kv_of(i), 0, 0)),
+                pl.BlockSpec((1, t, d), lambda i, j: (kv_of(i), 0, 0)),
                 pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
                 pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
                 pl.BlockSpec((1, 1, 1, block_q),
@@ -1492,7 +1611,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         # is the single definition, so fwd and dq cannot disagree on
         # which blocks stream; narrows for any m = bq//bk (affine)
         span, kv_j, kb_in, vb_in = _narrowed_kv(
-            causal, window, block_q, block_k, nk, kb, vb)
+            causal, window, block_q, block_k, nk, kb, vb, kv_of)
         dq_kernel = functools.partial(
             _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
             block_k=block_k, window=window, span=span)
@@ -1527,6 +1646,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
             interpret=interpret,
         )(qb, kb_in, vb_in, dob, ob, lse4)
 
+    # one dk and dv a QUERY head: `_unbh_kv` sums each group's
     dkv_shapes = [
         jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
         jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
@@ -1540,8 +1660,10 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
             dkv_kernel,
             grid=(b * h, nk),
             in_specs=[
-                pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+                pl.BlockSpec((1, block_k, d),
+                             lambda i, j: (kv_of(i), j, 0)),
+                pl.BlockSpec((1, block_k, d),
+                             lambda i, j: (kv_of(i), j, 0)),
                 pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
                 pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
                 pl.BlockSpec((1, nq, 1, block_q),
@@ -1584,9 +1706,9 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                   span_dkv if span_dkv is not None else nq),
             in_specs=[
                 pl.BlockSpec((1, block_k, d),
-                             lambda i, j, kk: (i, j, 0)),
+                             lambda i, j, kk: (kv_of(i), j, 0)),
                 pl.BlockSpec((1, block_k, d),
-                             lambda i, j, kk: (i, j, 0)),
+                             lambda i, j, kk: (kv_of(i), j, 0)),
                 pl.BlockSpec((1, block_q, d), qdo_j),
                 pl.BlockSpec((1, block_q, d), qdo_j),
                 pl.BlockSpec((1, nq, 1, block_q),
@@ -1608,7 +1730,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
             compiler_params=_dim_semantics(3),
             interpret=interpret,
         )(kb, vb, qb_in, dob_in, lse4, delta4)
-    return (_unbh(dq, b, h), _unbh(dk, b, h), _unbh(dv, b, h))
+    return (_unbh(dq, b, h), _unbh_kv(dk, b, h_kv), _unbh_kv(dv, b, h_kv))
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
@@ -1656,7 +1778,7 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
-               block_q=None, block_k=None):
+               block_q=None, block_k=None, q_per_kv=1):
     """Static execution plan for `flash_attention` at this shape: block
     sizes, the MXU operand dtype of the block matmuls
     (`_operand_dtype`), per-kernel scheme, and per-kernel VISITED K/V
@@ -1676,7 +1798,16 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
     5). Its `visited_blocks` are the (q-block, k-block) pairs whose
     block step RUNS — for the streaming grids fewer than the grid
     steps "dq" and "dkv" count, since `pl.when` skips the rest — and
-    `vmem_bytes` the largest estimate among its kernels."""
+    `vmem_bytes` the largest estimate among its kernels. A windowed
+    call reads the same way: its backward is the dq + dkv pair, and
+    "dq" / "dkv" say which of the pair narrowed its grid or its loops.
+
+    `q_per_kv` > 1 (grouped K/V heads: that many query heads read one
+    K/V head) changes no tile, scheme or count, all of them a query
+    head's, and adds one entry, "kv_group": how K/V are read (`_kv_row`
+    in the block specs' index maps), where dk and dv are summed over a
+    group (`_unbh_kv`), and the bytes of the per-query-head dk and dv
+    that sum reads, a K/V head. Ungrouped plans carry no such key."""
     isz = jnp.dtype(dtype).itemsize
     tiles = _tiles(t, causal, block_q, block_k, window, d=d,
                    itemsize=isz)
@@ -1732,6 +1863,12 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
         "grid_blocks": nq * nk,
         "block_matmuls": 5 if scheme in ("head", "stream_fused") else 7,
         "vmem_bytes": vmem}
+    if q_per_kv > 1:
+        plan["kv_group"] = {
+            "q_per_kv": q_per_kv,
+            "kv_read": "index_map",   # row i of q reads K/V row i // g
+            "dkv_sum": "xla_f32",     # over a group, outside the kernel
+            "dkv_partial_bytes": 2 * q_per_kv * t * d * isz}
     return plan
 
 

@@ -26,7 +26,8 @@ from .expert import (MoEParams, dispatch_tensors, init_moe_params,
                      moe_capacity, moe_mlp)
 from .pipeline import (pipeline_apply, pipeline_train_step_1f1b,
                        stack_stage_params)
-from .rules import (PlanError, RuleTable, bert_tp_rules, glm_moe_rules,
+from .rules import (PlanError, RuleTable, afmoe_rules, bert_tp_rules,
+                    glm_moe_rules,
                     gpt_moe_rules,
                     gpt_pp_rules, gpt_serve_rules, gpt_tp_rules,
                     match_partition_rules,
@@ -67,6 +68,7 @@ __all__ = [
     "gpt_moe_rules",
     "glm_moe_rules",
     "ouro_rules",
+    "afmoe_rules",
     "gpt_pp_rules",
     "gpt_serve_rules",
     "moe_ep_rules",

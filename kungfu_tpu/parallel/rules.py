@@ -478,6 +478,31 @@ def ouro_rules(axis: str = "model") -> RuleTable:
         batch_axes=("data",))
 
 
+def afmoe_rules(axis: str = "model") -> RuleTable:
+    """The Megatron split for `models/afmoe.py`'s parameter paths, both
+    kinds of attention layer alike: q, k and v column-parallel over
+    their heads (the K/V heads are fewer: the axis must divide THEIR
+    count), the output gate over its columns, which lie head by head as
+    `o`'s rows do, and `o` row-parallel; every SwiGLU (dense, shared
+    expert) and the held experts' stacks split over their width. Norms
+    (the QK-norms' [head_dim] scales too), routers and their biases,
+    the embedding and the head replicate. The stacks' leading dim is
+    the held experts', as in `glm_moe_rules`."""
+    return RuleTable(
+        name=f"afmoe[{axis}]",
+        rules=(
+            (r".*Attention_\d+/(q|k|v)/kernel", spec(None, axis, None)),
+            (r".*Attention_\d+/gate/kernel", cols(axis)),
+            (r".*Attention_\d+/o/kernel", rows(axis)),
+            (r".*(mlp|shared)/(gate|up)/kernel", cols(axis)),
+            (r".*(mlp|shared)/down/kernel", rows(axis)),
+            (r".*moe/w_(gate|up)", spec(None, None, axis)),
+            (r".*moe/w_down", spec(None, axis, None)),
+            _CATCH_ALL,
+        ),
+        batch_axes=("data",))
+
+
 def gpt_pp_rules(axis: str = "pipe",
                  tp_axis: Optional[str] = None) -> RuleTable:
     """Stage-stacked pipeline placement for the STACKED half of
@@ -710,6 +735,27 @@ def _template_ouro() -> Dict[str, Tuple[int, ...]]:
     return _tree_template(shapes["params"])
 
 
+@lru_cache(maxsize=8)
+def _template_afmoe() -> Dict[str, Tuple[int, ...]]:
+    """`models/afmoe.py` at a size whose heads (4 on 2 K/V heads) and
+    widths a 2-way model axis divides: a dense sliding block and an
+    expert full block holding 2 of 8 experts. Imported here and not at
+    the top, as `_template_glm_moe`."""
+    import jax.numpy as jnp
+
+    from ..models.afmoe import FULL, SLIDING, AfmoeConfig, AfmoeLM
+
+    cfg = AfmoeConfig(
+        vocab_size=251, hidden_size=64, num_heads=4, num_kv_heads=2,
+        head_dim=16, sliding_window=8, layer_types=(SLIDING, FULL),
+        num_dense_layers=1, intermediate_size=160,
+        moe_intermediate_size=48, n_routed_experts=8,
+        num_experts_per_tok=2, held=(0, 2), dtype=jnp.float32)
+    shapes = jax.eval_shape(AfmoeLM(cfg).init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 16), jnp.int32))
+    return _tree_template(shapes["params"])
+
+
 def _register_builtin_tables() -> None:
     """The shipped model-family tables at the MULTICHIP dryrun shapes
     — what `python -m kungfu_tpu.analysis` statically verifies."""
@@ -747,6 +793,10 @@ def _register_builtin_tables() -> None:
              _template_ouro,
              [{"data": 4, "model": 2}, {"data": 1, "model": 2},
               {"data": 1, "model": 1}])
+    register("afmoe", afmoe_rules(),
+             _template_afmoe,
+             [{"data": 4, "model": 2}, {"data": 1, "model": 2},
+              {"data": 1, "model": 1}])
     register("gpt_serve", gpt_serve_rules(),
              _template_gpt,
              # decode's (1, tp) serving mesh and the dp-replicated
@@ -774,7 +824,8 @@ def _table_universe(table: RuleTable) -> Tuple[str, ...]:
 TABLE_AXES: Dict[str, Tuple[str, ...]] = {
     f.__name__: _table_universe(f())
     for f in (bert_tp_rules, gpt_tp_rules, gpt_moe_rules,
-              glm_moe_rules, ouro_rules, gpt_pp_rules, moe_ep_rules,
+              glm_moe_rules, ouro_rules, afmoe_rules, gpt_pp_rules,
+              moe_ep_rules,
               seq_sp_rules,
               gpt_serve_rules)
 }

@@ -55,6 +55,18 @@ LOOP_STACK = "kf.loop_stack"
 #: passes' cross-entropies (the head + CE calls stay under FUSED_CE).
 LOOP_EXIT = "kf.loop_exit"
 
+#: the attention sublayer of a SLIDING-window layer of
+#: `models/afmoe.py`: both sandwich norms, the q / k / v / gate
+#: projections, QK-norm, rotary, the windowed flash (or plain) call, the
+#: output gate and `o`. The kernels stay `pallas_call`s directly under
+#: `LocalAttention_<n>` inside it.
+ATTN_LOCAL = "kf.attn_local"
+
+#: the same of a FULL-attention layer (no positions, no window); the
+#: kernels directly under `GlobalAttention_<n>`. Two names, so that a
+#: trace prices the two kinds of layer of one stack apart.
+ATTN_GLOBAL = "kf.attn_global"
+
 #: a kftrace span `name` shows in a profiler session as
 #: HOST_SPAN_PREFIX + name, on the calling thread of `/host:CPU`.
 HOST_SPAN_PREFIX = "kf."

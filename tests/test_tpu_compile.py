@@ -188,6 +188,69 @@ def test_flash_looped_model_d128_t4096(one_chip, grad):
     assert compiled.as_text().count(stated) == (1 if grad else 0)
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("window, kernels", [(None, 2), (2047, 3)],
+                         ids=["full", "sliding"])
+def test_flash_grouped_heads_d128_t8192(one_chip, window, kernels, grad):
+    """The `trinity-mini.train-b1-t8192` cell's two calls: one sequence
+    of 8192, 32 query heads of 128 on 4 K/V heads, bf16, causal. A full
+    layer's: the forward on the resident loops at 1024 x 512, ONE fused
+    backward kernel at its own 1024 x 1024. A sliding layer's (window
+    2047 = `sliding_window - 1`): 512 x 512 tiles, forward, dq and dkv
+    all on the resident loops. K/V reach every kernel through `i //
+    8` in their block specs' index maps, which Mosaic takes in the
+    resident, streaming and fused forms alike."""
+    from kungfu_tpu.ops import flash
+
+    plan = flash.flash_plan(8192, 128, dtype=jnp.bfloat16, causal=True,
+                            window=window, q_per_kv=8)
+    assert plan["fwd"]["scheme"] == "resident"
+    assert plan["bwd"]["scheme"] == ("resident" if window
+                                     else "stream_fused")
+    assert (plan["block_q"], plan["block_k"]) == (
+        (512, 512) if window else (1024, 512))
+
+    def fwd(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True, window=window,
+                                     interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    q = _qkv(one_chip, b=1, t=8192, h=32, d=128)[0]
+    k = _qkv(one_chip, b=1, t=8192, h=4, d=128)[0]
+    compiled = _compile(fn, q, k, k)
+    assert _kernels(compiled) == (kernels if grad else 1)
+    if grad:   # dk and dv come back at the K/V heads' count
+        assert [x.shape for x in compiled.out_info] == [
+            (1, 8192, 32, 128), (1, 8192, 4, 128), (1, 8192, 4, 128)]
+
+
+def test_grouped_expert_matmuls_top8_of_128(one_chip):
+    """The held experts' grouped SwiGLU at the `trinity-mini` cell's
+    sizes: 8 experts of 2048 x 1024 over the worst-case row buffer
+    (8192 tokens x min(8, 8) = 65536 rows), forward and backward."""
+    from kungfu_tpu.parallel.grouped_moe import buffer_rows, grouped_swiglu
+
+    rows, h, f, held = buffer_rows(8192, 8, (0, 8)), 2048, 1024, 8
+    assert rows == 65536
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        return grouped_swiglu(x, w_gate, w_up, w_down, sizes).astype(
+            jnp.float32).sum()
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3)),
+        arg((rows, h), jnp.bfloat16), arg((held, h, f), jnp.float32),
+        arg((held, h, f), jnp.float32), arg((held, f, h), jnp.float32),
+        arg((held,), jnp.int32))
+    assert compiled.as_text().count("ragged-dot") >= 9
+
+
 def test_row_cross_entropy_looped_model_head(one_chip):
     """One of the cell's four head + CE calls: 4095 rows of 2048
     against the whole 49152-row vocabulary, every row's loss under a
