@@ -58,6 +58,7 @@ import jax.numpy as jnp
 
 from ..ops.flash import (FLASH_LSE, FLASH_OUT, _plain_attention,
                          flash_attention, flash_plan)
+from ..parallel.grouped_moe import MOE_ROUTED
 from ..trace.scopes import ATTN_GLOBAL, ATTN_LOCAL
 from .glm_moe import ExpertFFN, SwiGLU, _dense, _norm, _stack_aux, rotary
 
@@ -185,9 +186,13 @@ class Block(nn.Module):
 # what a recomputed block keeps beside its input: the two residuals of
 # flash's backward that only its forward kernel can make
 # (`models/glm_moe.py::_KEPT`, PR 28), so no kernel of either kind of
-# call runs twice. q, k, v and `o`'s output are NOT kept: at T 8192 they
-# are 151 MB a block and the chip is full (PERF.md section 6, PR 34).
-_KEPT = (FLASH_OUT, FLASH_LSE)
+# call runs twice, and an expert block's routed output (33.5 MB at T
+# 8192): the fourth norm's backward reads it, and without the name the
+# recomputed forward runs the whole routed path for it, which the
+# routed path's own backward never uses (PERF.md section 6, PR 35). q,
+# k, v and `o`'s output are NOT kept: at T 8192 they are 151 MB a block
+# and the chip is full (PERF.md section 6, PR 34).
+_KEPT = (FLASH_OUT, FLASH_LSE, MOE_ROUTED)
 
 
 class AfmoeLM(nn.Module):
@@ -233,20 +238,34 @@ def afmoe_logits(model: AfmoeLM, params, token_ids):
 def afmoe_fused_loss(model: AfmoeLM, params, token_ids,
                      interpret: bool | None = None,
                      residual: bool = True):
-    """(objective, metrics): the mean next-token CE through
-    `ops.fused_ce.fused_cross_entropy` (no [B, T, V] logits), plus the
+    """(objective, metrics): the mean next-token CE through the fused
+    head + CE kernels (no [B, T, V] logits; `residual=False`: the
+    scheme of `ops/fused_ce.py` that keeps no logits at all), plus the
     expert layers' zero-valued bias terms. `metrics` holds the CE and
     the expert layers' counters as device arrays: pass `has_aux=True`
     to the step builder."""
     from ..ops.fused_ce import fused_cross_entropy
+    from ..ops.fused_ce_rows import fused_cross_entropy_rows
 
     c = model.config
     hidden, aux = model.apply({"params": params}, token_ids)
-    ce = fused_cross_entropy(
-        hidden[:, :-1].reshape(-1, c.hidden_size), params["lm_head"],
-        jnp.zeros((c.vocab_size,), jnp.float32),   # the head has no bias
-        token_ids[:, 1:].reshape(-1), interpret=interpret,
-        residual=residual)
+    x = hidden[:, :-1].reshape(-1, c.hidden_size)
+    targets = token_ids[:, 1:].reshape(-1)
+    if residual:
+        # the mean of the ROWS' form: the same kernels, and its backward
+        # takes a row's target column from the row's f32 loss, where
+        # `fused_cross_entropy`'s rebuilds it from the bf16 logits and
+        # is 2-3 times off on rows the model is sure of (ROADMAP D14):
+        # after ~55 steps on this model's ring that alone puts layer
+        # 0's gradients 0.6 from the reference's (PERF.md section 6,
+        # PR 35)
+        ce = fused_cross_entropy_rows(x, params["lm_head"], targets,
+                                      interpret=interpret).mean()
+    else:
+        ce = fused_cross_entropy(
+            x, params["lm_head"],
+            jnp.zeros((c.vocab_size,), jnp.float32),  # the head has no bias
+            targets, interpret=interpret, residual=False)
     metrics = {"ce": ce}
     loss = ce
     if aux:
@@ -271,13 +290,15 @@ def layer_plan(c: AfmoeConfig, batch: int, seq: int):
     `window` the sliding layers hand `flash_attention`, and what
     recomputation keeps from forward to backward for each block, its
     input and `_KEPT` (flash's two names only where attention runs the
-    kernel: the plain path sets none; `jax.ad_checkpoint.
+    kernel: the plain path sets none; the routed output in the expert
+    blocks alone, `kept_bytes_per_expert_block`; `jax.ad_checkpoint.
     saved_residuals` is what the tests hold it to)."""
     isz = jnp.dtype(c.dtype).itemsize
     window = c.sliding_window - 1
     kept = {}
+    state = batch * seq * c.hidden_size * isz
     if c.remat:
-        kept["input"] = batch * seq * c.hidden_size * isz
+        kept["input"] = state
         if c.attention == "flash" and all("fwd" in flash_plan(
                 seq, c.head_dim, dtype=c.dtype, causal=True, window=w)
                 for w in {window if kind == SLIDING else None
@@ -286,6 +307,10 @@ def layer_plan(c: AfmoeConfig, batch: int, seq: int):
             kept[FLASH_OUT] = rows * c.head_dim * isz
             kept[FLASH_LSE] = rows * 4
     per_block = sum(kept.values())
+    experts = c.num_layers - c.num_dense_layers
+    routed = state if c.remat and experts else 0
+    if routed:
+        kept[MOE_ROUTED] = routed
     return {
         "layers": tuple(
             ("sliding" if kind == SLIDING else "full",
@@ -296,4 +321,5 @@ def layer_plan(c: AfmoeConfig, batch: int, seq: int):
                           "sliding": visible_pairs(seq, window)},
         "kept": tuple(kept),
         "kept_bytes_per_block": per_block,
-        "kept_bytes": per_block * c.num_layers}
+        "kept_bytes_per_expert_block": per_block + routed,
+        "kept_bytes": per_block * c.num_layers + routed * experts}

@@ -224,25 +224,26 @@ class ExpertFFN(nn.Module):
         w_up = stack("w_up", (count, h, f), h)
         w_down = stack("w_down", (count, f, h), f)
         flat = x.reshape(b * t, h)
+        ladder = gm.row_ladder(b * t, c.num_experts_per_tok, c.held, e)
         with jax.named_scope(MOE_ROUTE):
             routing = gm.route_sigmoid_topk(
                 flat, router, bias, c.num_experts_per_tok,
                 c.routed_scaling_factor)
             d = gm.plan_dispatch(routing.idx, c.held)
-            rows = gm.dispatch_rows(flat, d.row_assign, d.pos, d.valid)
         with jax.named_scope(MOE_EXPERTS):
-            out = gm.grouped_swiglu(rows, w_gate, w_up, w_down,
-                                    d.group_sizes)
             shared = SwiGLU(c, f * c.n_shared_experts, name="shared")(x)
-        with jax.named_scope(MOE_ROUTE):
-            y = gm.combine_rows(out, routing.weights, d.row_assign,
-                                d.pos, d.valid).reshape(b, t, h)
+            # cast once, outside the routed path: its backward rebuilds
+            # the forward and would cast the stacks again
+            stacks = [w.astype(flat.dtype) for w in (w_gate, w_up, w_down)]
+        # opens kf.moe_route and kf.moe_experts itself, under its switch
+        y = gm.routed_experts(flat, routing.weights, d, *stacks,
+                              ladder).reshape(b, t, h)
         counts = routing.counts.astype(jnp.float32)
         load_sign = lax.stop_gradient(jnp.sign(counts - counts.mean()))
         pull = jnp.vdot(bias, load_sign)
         aux = {"bias_loss": pull - lax.stop_gradient(pull),
                "counts": routing.counts,
-               **gm.held_counters(d, rows.shape[0])}
+               **gm.held_counters(d, ladder)}
         return shared + y, aux
 
 

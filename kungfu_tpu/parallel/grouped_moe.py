@@ -16,27 +16,55 @@ one-hot dispatch einsums. Here
   exchanged over an `expert` mesh axis the expert-parallel one (not
   run across chips yet: no all-to-all is written here);
 - **nothing is dropped**: the (token, held expert) assignments are
-  sorted by expert into a row buffer of the true worst case,
-  `tokens * min(k, count)` rows (a token's k experts are distinct, so
-  no routing can need more), and one grouped matmul a projection
-  (`jax.lax.ragged_dot`) runs over the rows the group sizes cover. XLA
+  sorted by expert, and the true worst case is `tokens * min(k, count)`
+  rows (`buffer_rows`: a token's k experts are distinct, so no routing
+  can need more). One grouped matmul a projection
+  (`jax.lax.ragged_dot`) runs over the rows the group sizes cover; XLA
   lowers it on the TPU to a Mosaic kernel that walks row tiles by
-  group, so the work follows the group sizes and not the buffer
-  (PERF.md section 6, PR 27 has the chip's readings). Rows past the
-  last group are never read back.
+  group, so ITS work follows the group sizes. Everything else (the
+  dispatch and combine gathers, SwiGLU's elementwise passes, the
+  cotangents of the rows) is as long as the buffer, and a chip that
+  holds `count` of `E` experts uses `count / E` of the worst case on
+  average. So the buffer has a **ladder** of static lengths
+  (`row_ladder`: twice and four times the expected rows, then
+  `buffer_rows`, which always ends it), the routed path is compiled
+  once a rung,
+  and the device takes, each step and each layer, the smallest rung
+  that holds the step's `sum(group_sizes)` (`routed_experts`). The
+  top rung IS the worst case, so no routing can lack a row: `dropped`
+  is still counted against the worst case, outside the switch, and
+  reads 0. A caller that holds every expert has one rung and no
+  switch.
 
 Dispatch and combine are gathers in both directions: each has a
 `custom_vjp` whose backward gathers through the inverse permutation,
 where autodiff would scatter-add 32k rows.
+
+The switch is not differentiated through. JAX's partial evaluation of
+`cond` would make the forward switch return the union of every rung's
+residuals, each branch filling the other rungs' with zeros (1.2 GB of
+zero-fill a layer at 65,536 x 2048). `routed_experts` is ONE
+`custom_vjp` with a switch in each half: its residuals are its inputs,
+none as long as a rung, and each backward branch rebuilds its rung's
+forward (`jax.vjp` of the same branch function) and applies it.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from ..trace.scopes import MOE_EXPERTS, MOE_ROUTE
+
+# `checkpoint_name` of `routed_experts`' output, [N, H]: a recomputed
+# block whose policy keeps it runs no routed forward again (its
+# backward rebuilds the rung it needs anyway); inert elsewhere
+MOE_ROUTED = "kf.moe_routed"
 
 
 class Routing(NamedTuple):
@@ -75,6 +103,28 @@ def route_sigmoid_topk(x, router, bias, k: int,
 def buffer_rows(tokens: int, k: int, held: Tuple[int, int]) -> int:
     """Rows that hold every held assignment whatever the routing."""
     return tokens * min(k, held[1])
+
+
+def row_ladder(tokens: int, k: int, held: Tuple[int, int],
+               router_width: int) -> Tuple[int, ...]:
+    """The static buffer lengths the routed path is compiled at, from
+    shapes alone: twice and four times the rows a balanced router sends
+    here (`tokens * k * count / router_width`), then the worst case,
+    which always ends it. One rung where twice the expected rows reach
+    the worst case (every expert held). No rung between four times and
+    the worst case: a rung costs a compile of the routed path (1.3 s a
+    layer on the chip's compiler) and no step on record needed one
+    (PERF.md section 6, PR 35)."""
+    top = buffer_rows(tokens, k, held)
+    first = 2 * -(-tokens * k * held[1] // router_width)
+    return (*(r for r in (first, 2 * first) if r < top), top)
+
+
+def rung_index(ladder: Tuple[int, ...], group_sizes):
+    """The smallest rung that holds every held assignment of the step:
+    by the rows held and by nothing else."""
+    return jnp.sum(jnp.sum(group_sizes)
+                   > jnp.asarray(ladder[:-1], jnp.int32)).astype(jnp.int32)
 
 
 def plan_dispatch(idx, held: Tuple[int, int]) -> Dispatch:
@@ -172,15 +222,77 @@ def grouped_swiglu(rows, w_gate, w_up, w_down, group_sizes):
     return grouped(hidden, w_down)
 
 
-def held_counters(d: Dispatch, rows: int) -> dict:
+def _routed(rung: int, x, weights, d: Dispatch, w_gate, w_up, w_down):
+    """Dispatch -> grouped SwiGLU -> combine on a buffer of `rung` rows:
+    right for every step whose held assignments fit it. The two scopes
+    open HERE, inside what a switch branches to, so that a trace's
+    `conditional` event (which spans its branch) carries neither."""
+    row_assign, valid = d.row_assign, d.valid
+    if rung < row_assign.shape[0]:
+        row_assign, valid = row_assign[:rung], valid & (d.pos < rung)
+    with jax.named_scope(MOE_ROUTE):
+        rows = dispatch_rows(x, row_assign, d.pos, valid)
+    with jax.named_scope(MOE_EXPERTS):
+        out = grouped_swiglu(rows, w_gate, w_up, w_down, d.group_sizes)
+    with jax.named_scope(MOE_ROUTE):
+        return combine_rows(out, weights, row_assign, d.pos, valid)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _laddered(ladder, x, weights, d, w_gate, w_up, w_down):
+    with jax.named_scope(MOE_ROUTE):
+        rung = rung_index(ladder, d.group_sizes)
+    return lax.switch(rung, [partial(_routed, r) for r in ladder],
+                      x, weights, d, w_gate, w_up, w_down)
+
+
+def _laddered_fwd(ladder, *args):
+    return _laddered(ladder, *args), args
+
+
+def _laddered_bwd(ladder, res, dy):
+    def branch(rung, dy, x, weights, d, *w):
+        _, pull = jax.vjp(
+            lambda x, weights, *w: _routed(rung, x, weights, d, *w),
+            x, weights, *w)
+        return pull(dy)
+
+    with jax.named_scope(MOE_ROUTE):
+        rung = rung_index(ladder, res[2].group_sizes)
+    dx, dweights, *dw = lax.switch(
+        rung, [partial(branch, r) for r in ladder], dy, *res)
+    return (dx, dweights, None, *dw)
+
+
+_laddered.defvjp(_laddered_fwd, _laddered_bwd)
+
+
+def routed_experts(x, weights, d: Dispatch, w_gate, w_up, w_down,
+                   ladder: Tuple[int, ...]):
+    """y [N, H]: each token's weighted sum over its held experts'
+    SwiGLUs, on the smallest rung of `ladder` that holds the step's
+    rows, chosen on the device (module docstring). `ladder[-1]` is
+    `d`'s buffer, the worst case; with one rung there is no switch and
+    autodiff sees the three calls themselves."""
+    if len(ladder) == 1:
+        y = _routed(ladder[0], x, weights, d, w_gate, w_up, w_down)
+    else:
+        y = _laddered(ladder, x, weights, d, w_gate, w_up, w_down)
+    return checkpoint_name(y, MOE_ROUTED)
+
+
+def held_counters(d: Dispatch, ladder: Tuple[int, ...]) -> dict:
     """What a step's routing did to this chip, as device scalars: the
     assignments held, the held experts' largest load over their mean,
-    rows of the buffer used (of `rows`), dropped assignments."""
+    rows of the worst-case buffer used, the rows of the rung the step
+    ran on, dropped assignments."""
     held = jnp.sum(d.group_sizes)
     mean = jnp.maximum(held, 1).astype(jnp.float32) / d.group_sizes.shape[0]
     return {
         "held_assignments": held,
         "max_load_over_mean": jnp.max(d.group_sizes) / mean,
-        "buffer_rows_used": jnp.minimum(held, rows),
+        "buffer_rows_used": jnp.minimum(held, ladder[-1]),
+        "rung_rows": jnp.asarray(ladder, jnp.int32)[
+            rung_index(ladder, d.group_sizes)],
         "dropped": d.dropped,
     }

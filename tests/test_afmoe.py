@@ -7,6 +7,7 @@ window cuts, a dense block then expert blocks holding a share of a
 wider router, an untied head.
 """
 
+from collections import Counter
 import dataclasses
 import json
 import os
@@ -33,12 +34,16 @@ from kungfu_tpu.ops import flash
 from kungfu_tpu.ops.flash import FLASH_LSE, FLASH_OUT
 from kungfu_tpu.parallel import (afmoe_rules, build_gspmd_train_step,
                                  shard_params)
+from kungfu_tpu.parallel import grouped_moe as gm
+from kungfu_tpu.parallel.grouped_moe import MOE_ROUTED
 from kungfu_tpu.parallel import rules as R
 from kungfu_tpu.trace.scopes import (ATTN_GLOBAL, ATTN_LOCAL, FUSED_CE,
                                      MOE_EXPERTS, MOE_ROUTE)
 
-from test_device_scopes import primitive, scope_paths
-from test_glm_moe import kernel_calls, leaves_with_names, rel_err
+from test_device_scopes import (primitive, scope_paths,
+                                switches_stand_outside)
+from test_glm_moe import (kernel_calls, leaves_with_names, one_rung,
+                          rel_err, routed_conds, sub_jaxprs)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -175,6 +180,39 @@ def test_recomputation_changes_neither_loss_nor_gradients(f32_case):
     for (name, got), (_, exp) in zip(leaves_with_names(grads_r),
                                      leaves_with_names(grads)):
         assert rel_err(got, exp) < 1e-5, name
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "remat"])
+def test_the_ladder_changes_neither_loss_nor_gradients(monkeypatch,
+                                                       f32_case, remat):
+    """The whole model on the ladder of row buffers (4 of 16 experts
+    held: rungs 256 and 512 for 128 tokens, and every layer of this
+    step fits the first) against the worst-case buffer alone, with and
+    without the per-block recomputation."""
+    c, tokens, params = f32_case
+    assert gm.row_ladder(tokens.size, 4, c.held, 16) == (256, 512)
+
+    def run():
+        cfg = dataclasses.replace(c, remat=remat)
+        return jax.jit(jax.value_and_grad(    # a new function a run
+            lambda p: afmoe_fused_loss(AfmoeLM(cfg), p, tokens),
+            has_aux=True))(params)
+
+    (loss, metrics), grads = run()
+    one_rung(monkeypatch)
+    (loss_w, metrics_w), grads_w = run()
+    assert metrics["rung_rows"].tolist() == [256] * 3
+    assert metrics_w["rung_rows"].tolist() == [512] * 3
+    assert (metrics["held_assignments"] <= 256).all()
+    assert (metrics["held_assignments"] == metrics_w["held_assignments"]).all()
+    assert int(metrics["dropped"].sum()) == 0
+    assert float(loss) == float(loss_w)
+    for (name, got), (_, exp) in zip(leaves_with_names(grads),
+                                     leaves_with_names(grads_w)):
+        if "['w_" in name:    # an expert stack's: a sum over the rows
+            assert rel_err(got, exp) <= 1e-6, name
+        else:
+            np.testing.assert_array_equal(got, exp, err_msg=name)
 
 
 # -- (b) the shares add up to the uncut layer ---------------------------------
@@ -454,10 +492,14 @@ def test_layer_plan_counts_what_the_issue_says():
         ("full", "expert"),)
     assert plan["window"] == 2047
     assert plan["visible_pairs"] == {"full": 33558528, "sliding": 14681088}
-    assert plan["kept"] == ("input", FLASH_OUT, FLASH_LSE)
+    assert plan["kept"] == ("input", FLASH_OUT, FLASH_LSE, MOE_ROUTED)
     assert plan["kept_bytes_per_block"] == (
         8192 * 2048 * 2 + 8192 * 32 * 128 * 2 + 8192 * 32 * 4)
-    assert plan["kept_bytes"] == 8 * plan["kept_bytes_per_block"]
+    # the six expert blocks keep their routed output too
+    assert plan["kept_bytes_per_expert_block"] == (
+        plan["kept_bytes_per_block"] + 8192 * 2048 * 2)
+    assert plan["kept_bytes"] == (2 * plan["kept_bytes_per_block"]
+                                  + 6 * plan["kept_bytes_per_expert_block"])
 
 
 # -- (f) recomputation keeps flash's two names through both kinds of call -----
@@ -493,17 +535,38 @@ def test_recomputed_blocks_run_no_flash_forward_twice(flash_params):
         "_dkv_res_kernel"] * 2 + ["_dq_res_kernel"] * 2
 
 
+@pytest.mark.parametrize("kept, switches", [(True, 6), (False, 9)])
+def test_recomputed_blocks_run_no_routed_forward_twice(
+        monkeypatch, flash_params, kept, switches):
+    """Three expert blocks, two rungs each: with `kf.moe_routed` kept a
+    step runs a forward and a backward switch a block (the backward
+    rebuilds the rung it needs) and the recomputed forward none; without
+    the name the fourth norm's backward makes it run the routed path a
+    third time."""
+    if not kept:
+        monkeypatch.setattr(afmoe, "_KEPT", (FLASH_OUT, FLASH_LSE))
+    c, tokens, _ = flash_case()
+    model = AfmoeLM(c)
+    jaxpr = jax.make_jaxpr(jax.grad(    # a new function a case
+        lambda p: afmoe_fused_loss(model, p, tokens)[0]))(flash_params)
+    conds = [e for e in routed_conds(jaxpr.jaxpr) if any(
+        q.primitive.name == "ragged_dot_general"
+        for b in e.params["branches"] for q in sub_jaxprs(b.jaxpr))]
+    assert len(conds) == switches
+
+
 @pytest.mark.parametrize("case, names", [
-    ("flash", ("input", FLASH_OUT, FLASH_LSE)),
-    ("local", ("input",)),
+    ("flash", ("input", FLASH_OUT, FLASH_LSE, MOE_ROUTED)),
+    ("local", ("input", MOE_ROUTED)),
     ("kept", ()),
 ])
 def test_layer_plan_is_what_jax_keeps(monkeypatch, flash_params, case,
                                       names):
     """`layer_plan` against `saved_residuals`: each of the four blocks
     keeps its input and, through the kernels of either kind of call,
-    flash's output and lse, and NOTHING else beyond what the same
-    program keeps with no name asked for."""
+    flash's output and lse, each of the three expert blocks its routed
+    output, and NOTHING else beyond what the same program keeps with no
+    name asked for."""
     kw = {"local": dict(attention="local"), "kept": dict(remat=False)}
     c, tokens, _ = flash_case()
     c = dataclasses.replace(c, **kw.get(case, {}))
@@ -511,7 +574,8 @@ def test_layer_plan_is_what_jax_keeps(monkeypatch, flash_params, case,
     loss = lambda p: afmoe_fused_loss(model, p, tokens)[0]  # noqa: E731
     plan = layer_plan(c, *tokens.shape)
     assert plan["kept"] == names
-    assert plan["kept_bytes"] == 4 * plan["kept_bytes_per_block"]
+    assert plan["kept_bytes"] == (plan["kept_bytes_per_block"]
+                                  + 3 * plan["kept_bytes_per_expert_block"])
     if not names:
         assert plan["kept_bytes"] == 0
         return
@@ -520,20 +584,22 @@ def test_layer_plan_is_what_jax_keeps(monkeypatch, flash_params, case,
         4 if FLASH_LSE in names else 0)
 
     def held(res):
-        return sorted((a.str_short(), a.size * a.dtype.itemsize)
-                      for a, why in res if "from the argument" not in why)
+        return Counter((a.str_short(), a.size * a.dtype.itemsize)
+                       for a, why in res if "from the argument" not in why)
 
     monkeypatch.setattr(afmoe, "_KEPT", ())
     bare = held(saved_residuals(loss, flash_params))
-    extra = held(res)
-    for item in bare:
-        extra.remove(item)
+    extra = held(res) - bare
+    # the bare program keeps each forward switch's index for a routed
+    # forward it runs again; with the routed output kept none runs
+    assert set(bare - held(res)) <= {("int32[1]", 4)}
     state = tokens.size * c.hidden_size * 4
-    named = plan["kept_bytes_per_block"] - state
-    assert sum(size for _, size in extra) == 4 * named
-    inputs = [size for text, size in bare
-              if size == state and "float32[1,512,64]" in text]
-    assert len(inputs) >= 4
+    assert plan["kept_bytes_per_expert_block"] == (
+        plan["kept_bytes_per_block"] + state)
+    assert extra[(f"float32[{tokens.size},{c.hidden_size}]", state)] == 3
+    assert sum(size * n for (_, size), n in extra.items()) == (
+        plan["kept_bytes"] - 4 * state)
+    assert bare[("float32[1,512,64]", state)] >= 4
 
 
 # -- (g) the selection bias rides in tx ---------------------------------------
@@ -632,7 +698,7 @@ def afmoe_paths():
     step = build_gspmd_train_step(
         lambda p, t: afmoe_fused_loss(model, p, t), tx, has_aux=True)
     return scope_paths(step, params, jax.eval_shape(tx.init, params),
-                       tokens)
+                       tokens, calls=("cond",))
 
 
 @pytest.mark.parametrize("scope, module, other", [
@@ -670,6 +736,28 @@ def test_every_flash_kernel_is_under_one_of_the_two_scopes(afmoe_paths):
     # the expert layer keeps its two names
     for scope in (MOE_ROUTE, MOE_EXPERTS):
         assert [p for p in afmoe_paths if scope in re.split(r"[/()]", p)]
+
+
+@pytest.mark.parametrize("scope, forward, backward", [
+    (MOE_ROUTE, {"dot_general", "top_k", "sort", "gather"},
+     {"gather", "dot_general"}),
+    (MOE_EXPERTS, {"ragged_dot_general", "dot_general", "logistic"},
+     {"ragged_dot_general", "dot_general"}),
+])
+def test_expert_scopes_hold_their_layers_round_the_switch(
+        afmoe_paths, scope, forward, backward):
+    """4 of 16 experts held: two rungs, so the routed path runs under a
+    switch, and the two names still hold what their metrics read."""
+    under = [p for p in afmoe_paths if scope in re.split(r"[/()]", p)]
+    fwd = {primitive(p) for p in under if "transpose(" not in p}
+    bwd = {primitive(p) for p in under if "transpose(" in p}
+    assert forward <= fwd, sorted(fwd)
+    assert backward <= bwd, sorted(bwd)
+    assert "cond" not in fwd | bwd
+
+
+def test_switches_carry_neither_expert_scope(afmoe_paths):
+    switches_stand_outside(afmoe_paths)
 
 
 # -- (j) the cell's rehearsal twin through the benchmark's command ------------

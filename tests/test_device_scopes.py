@@ -31,13 +31,14 @@ from kungfu_tpu.trace.scopes import (FUSED_CE, GRAD_SYNC, MLA, MOE_EXPERTS,
 MODEL = re.compile(r"jvp\(|transpose\(")
 
 
-def scope_paths(step, *args):
+def scope_paths(step, *args, calls=()):
     """Every equation of the traced step as "<scopes>/<primitive>":
     the name stack JAX hands XLA as the operation's metadata, which a
     device trace shows as `tf_op`. Nested programs (`jit`, `shard_map`,
     control flow) are walked with their caller's scopes in front; a
     `pallas_call`'s kernel body is one operation on the device and is
-    not entered."""
+    not entered. The primitives in `calls` (a `cond` is one event in a
+    trace, spanning its branch) are listed as well as walked."""
     paths = set()
 
     def walk(jaxpr, prefix):
@@ -52,7 +53,7 @@ def scope_paths(step, *args):
             subs = [sub for sub in subs if hasattr(sub, "eqns")]
             for sub in subs:
                 walk(sub, here)
-            if not subs:  # an operation, not a call
+            if not subs or eqn.primitive.name in calls:
                 paths.add(f"{here}/{eqn.primitive.name}")
 
     walk(jax.make_jaxpr(step)(*args).jaxpr, "")
@@ -265,7 +266,7 @@ def glm_paths():
     step = build_gspmd_train_step(
         lambda p, t: glm_moe_fused_loss(model, p, t), tx, has_aux=True)
     return scope_paths(step, params, jax.eval_shape(tx.init, params),
-                       tokens)
+                       tokens, calls=("cond",))
 
 
 @pytest.mark.parametrize("scope, forward, backward", [
@@ -286,6 +287,28 @@ def test_glm_scopes_hold_their_layers(glm_paths, scope, forward,
     bwd = {primitive(p) for p in under if "transpose(" in p}
     assert forward <= fwd, sorted(fwd)
     assert backward <= bwd, sorted(bwd)
+
+
+def switches_stand_outside(paths):
+    """The expert layers' switches over the row buffer's rungs (a share
+    of the experts held: more than one rung), forward and backward:
+    what a branch runs carries `kf.moe_route` or `kf.moe_experts`,
+    opened INSIDE the branches, and the switch itself neither, so that
+    a reader that adds up a trace's events under a name counts leaves
+    only (`benchmark/trace_reduce.py` counts an event that spans its
+    body with the body: PERF.md section 7 B (l))."""
+    names = {MOE_ROUTE, MOE_EXPERTS}
+    conds = [p for p in paths if primitive(p) == "cond" and "/moe/" in p]
+    assert {"transpose(" in p for p in conds} == {False, True}, conds
+    assert not [p for p in conds if names & set(re.split(r"[/()]", p))]
+    rows = [p for p in paths if "/moe/" in p
+            and primitive(p) in ("gather", "ragged_dot_general")]
+    assert rows and not [p for p in rows
+                         if not names & set(re.split(r"[/()]", p))]
+
+
+def test_glm_switches_carry_neither_expert_scope(glm_paths):
+    switches_stand_outside(glm_paths)
 
 
 def test_glm_flash_kernels_sit_directly_under_the_attention_module(

@@ -30,6 +30,7 @@ from kungfu_tpu.ops import flash
 from kungfu_tpu.ops.flash import FLASH_LSE, FLASH_OUT
 from kungfu_tpu.parallel import (build_gspmd_train_step, glm_moe_rules,
                                  shard_params)
+from kungfu_tpu.parallel import grouped_moe as gm
 from kungfu_tpu.parallel import rules as R
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -223,6 +224,182 @@ def test_rigged_router_drops_nothing(favoured, held_rows):
     assert int(aux["held_assignments"]) == int(held_rows * 40)
     assert (aux["counts"] == counts).all()
     assert int(counts[jnp.array(favoured)].sum()) == 4 * 40
+
+
+# -- (c') the ladder of buffer sizes: same bits, fewer rows --------------------
+
+
+@pytest.mark.parametrize("shapes, want", [
+    # tokens, k, held, router width
+    ((8192, 8, (0, 8), 128), (8192, 16384, 65536)),     # trinity-mini
+    ((8192, 4, (0, 8), 64), (8192, 16384, 32768)),      # glm-4.7-flash
+    ((8192, 4, (0, 64), 64), (32768,)),     # the whole layer: one rung
+    ((8192, 4, (0, 32), 64), (32768,)),     # half of it: still one
+    ((8192, 8, (120, 8), 128), (8192, 16384, 65536)),   # any share
+    ((8192, 8, (0, 32), 128), (32768, 65536)),
+    ((128, 4, (2, 2), 32), (64, 128, 256)),     # `laddered_runs` below
+    ((100, 4, (0, 3), 32), (76, 152, 300)),     # the expected rows round up
+], ids=["trinity-mini", "glm", "whole-layer", "half-layer", "last-share",
+        "quarter-layer", "small", "odd"])
+def test_row_ladder_follows_the_shapes(shapes, want):
+    ladder = gm.row_ladder(*shapes)
+    assert ladder == want
+    assert ladder[-1] == gm.buffer_rows(*shapes[:3])    # the worst case
+
+
+def sub_jaxprs(jaxpr):
+    """Every equation of a jaxpr, nested programs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from sub_jaxprs(sub)
+
+
+def one_rung(monkeypatch):
+    """`ExpertFFN` as it was before the ladder: the worst-case buffer
+    alone, autodiff through the three calls."""
+    monkeypatch.setattr(gm, "row_ladder",
+                        lambda n, k, held, e: (gm.buffer_rows(n, k, held),))
+
+
+LADDERED_TOKENS = 128
+
+
+@pytest.fixture(scope="module")
+def laddered_runs():
+    """An expert layer whose ladder has three rungs (64, 128, 256) and
+    whose router is rigged so that a token's first two features send
+    it to held expert 2 and to held expert 3: the test says how many
+    rows the layer holds. {wrap: (on the ladder, on the worst-case
+    buffer alone)}, each `f(held rows)` -> loss, y, counters and every
+    gradient, through `jax.jit` alone or through the model's per-block
+    `jax.checkpoint` as well. 128 tokens: under 64 rows the CPU's
+    matmuls take another kernel, whose sums run in another order, and
+    a rung's bits are then its own."""
+    c = small(n_routed_experts=32)
+    n = LADDERED_TOKENS
+    assert gm.row_ladder(n, 4, c.held, 32) == (64, 128, 256)
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, n, c.hidden_size))
+    params = ExpertFFN(c).init(jax.random.PRNGKey(6), x)["params"]
+    # two absent experts always chosen; the other two slots go to the
+    # absent 12 and 13 (score 0.5 + 0.2) unless a held expert's score
+    # nears 1
+    router = jnp.zeros_like(params["router"])
+    params["router"] = router.at[0, 2].set(12.0).at[1, 3].set(12.0)
+    params[ROUTER_BIAS] = jnp.zeros((32,)).at[jnp.array([8, 9])].set(
+        2.0).at[jnp.array([12, 13])].set(0.2)
+
+    def tokens(held):
+        """x whose first feature is +1 on `min(held, n)` tokens (held
+        expert 2 takes them) and -1 elsewhere, the second likewise for
+        the rest of `held` (expert 3)."""
+        sign = lambda m: jnp.where(jnp.arange(n) < m, 1.0, -1.0)  # noqa: E731
+        return x.at[0, :, 0].set(sign(min(held, n))).at[0, :, 1].set(
+            sign(held - min(held, n)))
+
+    def run(wrap):
+        def loss(params, x):    # a new one a run: jax caches traces by it
+            y, aux = ExpertFFN(c).apply({"params": params}, x)
+            return jnp.sum(y * y) + aux.pop("bias_loss"), (y, aux)
+
+        f = loss
+        if wrap == "checkpoint":
+            f = jax.checkpoint(
+                loss, policy=jax.checkpoint_policies.save_only_these_names(
+                    *glm_moe._KEPT))
+        f = jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+        f(params, x)    # traced here, whatever `row_ladder` is now
+        return lambda held: f(params, tokens(held))
+
+    wraps = ("jit", "checkpoint")
+    ladder = {wrap: run(wrap) for wrap in wraps}
+    with pytest.MonkeyPatch.context() as patch:
+        one_rung(patch)
+        return {wrap: (ladder[wrap], run(wrap)) for wrap in wraps}
+
+
+@pytest.mark.parametrize("wrap", ["jit", "checkpoint"])
+@pytest.mark.parametrize("held, rung", [
+    (0, 64), (63, 64), (64, 64), (65, 128), (127, 128), (128, 128),
+    (129, 256), (255, 256), (256, 256)])
+def test_every_rung_gives_the_one_buffer_paths_bits(laddered_runs, wrap,
+                                                    held, rung):
+    """Just under, at and just over each rung's edge: the step runs on
+    the smallest rung that holds its rows, drops nothing, and what it
+    returns is the worst-case buffer's to the bit (the expert stacks'
+    gradients to the order of an f32 sum over fewer rows)."""
+    ladder, whole = laddered_runs[wrap]
+    (loss, (y, aux)), (dparams, dx) = ladder(held)
+    (loss0, (y0, aux0)), (dparams0, dx0) = whole(held)
+    assert int(aux["held_assignments"]) == held
+    assert int(aux["rung_rows"]) == rung and int(aux0["rung_rows"]) == 256
+    assert int(aux["dropped"]) == 0 and int(aux0["dropped"]) == 0
+    assert float(loss) == float(loss0)
+    np.testing.assert_array_equal(y, y0)
+    np.testing.assert_array_equal(dx, dx0)
+    for name, got in leaves_with_names(dparams):
+        want = dict(leaves_with_names(dparams0))[name]
+        if "['w_" in name:    # an expert stack's: a sum over the rows
+            assert rel_err(got, want) <= 1e-6, name
+        else:
+            np.testing.assert_array_equal(got, want, err_msg=name)
+    if held:
+        assert float(jnp.abs(dparams["w_down"]).max()) > 0
+
+
+def routed_conds(jaxpr):
+    return [eqn for eqn in sub_jaxprs(jaxpr) if eqn.primitive.name == "cond"]
+
+
+def test_no_rung_sized_value_leaves_a_switch():
+    """The trap ISSUE 35 found: differentiated THROUGH, the forward
+    `cond` returns every rung's residuals, each branch zero-filling the
+    others'. With one `custom_vjp` round the routed path a switch hands
+    out the layer's output or the cotangents of its inputs: arrays as
+    long as the tokens or the weight stacks, never as long as a rung."""
+    c = small(n_routed_experts=32)
+    n, h, f, k = 48, c.hidden_size, c.moe_intermediate_size, 4
+    ladder = gm.row_ladder(n, k, c.held, 32)
+    assert ladder == (24, 48, 96)
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, n, h))
+    params = ExpertFFN(c).init(jax.random.PRNGKey(6), x)["params"]
+
+    def loss(params, x):
+        y, aux = ExpertFFN(c).apply({"params": params}, x)
+        return jnp.sum(y * y) + aux["bias_loss"]
+
+    step = jax.grad(jax.checkpoint(loss), argnums=(0, 1))
+    conds = routed_conds(jax.make_jaxpr(step)(params, x).jaxpr)
+    # forward, the recomputed forward, backward
+    assert len(conds) == 3
+    assert all(len(eqn.params["branches"]) == 3 for eqn in conds)
+    allowed = {(n, h), (n, k), (2, h, f), (2, f, h)}
+    shapes = {v.aval.shape for eqn in conds for v in eqn.outvars}
+    assert shapes <= allowed, shapes - allowed
+    assert {(n, h), (2, h, f)} <= shapes
+    # and inside the branches the rows ARE as long as their rung
+    inside = {v.aval.shape[0] for eqn in conds
+              for branch in eqn.params["branches"]
+              for e in sub_jaxprs(branch.jaxpr)
+              for v in e.outvars if v.aval.shape[1:] == (h,)}
+    assert set(ladder) <= inside
+
+
+def test_a_caller_that_holds_every_expert_has_no_switch():
+    c = small(held=(0, 16))
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 40, c.hidden_size))
+    params = ExpertFFN(c).init(jax.random.PRNGKey(6), x)["params"]
+    assert gm.row_ladder(40, 4, c.held, 16) == (160,)
+
+    def loss(params, x):
+        y, aux = ExpertFFN(c).apply({"params": params}, x)
+        assert aux["rung_rows"].shape == ()
+        return jnp.sum(y * y) + aux["bias_loss"]
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr
+    assert not routed_conds(jaxpr)
+    assert any(e.primitive.name == "ragged_dot_general"
+               for e in sub_jaxprs(jaxpr))
 
 
 # -- (d) latent attention through the flash kernels at d = 256 ----------------
