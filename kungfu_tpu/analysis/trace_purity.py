@@ -25,7 +25,7 @@ or used as a ``shard_map`` body — and flags, anywhere inside:
   named by ``static_argnames``/``static_argnums`` literals are not
   tracers and are exempt too)
 - kftrace recorder calls: ``trace.span`` / ``trace.event`` /
-  ``trace.counter`` / ``trace.complete`` / ``trace.flight_dump`` /
+  ``trace.complete`` / ``trace.flight_dump`` /
   ``trace.set_context`` (any ``trace``/``kftrace`` module prefix).
   A recorder call inside a jitted body runs at TRACE time — it
   records one event at compile, then never again — and the wall
@@ -55,8 +55,8 @@ _CASTS = {"float", "int", "bool"}
 #: kftrace recorder entry points (kungfu_tpu/trace/__init__.py) — any
 #: dotted call whose module segment is trace/kftrace and whose final
 #: segment is one of these fires inside a jit/shard_map body
-_RECORDER_FUNCS = {"span", "event", "counter", "complete",
-                   "flight_dump", "set_context"}
+_RECORDER_FUNCS = {"span", "event", "complete", "flight_dump",
+                   "set_context"}
 _RECORDER_MODULES = {"trace", "kftrace"}
 
 

@@ -59,6 +59,8 @@ def _decompose_dir(trace_dir: str, device_batch: int):
 
 
 def _row(run, decomp) -> dict:
+    from kungfu_tpu.trace.goodput import PHASES
+
     t = decomp["totals"]
     wall = t["wall_ms"] or 1.0
     return {
@@ -69,8 +71,7 @@ def _row(run, decomp) -> dict:
         "restored_step": decomp.get("restored_step"),
         "phases_pct": {
             p: round(100.0 * t[f"{p}_ms"] / wall, 1)
-            for p in ("compute", "wire", "hook", "resize", "recovery",
-                      "checkpoint", "straggler", "lost")
+            for p in PHASES
         },
         "other_pct": round(100.0 * t["other_ms"] / wall, 1),
         "wall_ms": t["wall_ms"],

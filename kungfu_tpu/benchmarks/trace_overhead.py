@@ -9,6 +9,12 @@ Four measurements, least to most integrated:
    ``KF_TRACE`` off and no profiler session (what every untraced run
    pays), µs a call while a `jax.profiler` session runs, ring off and
    ring on (host latencies: a CPU reading is what they are);
+1c. **compile ledger** — µs a listener call of
+   `compile_cache.CacheStats` on a trace event (a GPT step's trace
+   fires some 10^4 of them: every `jnp` function is a jitted function
+   traced inside it) and µs to close a program's record, ring off and
+   ring on (three `compile.*` events more). Paid only while JAX
+   compiles: set-up, never a steady step;
 2. **instrumented step wall** — a jitted train step (GPT-2-small
    scaled config by default; `--model slp` for the elastic harness's
    trainer) run in a loop carrying EXACTLY the per-step
@@ -95,6 +101,39 @@ def _bridge_cost(iters: int = 20000) -> dict:
             "session_span_traced_us": round(both_us, 3)}
 
 
+def _ledger_cost(iters: int = 20000) -> dict:
+    """The compile ledger's listeners, fed the events JAX fires: a
+    jitted function traced inside another (the common call), and a
+    whole program (trace, lower, backend) closed into a record."""
+    from kungfu_tpu import trace
+    from kungfu_tpu.compile_cache import _PHASES, CacheStats
+
+    tr, lo, be = _PHASES  # the three events, in a compile's order
+    trace._reset_for_tests()
+    trace.configure(enabled_=False)
+    stats = CacheStats("nowhere")
+    t0 = time.perf_counter()
+    for i in range(iters):
+        stats._on_span(tr, i + 0.25, i + 0.5, fun_name="add")
+    nested_us = (time.perf_counter() - t0) / iters * 1e6
+
+    def records(n):
+        t0 = time.perf_counter()
+        for i in range(n):
+            stats._on_span(tr, i, i + 0.25, fun_name="f")
+            stats._on_span(lo, i + 0.25, i + 0.5, fun_name="jit(f)")
+            stats._on_span(be, i + 0.5, i + 1.0, fun_name="jit(f)")
+        return (time.perf_counter() - t0) / n * 1e6
+
+    record_us = records(iters // 10)
+    trace.configure(enabled_=True, capacity=4096)
+    traced_us = records(iters // 10)
+    trace._reset_for_tests()
+    return {"ledger_trace_event_us": round(nested_us, 3),
+            "ledger_record_us": round(record_us, 3),
+            "ledger_record_traced_us": round(traced_us, 3)}
+
+
 def _step_wall(model: str, iters: int, warmup: int,
                traced: bool) -> float:
     """Median step wall (ms) of a jitted CPU train step carrying the
@@ -166,7 +205,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
 
-    per_event = {**_per_event_cost(), **_bridge_cost()}
+    per_event = {**_per_event_cost(), **_bridge_cost(), **_ledger_cost()}
     off_ms = _step_wall(args.model, args.iters, args.warmup,
                         traced=False)
     on_ms = _step_wall(args.model, args.iters, args.warmup,
@@ -208,6 +247,10 @@ def main(argv=None) -> int:
               f"profiler session {per_event['session_span_us']} µs, "
               f"with the ring on too "
               f"{per_event['session_span_traced_us']} µs")
+        print(f"compile ledger: a trace event "
+              f"{per_event['ledger_trace_event_us']} µs; a program's "
+              f"record {per_event['ledger_record_us']} µs, with the "
+              f"ring on {per_event['ledger_record_traced_us']} µs")
         print(f"step wall ({args.model}): {off_ms:.3f} ms untraced -> "
               f"{on_ms:.3f} ms traced ({overhead_pct:+.2f}%)")
         print(f"implied flagship fraction: {implied_pct:.4f}% of a "

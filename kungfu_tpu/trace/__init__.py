@@ -2,7 +2,7 @@
 
 The process-facing API of the observability layer
 (docs/observability.md). Instrumentation sites call the module-level
-helpers — `span` / `event` / `counter` / `set_context` — which are
+helpers — `span` / `event` / `complete` / `set_context` — which are
 no-ops until ``KF_TRACE=1`` (the same latch-once switch that enables
 the native scope counters), so the disabled cost on a hot path is one
 module-global check. `span` has a second listener: while a
@@ -41,8 +41,8 @@ from typing import Optional
 from .recorder import (DEFAULT_RING, NOOP_SPAN, TraceRecorder, _Span)
 
 __all__ = [
-    "enabled", "configure", "recorder", "span", "event", "counter",
-    "complete", "set_context", "flight_dump", "install",
+    "enabled", "configure", "recorder", "span", "event", "complete",
+    "set_context", "flight_dump", "install",
     "install_from_peer", "TraceRecorder", "DEFAULT_RING", "NOOP_SPAN",
 ]
 
@@ -137,11 +137,6 @@ def span(name: str, cat: str = "", **args):
 def event(name: str, cat: str = "", **args) -> None:
     if enabled():
         recorder().event(name, cat, **args)
-
-
-def counter(name: str, values, cat: str = "counter") -> None:
-    if enabled():
-        recorder().counter(name, values, cat)
 
 
 def complete(name: str, ts_us: int, dur_us: int, cat: str = "",

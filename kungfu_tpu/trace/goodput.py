@@ -31,6 +31,15 @@ taxonomy (docs/observability.md):
                 ``recovery.restore``, which wraps the restore-side
                 resync; the runner-side detect/propose phases ride
                 the separate MTTR decomposition)
+``compile``     tracing, lowering and compiling (or loading from the
+                persistent cache) the programs a process runs: the
+                compile ledger's ``compile.trace`` / ``.lower`` /
+                ``.backend`` spans (`compile_cache.py`). A jitted
+                call compiles inside its caller's span, so this time
+                is taken OUT of whichever ``compute`` (or ``lost``),
+                ``resize``, ``recovery``, ``wire``, ``hook`` or
+                ``checkpoint`` span it nests in: a process's first
+                ``step.compute`` is mostly this
 ``checkpoint``  checkpoint overhead EXPOSED to the step loop
                 (``ckpt.snapshot``); the async writer's
                 wall (``ckpt.save``) is reported separately as
@@ -85,8 +94,11 @@ _SPAN_PHASE = {
     "chaos.straggler": "straggler",
 }
 
-PHASES = ("compute", "wire", "hook", "resize", "recovery",
+PHASES = ("compute", "wire", "hook", "resize", "recovery", "compile",
           "checkpoint", "straggler", "lost")
+
+#: the compile ledger's spans (`compile_cache.py` alone emits them)
+_COMPILE_SPANS = ("compile.trace", "compile.lower", "compile.backend")
 
 
 def _step_computed(ev: Dict) -> int:
@@ -146,6 +158,16 @@ def decompose(sources: List[Dict], tolerance_pct: float = 5.0,
             recov_windows.setdefault(e["rank"], []).append(
                 (float(e["ts"]), float(e["ts"]) + float(e.get("dur", 0))))
 
+    # compile windows per rank: a jitted call traces, lowers and
+    # compiles INSIDE the span round its call site, so the part of any
+    # attributed span these windows cover is billed to "compile" once
+    # and taken out of that span's own phase
+    compile_windows: Dict[int, List[Tuple[float, float]]] = {}
+    for e in workers:
+        if e.get("name") in _COMPILE_SPANS and e.get("ph") == "X":
+            compile_windows.setdefault(e["rank"], []).append(
+                (float(e["ts"]), float(e["ts"]) + float(e.get("dur", 0))))
+
     # compute attempts grouped per (rank, step-computed), time-ordered
     attempts: Dict[Tuple[int, int], List[Dict]] = {}
     for e in workers:
@@ -166,8 +188,10 @@ def decompose(sources: List[Dict], tolerance_pct: float = 5.0,
 
     for (rank, step), spans in sorted(attempts.items()):
         for n, e in enumerate(spans):
-            dur = float(e.get("dur", 0))
-            end = float(e["ts"]) + dur
+            t0 = float(e["ts"])
+            end = t0 + float(e.get("dur", 0))
+            dur = end - t0 - _overlap_ms(
+                t0, end, compile_windows.get(rank, []))
             discarded = n < len(spans) - 1 or any(
                 end < ts_r and step > gen_step
                 for ts_r, gen_step in restores if gen_step >= 0)
@@ -183,28 +207,30 @@ def decompose(sources: List[Dict], tolerance_pct: float = 5.0,
         if e.get("ph") != "X":
             continue
         name, rank = e.get("name"), e["rank"]
-        dur = float(e.get("dur", 0))
-        t0, t1 = float(e["ts"]), float(e["ts"]) + dur
+        t0 = float(e["ts"])
+        t1 = t0 + float(e.get("dur", 0))
         phase = _SPAN_PHASE.get(name)
-        if name == "step.grad_wire":
-            other = [w for r, ws in strag_windows.items()
-                     if r != rank for w in ws]
-            waited = _overlap_ms(t0, t1, other)
-            acc(rank, "straggler", waited)
-            acc(rank, "wire", dur - waited)
-        elif name == "step.hook":
-            nested = _overlap_ms(t0, t1, strag_windows.get(rank, []))
-            acc(rank, "straggler", nested)
-            acc(rank, "hook", dur - nested)
-        elif name == "resize.resync":
-            nested = _overlap_ms(t0, t1, recov_windows.get(rank, []))
-            acc(rank, "resize", dur - nested)  # nested part: recovery
-        elif name == "chaos.straggler":
-            pass  # billed via the step.hook nesting subtraction above
-        elif name == "ckpt.save":
-            ckpt_async_us += dur  # overlaps training; reported aside
-        elif phase is not None:
-            acc(rank, phase, dur)
+        if name == "ckpt.save":
+            ckpt_async_us += t1 - t0  # overlaps training; reported aside
+        if phase is None or name == "chaos.straggler":
+            continue  # the sleep is billed via step.hook's nesting below
+        # the part of the span that is another phase's
+        away: List[Tuple[float, float]] = []
+        if name == "step.grad_wire":  # waiting on ANOTHER rank's sleep
+            away = [w for r, ws in strag_windows.items()
+                    if r != rank for w in ws]
+        elif name == "step.hook":  # this rank's own scheduled sleep
+            away = strag_windows.get(rank, [])
+        elif name == "resize.resync":  # nested in a restore: recovery's
+            away = recov_windows.get(rank, [])
+        if phase in ("wire", "hook"):
+            acc(rank, "straggler", _overlap_ms(t0, t1, away))
+        acc(rank, phase, (t1 - t0) - _overlap_ms(
+            t0, t1, away + compile_windows.get(rank, [])))
+
+    for rank, windows in compile_windows.items():
+        lo, hi = min(w[0] for w in windows), max(w[1] for w in windows)
+        acc(rank, "compile", _overlap_ms(lo, hi, windows))  # their union
 
     # rank-active wall: per (rank, process-boot) event envelope
     envelopes: Dict[Tuple[int, str], Tuple[float, float]] = {}
@@ -322,7 +348,9 @@ class GoodputMeter:
 
     - ``kf_useful_ms_total`` (counter) — compute milliseconds
     - ``kf_lost_ms_total{phase=...}`` (counter family) — every
-      non-compute millisecond, by taxonomy phase
+      non-compute millisecond, by taxonomy phase (``compile`` among
+      them: the trainer takes the compile ledger's seconds out of the
+      span it timed round a jitted call)
     - ``kf_goodput_ratio`` (gauge) — useful / (useful + lost), the
       live running ratio
 

@@ -44,11 +44,19 @@ class TestCacheDir:
 
         before = jax.config.jax_compilation_cache_dir
         monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
-        stats = compile_cache.enable()
-        assert stats.dir == str(tmp_path)
-        assert jax.config.jax_compilation_cache_dir == before
-        assert stats.as_dict() == {"dir": str(tmp_path), "hits": 0,
-                                   "misses": 0}
+        compile_cache._reset_for_tests()
+        try:
+            stats = compile_cache.enable()
+            assert stats.dir == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+            assert stats.as_dict() == {
+                "dir": str(tmp_path), "hits": 0, "misses": 0,
+                "skipped": 0, "programs": 0, "nested_traces": 0,
+                "trace_s": 0.0,
+                "lower_s": 0.0, "backend_s": 0.0, "load_s": 0.0,
+                "saved_s": 0.0, "by_fun": {}}
+        finally:
+            compile_cache._reset_for_tests()
 
 
 class TestChipSlot:

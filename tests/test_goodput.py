@@ -157,6 +157,45 @@ def test_resync_nested_in_recovery_restore_is_not_double_billed():
     assert d["invariant"]["ok"], d
 
 
+def test_compile_is_its_own_phase_taken_out_of_the_span_round_it():
+    """A jitted call compiles inside the span round its call site
+    (`compile_cache.py` emits the three spans): the first
+    `step.compute` of a process, `fuse`'s programs inside
+    `step.grad_wire`, a resync nested in a restore. Each compile is
+    billed ONCE, to `compile`, and leaves the phase it nests in."""
+    evs = clean_rank(0, steps=2)   # compute [0,100], wire [100,110]...
+    # the step's program inside the first step.compute: 20 + 10 + 50
+    evs.append(X("compile.trace", 5, 20, 0, fun="step", cache="miss"))
+    evs.append(X("compile.lower", 25, 10, 0, fun="step", cache="miss"))
+    evs.append(X("compile.backend", 35, 50, 0, fun="step", cache="miss"))
+    # a one-op program inside the wire span [100, 110]
+    evs.append(X("compile.backend", 102, 6, 0, fun="concatenate",
+                 cache="hit"))
+    # a resync [250, 430] nested in a restore [240, 440] recompiles
+    # for 100 ms: recovery loses it, resize stays 0, compile has it once
+    evs.append(X("recovery.restore", 240, 200, 0))
+    evs.append(X("resize.resync", 250, 180, 0))
+    evs.append(X("compile.backend", 300, 100, 0, fun="step",
+                 cache="miss"))
+    # a program traced inside another's trace: the union, not the sum
+    evs.append(X("compile.trace", 500, 40, 0, fun="outer", cache="off"))
+    evs.append(X("compile.backend", 510, 20, 0, fun="eager", cache="off"))
+    d = decompose([source("r0", evs)])
+    t = d["totals"]
+    assert t["compile_ms"] == 80 + 6 + 100 + 40
+    assert t["compute_ms"] == 200 - 80
+    assert t["wire_ms"] == 20 - 6
+    assert t["recovery_ms"] == 200 - 100 and t["resize_ms"] == 0
+    assert d["invariant"]["ok"] and d["invariant"]["error_pct"] == 0
+    assert "compile" in format_table(d)
+    # without the subtraction the same spans overrun the wall: the
+    # phases above sum to what the spans cover, no more
+    covered = 200 + 20 + 10 + 200 + 40
+    assert sum(t[f"{p}_ms"] for p in (
+        "compute", "wire", "hook", "recovery", "resize",
+        "compile")) == covered
+
+
 def test_double_counting_violates_the_invariant():
     # two overlapping resize spans: attributed exceeds the envelope —
     # the taxonomy must FAIL the run, not flatter it
@@ -206,7 +245,9 @@ def test_goodput_meter_maintains_registry_families():
     m.observe_step(compute_ms=90, wire_ms=10)
     m.observe_step(compute_ms=90, wire_ms=10, hook_ms=5)
     m.observe("resize", 100)
+    m.observe("compile", 0)  # a step that compiled nothing
     m.observe("straggler", 0)  # no-op: zero never creates a cell
+    assert reg.read("kf_lost_ms_total", phase="compile") == 0
     assert reg.read("kf_useful_ms_total") == 180
     assert reg.read("kf_lost_ms_total", phase="wire") == 20
     assert reg.read("kf_lost_ms_total", phase="hook") == 5

@@ -382,9 +382,10 @@ def test_span_is_in_a_running_profiler_session(tmp_path, kf_trace):
             with trace.span("step.hook", cat="step", foo=1) as sp:
                 assert sp.set(bar=2) is sp
                 time.sleep(0.002)
-            # not bridged: the ring's instant events and counters
+            # not bridged: the ring's instant events and retroactive
+            # spans (the compile ledger's)
             trace.event("resize.adopted", cat="elastic")
-            trace.counter("queue", {"depth": 1})
+            trace.complete("compile.backend", 0, 5, cat="compile")
     finally:
         jax.profiler.stop_trace()
     events = _host_events(tmp_path)
@@ -399,7 +400,7 @@ def test_span_is_in_a_running_profiler_session(tmp_path, kf_trace):
     assert hook["dur"] >= 1500  # microseconds; slept 2 ms
     if kf_trace:
         assert hook["args"] == {"step": "7", "version": "3"}
-        (ev,) = [e for e in rec.snapshot() if e["ph"] == "X"]
+        (ev,) = [e for e in rec.snapshot() if e["cat"] == "step"]
         assert ev["name"] == "step.hook" and ev["cat"] == "step"
         assert ev["step"] == 7 and ev["version"] == 3
         assert ev["args"] == {"foo": 1, "bar": 2}
