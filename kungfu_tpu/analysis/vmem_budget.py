@@ -128,7 +128,7 @@ def check_flash(grid: Sequence = FLASH_GRID,
         # the fused backward replaces dq + dkv behind the loops and
         # the streaming grid alike, at its own tiles and under the
         # limit it states
-        fused = bwd["scheme"] == "stream_fused"
+        fused = bwd["scheme"] in ("stream_fused", "resident_fused")
         kernels = [
             (which, plan[which]["scheme"], (bq, bk), flash._kernel_vmem(
                 which, plan[which]["scheme"], bq, bk, d, dtype.itemsize,

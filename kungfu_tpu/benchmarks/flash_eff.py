@@ -9,9 +9,9 @@ VISIBLE-pair FLOP count (`flash_attention_flops` — masked score area
 is overhead, not work), and reports achieved TFLOP/s plus efficiency
 against the chip's bf16 peak where the device kind is known. The
 execution plan (`flash_plan`: per-kernel scheme, block sizes, visited
-vs grid blocks, and under "bwd" which backward ran — the fused
-kernel (window-less calls past the head kernels), the head kernel or a
-dq + dkv pair (windowed calls) — with its tiles and block matmuls a
+vs grid blocks, and under "bwd" which backward ran — a fused
+kernel (`stream_fused` window-less, `resident_fused` windowed), the
+head kernel or a dq + dkv pair — with its tiles and block matmuls a
 step) rides along so a published row names exactly
 which kernel configuration produced it.
 

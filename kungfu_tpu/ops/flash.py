@@ -7,7 +7,7 @@ O(T) per query block (FlashAttention, Dao et al. 2022 — on TPU the
 win is HBM bandwidth, the usual bottleneck, not SRAM reuse).
 
 Three execution schemes per kernel (fwd / dq / dkv; the head scheme
-and the fused backward compute dq, dk and dv in one),
+and the fused backwards compute dq, dk and dv in one),
 selected by a VMEM-budget estimate in the style of
 `ops/fused_ce.py:_pick_blocks` (`flash_plan` shows the decision for a
 shape):
@@ -33,10 +33,11 @@ shape):
   dkv. The resident side is DMA'd once per head instead of once per
   outer block (the streaming grid re-fetches every K/V block nq
   times). The forward of every such call runs here; the dq + dkv pair
-  of loops serves WINDOWED calls only (and window-less ones that
-  `_tiles` gives several blocks under 512 rows), since window-less the
-  one-kernel backward below takes the call (PR 33). A model's sliding
-  layers run all three here (T 8192, d 128, window 2047: 512 x 512).
+  of loops serves only calls that `_tiles` gives several blocks under
+  512 rows and windows at rectangular tiles, since the one-kernel
+  backwards below take the rest (PR 33, PR 37). A model's sliding
+  layers run their forward here (T 8192, d 128, window 2047: 512 x
+  512) and their backward on `_bwd_res_kernel`.
 - **stream** (fallback past the VMEM budget — long T, big D): the
   round-5 grid (B*H, outer, inner) with VMEM-scratch-carried online
   state. Causal masking skips compute via `pl.when`; sliding windows
@@ -48,6 +49,12 @@ kernel (`_bwd_stream_kernel`, "stream_fused" in `flash_plan`) on the
 grid (B*H, nk, nq) wherever a head's f32 dq fits the VMEM limit that
 kernel states (`_bwd_stream_tiles`: T <= 16384 at d = 256 bf16, <=
 32768 at d <= 128); else the dq + dkv pair of the call's scheme.
+Under a window at square tiles it is ONE kernel too (PR 37:
+`_bwd_res_kernel`, "resident_fused"): grid (B*H, nk), the head's Q and
+dO held whole as the resident dkv holds them, a loop over the k-block's
+`_q_span` q-blocks whose step feeds dk, dv and dq as the streaming one
+does, under the same limit (T <= 8192 at d = 256 bf16, <= 16384 at d
+<= 128); else the pair.
 
 All run the same block steps (`_fwd_step`, `_dq_step`, `_dkv_step`).
 Their matmuls take their operands in the input's dtype when that is
@@ -163,9 +170,9 @@ Windows as a model calls them (PR 34; `window` counts the keys BEFORE
 self: position q attends to keys [q - window, q], window + 1 of them.
 A checkpoint whose config says `sliding_window: W` in the HF sense, W
 keys counting self, passes `window = W - 1`). A windowed call never
-takes the head kernels nor the fused backward: forward, dq and dkv run
-on the resident loops where they fit, else on the narrowed streaming
-grid, seven block matmuls a step. Its auto tiles stay SQUARE: at T
+takes the head kernels; until PR 37 its forward, dq and dkv ran on the
+resident loops where they fit, else on the narrowed streaming grid,
+seven block matmuls a step. Its auto tiles stay SQUARE: at T
 8192, d 128, window 2047 the budget shrink used to give 1024 x 512,
 which put dq and dkv past the loops' budget and on the streaming grid,
 where the dkv narrows only at block_q == block_k and so walked all 128
@@ -179,11 +186,36 @@ scale, the full-causal call with the dq + dkv pair forced: 20.79 (dq
 6.80, dkv 8.20) where the fused kernel's whole backward is 9.50: what
 a fused backward for windows could be sized against.
 
+What the chip said of a fused backward for windows (one TPU v5e,
+2026-10-15, PR 37; the same call, 32 query heads on 4, fwd + bwd of
+the isolated call, the forward 3.09 ms on the loops at 512 x 512 in
+every line; each line measured twice, in opposite orders, to 0.005
+ms): the dq + dkv pair 10.87 (backward 7.78). ONE kernel in the
+resident-loop form that landed (grid (B*H, nk), Q/dO held whole, a
+loop over `_q_span`): **512 x 512 8.68** (backward 5.58, the pair's
+0.72; 70 loop trips a head), 1024 x 1024 9.14, 256 x 256 11.62. The
+same step on the narrowed streaming grid, (B*H, nk, span) with the
+q/dO index map naming q-block jk + kk clamped to nq - 1 (no padding
+of Q and dO), steps past nq skipped: 8.85 at 512 x 512 (70 of 80
+steps compute), 9.04 at 1024 x 1024 (21 of 24), 13.00 at 256 x 256;
+so the loops, whose Q and dO cross HBM once a head, took it at every
+tile but 1024. Both forms (each q-block sums its k-blocks in
+ascending order in f32) read the distances below to every digit,
+repeat themselves to the bit, give the pair's dv to the bit and differ
+from its dq and dk in 0.008%
+and 0.004% of elements, by one bf16 rounding of ds (delta's summation
+order): an L2 distance of 1.0e-5 and 1.4e-5 where either stands 1.9e-3
+and 2.5e-3 from the pair on f32 inputs. The window-less calls, timed
+beside the parent's in the same call, did not move (glm 16.607 /
+16.607, `ouro` 2.378 / 2.377, the full layers 15.301 / 15.296, d 64 at
+T 4096 3.400 / 3.397): their kernels are the parent's to the jaxpr.
+
 Auto block sizes are budget-driven: the head kernels' chunk where they
 apply, else the largest power-of-two tile <= 1024 that keeps the worst
 kernel's VMEM estimate under budget (big head dims shrink blocks
 instead of failing to compile). The fused backward takes 1024 x 1024
-where T divides, whatever the forward's tiles, else the call's.
+where T divides, whatever the forward's tiles, else the call's; under
+a window the call's (512 x 512 at the sliding call: the sweep above).
 
 Backward overhead trims (round 6): in the dq + dkv pairs the delta
 precompute (`rowsum(dO * O)`, FlashAttention-2 eq. 4) is folded into
@@ -534,6 +566,27 @@ def _bwd_stream_vmem(bq, bk, d, isz, t):
     return inputs + outputs + scratch + bq * bk * (3 * 4 + 2 * isz)
 
 
+def _bwd_res_vmem(bq, bk, d, isz, t):
+    """`_bwd_res_kernel`'s: Q and dO whole as the resident dkv holds
+    them, dq whole as `_bwd_stream_vmem` counts it, dk and dv a carry."""
+    d = -(-d // 128) * 128
+    inputs = 2 * (2 * bk * d * isz + 2 * t * d * isz + 2 * t * 4)
+    outputs = 2 * (2 * bk * d * isz + t * d * isz)
+    carry = 2 * bk * d * 4
+    return inputs + outputs + carry + t * d * 4 + bq * bk * (3 * 4 + 2 * isz)
+
+
+def _bwd_fused_vmem(window):
+    """The estimate of the one-kernel backward a call past the head
+    kernels runs: `_bwd_stream_kernel` window-less, `_bwd_res_kernel`
+    under a window. Both hold to `_BWD_STREAM_VMEM_LIMIT`."""
+    return _bwd_stream_vmem if window is None else _bwd_res_vmem
+
+
+# the "bwd" schemes of `flash_plan` that run ONE backward kernel
+_ONE_KERNEL_BWD = ("head", "stream_fused", "resident_fused")
+
+
 def _fwd_res_vmem(bq, bk, d, isz, t):
     inputs = 2 * (bq * d * isz + 2 * t * d * isz)
     outputs = 2 * (bq * d * isz + bq * 4)
@@ -631,24 +684,27 @@ def _kernel_vmem(which, scheme, bq, bk, d, isz, t):
 
 
 def _bwd_stream_tiles(t, d, isz, bq, bk, causal, window, auto):
-    """(block_q, block_k) of the fused backward (`_bwd_stream_kernel`)
-    where a call takes it, else None: no window (the pair's narrowed
-    grids differ between dq and dkv), a pair on the loops or on the
-    streaming grid in its place (the head scheme has its own one-kernel
-    backward), and a head's dq inside the limit the kernel states. Its
-    tiles are its own: `_BWD_STREAM_BLOCK` square where the caller left
-    them to `_tiles` and T divides, else the call's. A function of
-    (t, d, dtype, causal, window) and the tiles alone; `flash_plan`
-    shows it under "bwd"."""
+    """(block_q, block_k) of the fused backward where a call takes it,
+    else None: `_bwd_stream_kernel` window-less, `_bwd_res_kernel` under
+    a window (PR 37), wherever a pair on the loops or on the streaming
+    grid would run (the head scheme has its own one-kernel backward), a
+    window's tiles are square (its auto tiles are; a k-block's q-blocks
+    are then `_q_span`'s whole ones) and a head's dq fits the limit the
+    kernels state. Window-less its tiles are its own:
+    `_BWD_STREAM_BLOCK` square where the caller left them to `_tiles`
+    and T divides, else the call's; a window keeps the call's (the
+    sweep in the module docstring). A function of (t, d, dtype, causal,
+    window) and the tiles alone; `flash_plan` shows it under "bwd"."""
     pair = {_choose_scheme(which, t, d, isz, bq, bk, causal, window)
             for which in ("dq", "dkv")}
-    if window is not None or "head" in pair:
+    if "head" in pair or (window is not None and bq != bk):
         return None
     tiles = [(bq, bk)]
-    if auto and t % _BWD_STREAM_BLOCK == 0:
+    if auto and window is None and t % _BWD_STREAM_BLOCK == 0:
         tiles.insert(0, (_BWD_STREAM_BLOCK, _BWD_STREAM_BLOCK))
-    tile = next((tile for tile in tiles if _bwd_stream_vmem(
-        *tile, d, isz, t) <= _BWD_STREAM_VMEM_LIMIT), None)
+    vmem = _bwd_fused_vmem(window)
+    tile = next((tile for tile in tiles if vmem(*tile, d, isz, t)
+                 <= _BWD_STREAM_VMEM_LIMIT), None)
     if tile and auto and "resident" in pair and tile[1] < 512 and t > tile[1]:
         # a T over 1024 that 512 does not divide gets 128- or 256-row
         # blocks from `_tiles`: a grid step of the fused kernel then
@@ -839,6 +895,54 @@ def _dkv_res_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         jnp.zeros((block_k, d), jnp.float32)))
     dk_ref[0] = (dk_acc * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv_acc.astype(dv_ref.dtype)
+
+
+def _bwd_res_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                    dq_ref, dk_ref, dv_ref, dq_acc, *, scale, causal,
+                    block_q, block_k, window=None, nq=None):
+    """Grid (B*H, nk): dq, dk and dv of one head from ONE pass over its
+    visible score blocks — the fused backward of a windowed call (PR
+    37). `_dkv_res_kernel`'s loop (Q/dO held at full length per head,
+    a fori over `_q_span`'s q-blocks, dk/dv its carry) whose step's
+    dsT also gives the q-block's dq, dsT^T @ k, summed into a whole
+    head's f32 accumulator as `_bwd_stream_kernel` sums it: zeroed at
+    the head's first k-block, scaled and written at its last, so the
+    k-block dim is "arbitrary". Five block matmuls and one exp pass a
+    step where the pair runs seven and two; each q-block still sums its
+    k-blocks in ascending order in f32."""
+    jk = pl.program_id(1)
+
+    @pl.when(jk == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    k_blk = k_ref[0]
+    v_blk = v_ref[0]
+    d = k_blk.shape[-1]
+    lo, hi = _q_span(jk, nq, causal=causal, window=window,
+                     block_q=block_q, block_k=block_k)
+
+    def body(iq, carry):
+        dk_acc, dv_acc = carry
+        rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+        dk, dv, ds_t = _dkv_step(
+            q_ref[0, rows, :], k_blk, v_blk, do_ref[0, rows, :],
+            lse_ref[0, iq, 0, :][None, :],            # [1, bq] lanes
+            delta_ref[0, iq, 0, :][None, :],
+            iq, jk, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, window=window)
+        dq_acc[rows, :] += _mxu(ds_t, k_blk, _TN, ds_t.dtype)
+        return dk_acc + dk, dv_acc + dv
+
+    dk, dv = lax.fori_loop(lo, hi, body, (
+        jnp.zeros((block_k, d), jnp.float32),
+        jnp.zeros((block_k, d), jnp.float32)))
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(jk == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -1036,9 +1140,9 @@ def flash_attention(
     (q, k, v, o, lse), dq/dk/dv are computed blockwise with the
     FlashAttention-2 recurrence (p re-materialized per block from the
     saved logsumexp): one kernel for all three in the head scheme and,
-    window-less, behind the loops and the streaming grid alike
-    (`_bwd_stream_tiles`), else a dq + dkv pair with the delta
-    precompute inside the dq kernel. Both
+    behind the loops and the streaming grid alike, window-less or under
+    a window at square tiles (`_bwd_stream_tiles`), else a dq + dkv
+    pair with the delta precompute inside the dq kernel. Both
     directions are O(T) in HBM. Non-tiling shapes fall back to the
     plain VJP.
 
@@ -1049,7 +1153,8 @@ def flash_attention(
     a model with `sliding_window: 2048` passes `window=2047`
     (`models/afmoe.py`; `tests/test_afmoe.py` pins the edge). A
     windowed call's auto tiles are square (512 x 512 at d = 128 and
-    256, T 8192), its backward the dq + dkv pair: module docstring.
+    256, T 8192), its backward ONE kernel (`_bwd_res_kernel`) where it
+    fits: module docstring.
     Out-of-window blocks stream no DMA and spend no FLOPs — O(T *
     window) compute AND data movement — via the resident loop bounds
     (`_k_span`/`_q_span`), or, on the streaming fallback, via the
@@ -1523,6 +1628,36 @@ def _stream_bwd(qb, kb, vb, dob, lse, delta, *, scale, causal, block_q,
       delta.reshape(bh, nq, 1, block_q))
 
 
+def _res_bwd(qb, kb, vb, dob, lse, delta, *, scale, causal, block_q,
+             block_k, window, interpret):
+    """`_stream_bwd`'s contract under a window, by `_bwd_res_kernel`:
+    one K/V block and the head's whole Q, dO, lse and delta rows a
+    program."""
+    bh, t, d = qb.shape
+    nq, nk = t // block_q, t // block_k
+    kv_of = _kv_row(bh // kb.shape[0])
+    kv_in = pl.BlockSpec((1, block_k, d), lambda i, j: (kv_of(i), j, 0))
+    kv = pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0))
+    full = pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0))
+    rows = pl.BlockSpec((1, nq, 1, block_q), lambda i, j: (i, 0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_res_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, window=window,
+                          nq=nq),
+        grid=(bh, nk),
+        in_specs=[kv_in, kv_in, full, full, rows, rows],
+        out_specs=[full, kv, kv],
+        out_shape=[jax.ShapeDtypeStruct(qb.shape, x.dtype)
+                   for x in (qb, kb, vb)],
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)],   # dq
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_STREAM_VMEM_LIMIT),
+        interpret=interpret,
+    )(kb, vb, qb, dob, lse.reshape(bh, nq, 1, block_q),
+      delta.reshape(bh, nq, 1, block_q))
+
+
 def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                     interpret, window=None):
     b, t, h, d = q.shape
@@ -1548,9 +1683,12 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         # the glm cell's call (O's blocks ride the pipeline)
         delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1).transpose(0, 2, 1).reshape(b * h, t)
-        dq, dk, dv = _stream_bwd(
-            qb, kb, vb, dob, lse, delta, scale=scale, causal=causal,
-            block_q=fused[0], block_k=fused[1], interpret=interpret)
+        kw = dict(scale=scale, causal=causal, block_q=fused[0],
+                  block_k=fused[1], interpret=interpret)
+        dq, dk, dv = (_stream_bwd(qb, kb, vb, dob, lse, delta, **kw)
+                      if window is None else
+                      _res_bwd(qb, kb, vb, dob, lse, delta, window=window,
+                               **kw))
         return (_unbh(dq, b, h), _unbh_kv(dk, b, h_kv),
                 _unbh_kv(dv, b, h_kv))
     ob = _bh(o)
@@ -1792,15 +1930,16 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
     for comparison.
 
     "bwd" says what the backward as a whole is: `scheme`
-    "stream_fused" where `_bwd_stream_tiles` takes the call (one
-    kernel, ITS tiles, `block_matmuls` 5 a block step), else the dq +
-    dkv pair's (7: each rebuilds s and dp; the head scheme's one kernel
-    5). Its `visited_blocks` are the (q-block, k-block) pairs whose
-    block step RUNS — for the streaming grids fewer than the grid
-    steps "dq" and "dkv" count, since `pl.when` skips the rest — and
-    `vmem_bytes` the largest estimate among its kernels. A windowed
-    call reads the same way: its backward is the dq + dkv pair, and
-    "dq" / "dkv" say which of the pair narrowed its grid or its loops.
+    "stream_fused" (window-less) or "resident_fused" (a window: PR 37)
+    where `_bwd_stream_tiles` takes the call (one kernel, ITS tiles,
+    `block_matmuls` 5 a block step), else the dq + dkv pair's (7: each
+    rebuilds s and dp; the head scheme's one kernel 5). Its
+    `visited_blocks` are the (q-block, k-block) pairs whose block step
+    RUNS — for the streaming grids fewer than the grid steps "dq" and
+    "dkv" count, since `pl.when` skips the rest; for "resident_fused"
+    its loops' trips — and `vmem_bytes` the largest estimate among its
+    kernels. "dq" / "dkv" say what the pair would run: which of the two
+    narrowed its grid or its loops.
 
     `q_per_kv` > 1 (grouped K/V heads: that many query heads read one
     K/V head) changes no tile, scheme or count, all of them a query
@@ -1847,7 +1986,8 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
     if fused is not None:
         bq, bk = fused
         nq, nk = t // bq, t // bk
-        scheme, vmem = "stream_fused", _bwd_stream_vmem(bq, bk, d, isz, t)
+        scheme = "stream_fused" if window is None else "resident_fused"
+        vmem = _bwd_fused_vmem(window)(bq, bk, d, isz, t)
     else:
         scheme = pair[0] if pair[0] == pair[1] else "+".join(pair)
         vmem = max(_kernel_vmem(which, plan[which]["scheme"], bq, bk, d,
@@ -1861,7 +2001,7 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
         "masked_blocks": (plan["dq"]["masked_blocks"] if scheme == "head"
                           else visited if causal else 0),
         "grid_blocks": nq * nk,
-        "block_matmuls": 5 if scheme in ("head", "stream_fused") else 7,
+        "block_matmuls": 5 if scheme in _ONE_KERNEL_BWD else 7,
         "vmem_bytes": vmem}
     if q_per_kv > 1:
         plan["kv_group"] = {

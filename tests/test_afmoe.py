@@ -275,11 +275,12 @@ def _plain_on_repeated(q, k, v, window):
         (512, 8, 2, None, (128, 128), "resident", "resident",
          "stream_fused"),
         (512, 4, 1, None, (256, 128), "stream", "stream", "stream_fused"),
-        (512, 4, 2, 100, (128, 128), "resident", "resident", "resident"),
-        (512, 4, 2, 100, (128, 128), "stream", "stream", "stream"),
+        (512, 4, 2, 100, (128, 128), "resident", "resident",
+         "resident_fused"),
+        (512, 4, 2, 100, (128, 128), "stream", "stream", "resident_fused"),
         (512, 4, 2, 100, (256, 128), "stream", "stream", "stream"),
         (300, 4, 2, None, None, None, "resident", "stream_fused"),
-        (100, 4, 2, 37, None, None, "resident", "resident"),
+        (100, 4, 2, 37, None, None, "resident", "resident_fused"),
     ], ids=["head", "resident+fused", "stream+fused", "window-resident",
             "window-stream-narrowed", "window-stream-m2", "one-block",
             "one-block-window"])
@@ -418,49 +419,21 @@ def test_full_layers_carry_no_positions_and_sliding_ones_relative_ones():
 
 # -- (e) the plans ------------------------------------------------------------
 
-_BWD_FUSED = {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
-              "block_matmuls": 5}
-
-
 def _per_kernel(scheme, visited, grid):
     return {"scheme": scheme, "visited_blocks": visited,
             "masked_blocks": visited, "grid_blocks": grid}
 
 
-@pytest.mark.parametrize("t, d, want", [
-    (4096, 128, {      # ouro-2.6b.train-b1-t4096
-        "block_q": 1024, "block_k": 512, "nq": 4, "nk": 8,
-        "operand_dtype": "bfloat16",
-        "fwd": _per_kernel("resident", 20, 32),
-        "dq": _per_kernel("resident", 20, 32),
-        "dkv": _per_kernel("resident", 20, 32),
-        "bwd": {**_BWD_FUSED, "visited_blocks": 10, "masked_blocks": 10,
-                "grid_blocks": 16, "vmem_bytes": 25231360}}),
-    (8192, 128, {      # this model's full layers, as the parent plans it
-        "block_q": 1024, "block_k": 512, "nq": 8, "nk": 16,
-        "operand_dtype": "bfloat16",
-        "fwd": _per_kernel("resident", 72, 128),
-        "dq": _per_kernel("stream", 128, 128),
-        "dkv": _per_kernel("stream", 128, 128),
-        "bwd": {**_BWD_FUSED, "visited_blocks": 36, "masked_blocks": 36,
-                "grid_blocks": 64, "vmem_bytes": 29491200}}),
-], ids=["ouro-cell", "full-layers"])
-def test_window_less_plans_are_the_parents_key_for_key(t, d, want):
-    """Literals copied from the parent commit's `flash_plan` (the GPT
-    and glm cells' are in test_flash_skip.py): grouped heads and the
-    windowed calls' tiles moved no window-less, ungrouped plan, and an
-    ungrouped plan carries no `kv_group` key."""
-    assert flash.flash_plan(t, d, dtype=jnp.bfloat16, causal=True) == want
-
-
 def test_the_cells_two_calls_plan_as_perf_md_says():
     """T 8192, d 128, bf16, 32 query heads on 4: the full layers as any
-    window-less call (one fused backward kernel), the sliding layers
-    (window 2047) on SQUARE 512 x 512 tiles with all three kernels on
-    the resident loops, 70 of 256 blocks each — where the parent's 1024
-    x 512 put dq and dkv on the streaming grid and the dkv, which
-    narrows only at square tiles, walked all 128 steps a head (PERF.md
-    section 6, PR 34)."""
+    window-less call (one fused backward kernel; their plan, key for
+    key, is among test_flash_skip.py's cells that must not move), the
+    sliding layers (window 2047) on SQUARE 512 x 512 tiles with the
+    forward on the resident loops, 70 of 256 blocks, and ONE fused
+    backward over the same 70 (PR 37) — where PR 34's parent's 1024 x
+    512 put dq and dkv on the streaming grid and the dkv, which narrows
+    only at square tiles, walked all 128 steps a head (PERF.md section
+    6, PR 34)."""
     kw = dict(dtype=jnp.bfloat16, causal=True, q_per_kv=8)
     full = flash.flash_plan(8192, 128, **kw)
     local = flash.flash_plan(8192, 128, window=2047, **kw)
@@ -472,9 +445,9 @@ def test_the_cells_two_calls_plan_as_perf_md_says():
     for which in ("fwd", "dq", "dkv"):
         assert local[which] == _per_kernel("resident", 70, 256)
     assert local["bwd"] == {
-        "scheme": "resident", "block_q": 512, "block_k": 512,
+        "scheme": "resident_fused", "block_q": 512, "block_k": 512,
         "visited_blocks": 70, "masked_blocks": 70, "grid_blocks": 256,
-        "block_matmuls": 7, "vmem_bytes": 13238272}
+        "block_matmuls": 5, "vmem_bytes": 22675456}
     # a windowed call stays square at the other head sizes too
     for d in (64, 256):
         p = flash.flash_plan(8192, d, dtype=jnp.bfloat16, causal=True,

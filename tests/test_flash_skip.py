@@ -301,12 +301,14 @@ def _count(jaxpr, name):
 
 def test_resident_grad_runs_three_2d_kernels(monkeypatch):
     """Structural: a fwd+bwd trace of a WINDOWED call under the
-    resident scheme (a window keeps the dq + dkv pair) contains exactly
-    three pallas_calls (fwd, dq, dkv) — no standalone delta pass — each
-    on a 2-D (B*H, blocks) grid, i.e. the block loop with its dynamic
-    trip count lives INSIDE the kernel. The dq call emits two outputs
-    (dq + the folded delta row set for dkv)."""
+    resident scheme with the dq + dkv pair (what rectangular tiles
+    under a window run) contains exactly three pallas_calls (fwd, dq,
+    dkv) — no standalone delta pass — each on a 2-D (B*H, blocks) grid,
+    i.e. the block loop with its dynamic trip count lives INSIDE the
+    kernel. The dq call emits two outputs (dq + the folded delta row
+    set for dkv)."""
     monkeypatch.setattr(F, "_FORCE_SCHEME", "resident")
+    _pair_only(monkeypatch)
     q, k, v = qkv(t=512)
 
     def loss(q, k, v):
@@ -342,9 +344,10 @@ def test_resident_windowless_grad_is_a_loop_and_one_backward(monkeypatch):
 
 def test_stream_grad_also_folds_delta(monkeypatch):
     """The streaming dq + dkv pair (what a windowed call past the
-    budget runs) folds delta into the dq kernel's kk==0 prologue too:
-    still exactly three pallas_calls, 3-D grids."""
+    budget runs at rectangular tiles) folds delta into the dq kernel's
+    kk==0 prologue too: still exactly three pallas_calls, 3-D grids."""
     monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    _pair_only(monkeypatch)
     q, k, v = qkv(t=512)
 
     def loss(q, k, v):
@@ -494,6 +497,96 @@ def test_fused_backward_takes_its_own_tiles(monkeypatch):
                             2e-4)
 
 
+# -- the fused backward under a window (PR 37) --------------------------------
+
+
+@pytest.mark.parametrize("t,h,h_kv,window,block", [
+    (512, 2, 2, 100, 64),     # a window that is no multiple of the block
+    (512, 2, 2, 511, 128),    # window + 1 == T: every key visible
+    (512, 2, 2, 700, 128),    # window + 1 > T
+    (512, 2, 2, 20, 128),     # a window under one block
+    (512, 8, 1, 150, 64),     # grouped K/V: 8 query heads a K/V head
+    (512, 2, 2, 300, 128),    # the last k-blocks' q-spans run past nq
+], ids=["window-off-block", "window-is-T", "window-past-T", "under-a-block",
+        "grouped-8", "span-past-nq"])
+def test_windowed_fused_backward_matches_plain_and_the_pair(
+        monkeypatch, t, h, h_kv, window, block):
+    """dq, dk, dv of `_bwd_res_kernel` against masked plain attention's
+    (keys [q - window, q]; the group summed in f32) and against the dq +
+    dkv pair it replaces, on the same f32 inputs: each q-block still
+    sums its k-blocks in ascending order in f32, so to 1e-5."""
+    kw = dict(causal=True, window=window, block_q=block, block_k=block)
+    plan = F.flash_plan(t, 32, q_per_kv=h // h_kv, **kw)
+    assert plan["bwd"]["scheme"] == "resident_fused"
+    assert plan["bwd"]["block_matmuls"] == 5
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, g = (jax.random.normal(k, (1, t, h, 32)) for k in ks[:2])
+    k, v = (jax.random.normal(x, (1, t, h_kv, 32)) for x in ks[2:])
+    with jax.default_matmul_precision("highest"):
+        fused = _grads(q, k, v, g, **kw)
+        _, ref_vjp = jax.vjp(lambda q, k, v: _plain_attention(
+            q, k, v, True, 32 ** -0.5, window=window), q, k, v)
+        _assert_grads_close(fused, ref_vjp(g), 2e-4)
+        _pair_only(monkeypatch)
+        assert F.flash_plan(t, 32, **kw)["bwd"]["scheme"] == "resident"
+        for name, a, b in zip("dq dk dv".split(), fused,
+                              _grads(q, k, v, g, **kw)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=0, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("t,d,window,visited", [
+    (8192, 128, 2047, 70),    # the trinity-mini cell's sliding layers
+    (16384, 128, 512, 63),    # past the loops' budget: the pair streamed
+])
+def test_windowed_fused_plan_counts_the_steps_the_kernel_computes(
+        t, d, window, visited):
+    """At the cell's sliding call: "bwd" is ONE kernel at 512 x 512 whose
+    loops run `_q_span`'s q-blocks of every k-block, which are exactly
+    the blocks `_diag_ok(..., window)` passes and the brute-forced
+    visible ones (70 of 256); `grid_blocks` is the unskipped nq x nk as
+    for the resident pair, and its estimate is under the kernel's
+    stated limit."""
+    bwd = F.flash_plan(t, d, dtype=jnp.bfloat16, causal=True,
+                       window=window, q_per_kv=8)["bwd"]
+    assert bwd["scheme"] == "resident_fused" and bwd["block_matmuls"] == 5
+    bq, bk = bwd["block_q"], bwd["block_k"]
+    assert bq == bk == 512
+    nq, nk = t // bq, t // bk
+    ok = np.array([[bool(F._diag_ok(iq, jk, True, bq, bk, window))
+                    for jk in range(nk)] for iq in range(nq)])
+    assert (ok == _visible_block_mask(t, bq, bk, window)).all()
+    trips = sum(int(hi) - int(lo) for lo, hi in (
+        F._q_span(jk, nq, causal=True, window=window, block_q=bq,
+                  block_k=bk) for jk in range(nk)))
+    assert bwd["visited_blocks"] == bwd["masked_blocks"] == ok.sum() \
+        == trips == visited
+    assert bwd["grid_blocks"] == nq * nk
+    assert bwd["vmem_bytes"] == F._bwd_res_vmem(bq, bk, d, 2, t) \
+        <= F._BWD_STREAM_VMEM_LIMIT
+
+
+@pytest.mark.parametrize("scheme", ["resident", "stream"])
+def test_windowed_grad_is_a_loop_and_one_backward(monkeypatch, scheme):
+    """Forward + ONE backward kernel on grid (B*H, nk): three outputs
+    (dq, dk, dv), one loop over q-blocks inside, five dot_generals —
+    whichever of the loops or the streaming grid the forward and the
+    pair would run on."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", scheme)
+    q, k, v = qkv(t=512)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None, 128, 128, None,
+                               200).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    _, bwd = _pallas_eqns(jaxpr.jaxpr)
+    assert bwd.params["grid_mapping"].grid == (2, 4)
+    assert len(bwd.outvars) == 3
+    assert _count(bwd.params["jaxpr"], "while") == 1
+    assert _count(bwd.params["jaxpr"], "dot_general") == 5
+
+
 # -- the streaming kernels under the TPU-faithful interpreter (PR 31) ---------
 #
 # `interpret=True` runs a kernel's body as plain JAX on blocks sliced
@@ -565,6 +658,35 @@ def test_stream_kernels_under_the_tpu_interpreter(monkeypatch, backward,
                                       err_msg=name)
 
 
+@pytest.mark.parametrize("mode", list(_TPU_INTERPRETERS))
+@pytest.mark.parametrize("scheme", ["resident", "stream"])
+def test_windowed_fused_backward_under_the_tpu_interpreter(monkeypatch,
+                                                           scheme, mode):
+    """`_bwd_res_kernel` (PR 37) where uninitialised VMEM reads as NaN
+    and DMAs are modelled: every value finite, bit-equal to the plain
+    interpreter's, no race (B*H 2, T 512, d 128, 128 x 128, window 200:
+    two or three q-blocks a k-block, the last ones cut at nq)."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", scheme)
+    plan = F.flash_plan(512, 128, causal=True, window=200, block_q=128,
+                        block_k=128)
+    assert plan["bwd"]["scheme"] == "resident_fused"
+    q, k, v = qkv(t=512, d=128)
+    g = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+
+    def run(interpret):
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, True, None, 128, 128,
+                                            interpret, 200), q, k, v)
+        return (out, *vjp(g))
+
+    got, raced = _under_tpu_interpreter(mode, run)
+    assert not raced
+    for name, a, b in zip("out dq dk dv".split(), got, run(True)):
+        assert bool(jnp.isfinite(a).all()), name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+
+
 def test_tpu_interpreter_sees_an_unzeroed_accumulator():
     """The instrument's control: the fused kernel's shape of fault — a
     VMEM accumulator summed into over grid steps and never zeroed —
@@ -596,7 +718,7 @@ def test_tpu_interpreter_sees_an_unzeroed_accumulator():
     (dict(t=8192, d=256, dtype=jnp.bfloat16), "stream_fused",
      "non-causal: every step computes"),
     (dict(t=16384, d=128, dtype=jnp.bfloat16, causal=True, window=512),
-     "stream", "a window: the pair's narrowed grids differ"),
+     "resident_fused", "a window past the loops' budget: ONE kernel (PR 37)"),
     (dict(t=32768, d=256, dtype=jnp.bfloat16, causal=True), "stream",
      "a head's f32 dq (32 MB) and its output block pass the limit"),
     (dict(t=65536, d=64, dtype=jnp.bfloat16, causal=True), "stream",
@@ -606,7 +728,14 @@ def test_tpu_interpreter_sees_an_unzeroed_accumulator():
     (dict(t=4096, d=128, dtype=jnp.bfloat16, causal=True), "stream_fused",
      "the ouro-2.6b cell's call: the forward on the loops, ONE backward"),
     (dict(t=4096, d=128, dtype=jnp.bfloat16, causal=True, window=512),
-     "resident", "a window keeps the resident dq + dkv loops"),
+     "resident_fused", "a window inside the loops' budget: ONE kernel"),
+    (dict(t=8192, d=128, dtype=jnp.bfloat16, causal=True, window=2047),
+     "resident_fused", "the trinity-mini cell's sliding layers"),
+    (dict(t=4096, d=128, dtype=jnp.bfloat16, causal=True, window=512,
+          block_q=512, block_k=256), "resident",
+     "rectangular tiles under a window keep the pair"),
+    (dict(t=4096, d=64, dtype=jnp.bfloat16, causal=True, window=255),
+     "resident", "a window under 512: 256-row tiles keep the loops"),
     (dict(t=2048, d=128, dtype=jnp.bfloat16), "stream_fused",
      "non-causal inside the budget: what Ulysses heads send"),
     (dict(t=4096, d=64, dtype=jnp.bfloat16, causal=True), "stream_fused",
@@ -627,9 +756,9 @@ def test_plan_says_which_backward_a_shape_takes(kw, scheme, why):
     alone, and `flash_plan` shows it."""
     bwd = F.flash_plan(kw.pop("t"), kw.pop("d"), **kw)["bwd"]
     assert bwd["scheme"] == scheme, why
-    assert bwd["block_matmuls"] == (5 if scheme in ("stream_fused", "head")
+    assert bwd["block_matmuls"] == (5 if scheme in F._ONE_KERNEL_BWD
                                     else 7)
-    if scheme == "stream_fused":
+    if scheme in ("stream_fused", "resident_fused"):
         assert bwd["vmem_bytes"] <= F._BWD_STREAM_VMEM_LIMIT
     else:
         assert bwd["vmem_bytes"] <= F._VMEM_BUDGET
@@ -641,35 +770,82 @@ _STREAM_8192 = {"scheme": "stream", "visited_blocks": 128,
                 "masked_blocks": 128, "grid_blocks": 128}
 
 
-@pytest.mark.parametrize("t,d,want", [
-    (1024, 64, {   # both GPT cells' call
+_RESIDENT_4096 = {"scheme": "resident", "visited_blocks": 20,
+                  "masked_blocks": 20, "grid_blocks": 32}
+_STREAM_4096 = {"scheme": "stream", "visited_blocks": 16,
+                "masked_blocks": 16, "grid_blocks": 16}
+_TRINITY_FULL = {
+    "block_q": 1024, "block_k": 512, "nq": 8, "nk": 16,
+    "operand_dtype": "bfloat16",
+    "fwd": {"scheme": "resident", "visited_blocks": 72,
+            "masked_blocks": 72, "grid_blocks": 128},
+    "dq": _STREAM_8192, "dkv": _STREAM_8192,
+    "bwd": {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
+            "visited_blocks": 36, "masked_blocks": 36, "grid_blocks": 64,
+            "block_matmuls": 5, "vmem_bytes": 29491200}}
+
+
+@pytest.mark.parametrize("t,d,q_per_kv,want", [
+    (1024, 64, 1, {   # both GPT cells' call
         "block_q": 256, "block_k": 256, "nq": 4, "nk": 4,
         "operand_dtype": "bfloat16", "fwd": _HEAD_1024, "dq": _HEAD_1024,
         "dkv": _HEAD_1024,
         "bwd": {"scheme": "head", "block_q": 256, "block_k": 256,
                 "visited_blocks": 10, "masked_blocks": 4, "grid_blocks": 16,
                 "block_matmuls": 5, "vmem_bytes": 5517312}}),
-    (8192, 256, {   # the glm-4.7-flash cell's call
+    (8192, 256, 1, {   # the glm-4.7-flash cell's call
         "block_q": 1024, "block_k": 512, "nq": 8, "nk": 16,
         "operand_dtype": "bfloat16", "fwd": _STREAM_8192,
         "dq": _STREAM_8192, "dkv": _STREAM_8192,
         "bwd": {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
                 "visited_blocks": 36, "masked_blocks": 36, "grid_blocks": 64,
                 "block_matmuls": 5, "vmem_bytes": 42074112}}),
-], ids=["gpt-cells", "glm-cell"])
-def test_plans_of_the_cells_that_must_not_move(t, d, want):
-    """`flash_plan` at the shapes of the cells PR 33 leaves alone, key
-    for key against a literal copied from its parent commit: widening
-    the fused backward's engage rule moved neither."""
-    assert F.flash_plan(t, d, dtype=jnp.bfloat16, causal=True) == want
+    (4096, 128, 1, {   # the ouro-2.6b cell's call (PR 37's parent)
+        "block_q": 1024, "block_k": 512, "nq": 4, "nk": 8,
+        "operand_dtype": "bfloat16", "fwd": _RESIDENT_4096,
+        "dq": _RESIDENT_4096, "dkv": _RESIDENT_4096,
+        "bwd": {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
+                "visited_blocks": 10, "masked_blocks": 10, "grid_blocks": 16,
+                "block_matmuls": 5, "vmem_bytes": 25231360}}),
+    (4096, 64, 1, {   # the gpt2-small.train-b2-t4096 cell's call
+        "block_q": 1024, "block_k": 1024, "nq": 4, "nk": 4,
+        "operand_dtype": "bfloat16",
+        "fwd": {"scheme": "resident", "visited_blocks": 10,
+                "masked_blocks": 10, "grid_blocks": 16},
+        "dq": _STREAM_4096, "dkv": _STREAM_4096,
+        "bwd": {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
+                "visited_blocks": 10, "masked_blocks": 10, "grid_blocks": 16,
+                "block_matmuls": 5, "vmem_bytes": 25231360}}),
+    (8192, 128, 1, _TRINITY_FULL),   # ... ungrouped (PR 34's parent)
+    (8192, 128, 8, {   # trinity-mini's two full layers
+        **_TRINITY_FULL,
+        "kv_group": {"q_per_kv": 8, "kv_read": "index_map",
+                     "dkv_sum": "xla_f32", "dkv_partial_bytes": 33554432}}),
+], ids=["gpt-cells", "glm-cell", "ouro-cell", "gpt2-small-t4096-cell",
+        "full-layers-ungrouped", "trinity-full-layers"])
+def test_plans_of_the_cells_that_must_not_move(t, d, q_per_kv, want):
+    """`flash_plan` at the window-less shapes of the cells, key for key
+    against a literal copied from a parent commit (PR 33's for the GPT
+    and glm cells, PR 34's for the ungrouped full layers, PR 37's for
+    the rest): grouped heads, the windowed calls' tiles and the fused
+    backward's engage rule, widened to windows by PR 37, moved none of
+    them; an ungrouped plan carries no `kv_group` key."""
+    assert F.flash_plan(t, d, dtype=jnp.bfloat16, causal=True,
+                        q_per_kv=q_per_kv) == want
 
 
-def test_forced_stream_windowed_call_takes_the_pair(monkeypatch):
+@pytest.mark.parametrize("blocks,scheme,matmuls", [
+    ((256, 256), "resident_fused", 5), ((256, 128), "stream", 7)],
+    ids=["square", "rect"])
+def test_windowed_call_keeps_the_pair_at_rect_tiles(monkeypatch, blocks,
+                                                   scheme, matmuls):
+    """Under a window the fused backward needs square tiles (PR 37):
+    rectangular ones keep the pair of the call's scheme."""
     monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
-    plan = F.flash_plan(2048, 64, causal=True, window=256, block_q=256,
-                        block_k=256)
-    assert plan["bwd"]["scheme"] == "stream"
-    assert plan["bwd"]["block_matmuls"] == 7
+    plan = F.flash_plan(2048, 64, causal=True, window=256,
+                        block_q=blocks[0], block_k=blocks[1])
+    assert plan["bwd"]["scheme"] == scheme
+    assert plan["bwd"]["block_matmuls"] == matmuls
 
 
 @pytest.mark.parametrize("t,d,blocks,visited,grid", [
