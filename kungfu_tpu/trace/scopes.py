@@ -33,7 +33,8 @@ FUSED_CE = "kf.fused_ce"
 MLA = "kf.mla"
 
 #: an expert layer's routing: router matmul, sigmoid, top-k, the sort
-#: into the row buffer, the dispatch and combine gathers.
+#: into the row buffer, dispatch's gather and combine's sums of each
+#: token's held rows.
 MOE_ROUTE = "kf.moe_route"
 
 #: an expert layer's matmuls: the grouped ones over the held experts'

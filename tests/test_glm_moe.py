@@ -402,6 +402,173 @@ def test_a_caller_that_holds_every_expert_has_no_switch():
                for e in sub_jaxprs(jaxpr))
 
 
+# -- (c'') what routing reads: no gather, no scatter, no [N, k, H] ------------
+
+
+def pick(rows, pos, valid):
+    """[N, k, H]: each assignment's row, zero where it has none: how
+    combine and dispatch's backward once read the rows, and the plain
+    reference their sums are held to here."""
+    picked = rows[jnp.where(valid, pos, 0)]
+    return jnp.where(valid[..., None], picked, jnp.zeros((), rows.dtype))
+
+
+def scatter_pos(idx, held):
+    """The sort's inverse permutation by scatter, as `plan_dispatch`
+    once computed it."""
+    first, count = held
+    n, k = idx.shape
+    local = idx - first
+    key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    return jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32)).reshape(n, k)
+
+
+def gathered_route(x, router, bias, k, scaling):
+    """`route_sigmoid_topk`'s weights through `take_along_axis`, as it
+    once computed them."""
+    scores = jax.nn.sigmoid(jnp.dot(x, router,
+                                    precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+    chosen = jnp.take_along_axis(scores, idx, axis=1)
+    return scaling * chosen / (chosen.sum(axis=1, keepdims=True) + 1e-20)
+
+
+ROUTINGS = {
+    # tokens, k, router width, held, the bias' favoured experts (None:
+    # a random router)
+    "random": (128, 4, 32, (2, 2), None),
+    "all-held": (128, 4, 16, (0, 16), None),
+    "none-held": (128, 4, 32, (2, 2), (8, 9, 10, 11)),
+    "top8": (128, 8, 128, (0, 8), None),
+    "two-tiles": (200, 4, 32, (2, 2), None),    # tokens no power of two
+}
+
+
+def routing(case):
+    n, k, e, held, favoured = ROUTINGS[case]
+    x = jax.random.normal(jax.random.PRNGKey(11), (n, 64))
+    router = jax.random.normal(jax.random.PRNGKey(12), (64, e)) * 0.3
+    bias = jnp.zeros((e,))
+    if favoured is not None:    # equal scores: the bias alone selects
+        router = jnp.zeros_like(router)
+        bias = bias.at[jnp.array(favoured)].set(1.0)
+    return x, router, bias, k, held
+
+
+@pytest.mark.parametrize("case", list(ROUTINGS))
+def test_pos_by_counting_is_the_scatters_to_the_bit(case):
+    x, router, bias, k, held = routing(case)
+    r = gm.route_sigmoid_topk(x, router, bias, k, 2.5)
+    d = gm.plan_dispatch(r.idx, held)
+    np.testing.assert_array_equal(d.pos, scatter_pos(r.idx, held))
+    held_slots = int(((r.idx >= held[0])
+                      & (r.idx < held[0] + held[1])).sum())
+    assert int(d.valid.sum()) == int(d.group_sizes.sum()) == held_slots
+    assert held_slots == {"all-held": x.shape[0] * k,
+                          "none-held": 0}.get(case, held_slots)
+    # every held row's assignment is the one whose `pos` names it
+    flat = np.asarray(d.row_assign)[:held_slots]
+    np.testing.assert_array_equal(
+        np.asarray(d.pos).reshape(-1)[flat], np.arange(held_slots))
+
+
+@pytest.mark.parametrize("case", list(ROUTINGS))
+def test_chosen_and_its_gradient_are_the_gathers_to_the_bit(case):
+    x, router, bias, k, _ = routing(case)
+    cot = jax.random.normal(jax.random.PRNGKey(13), (x.shape[0], k))
+
+    def run(route):
+        w, pull = jax.vjp(lambda x, r: route(x, r), x, router)
+        return (w, *pull(cot))
+
+    got = run(lambda x, r: gm.route_sigmoid_topk(x, r, bias, k, 2.5).weights)
+    want = run(lambda x, r: gathered_route(x, r, bias, k, 2.5))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("case", list(ROUTINGS))
+def test_held_rows_are_the_pick_form_on_every_rung(case):
+    """Combine forward, dispatch backward (each token's sum of its held
+    rows) and combine backward's weight cotangent (over the rows), each
+    on every rung of the case's ladder, against the [N, k, H] pick in
+    f32."""
+    x, router, bias, k, held = routing(case)
+    r = gm.route_sigmoid_topk(x, router, bias, k, 2.5)
+    d = gm.plan_dispatch(r.idx, held)
+    n, e = x.shape[0], router.shape[1]
+    for rung in gm.row_ladder(n, k, held, e):
+        rows = jax.random.normal(jax.random.PRNGKey(rung), (rung, 64))
+        row_assign, valid = d.row_assign[:rung], d.valid & (d.pos < rung)
+        dy = jax.random.normal(jax.random.PRNGKey(14), (n, 64))
+        y, pull = jax.vjp(lambda rows, w: gm.combine_rows(
+            rows, w, row_assign, d.pos, valid), rows, r.weights)
+        _, d_w = pull(dy)
+        picked = pick(rows, d.pos, valid)
+        w = jnp.where(valid, r.weights, 0.0)
+        with jax.default_matmul_precision("highest"):
+            want_y = jnp.einsum("nk,nkh->nh", w, picked)
+            want_dw = jnp.einsum("nh,nkh->nk", dy, picked)
+        _, pull = jax.vjp(lambda x: gm.dispatch_rows(
+            x, row_assign, d.pos, valid), x)
+        (dx,) = pull(rows)
+        assert rel_err(y, want_y) <= 1e-6, rung
+        assert rel_err(d_w, want_dw) <= 1e-6, rung
+        assert rel_err(dx, picked.sum(axis=1)) <= 1e-6, rung
+        assert bool((y == 0).all()) == (case == "none-held")
+
+
+@pytest.mark.parametrize("weighed", [False, True], ids=["plain", "weighed"])
+@pytest.mark.parametrize("case", ["random", "top8", "all-held"])
+def test_held_sum_of_bf16_rows_is_the_pick_forms_on_every_rung(case,
+                                                                weighed):
+    """The rows as the step holds them, in bf16: each token's sum, in
+    f32, equals the [N, k, H] pick's cast to f32 and summed over k, with
+    and without combine's weights, on every rung."""
+    x, router, bias, k, held = routing(case)
+    r = gm.route_sigmoid_topk(x, router, bias, k, 2.5)
+    d = gm.plan_dispatch(r.idx, held)
+    w = r.weights if weighed else None
+    for rung in gm.row_ladder(x.shape[0], k, held, router.shape[1]):
+        rows = jax.random.normal(jax.random.PRNGKey(rung), (rung, 64),
+                                 jnp.bfloat16)
+        valid = d.valid & (d.pos < rung)
+        got = gm._held_sum(rows, d.pos, valid, w)
+        picked = pick(rows, d.pos, valid).astype(jnp.float32)
+        if weighed:
+            picked = r.weights[:, :, None] * picked
+        assert got.dtype == jnp.float32
+        assert rel_err(got, picked.sum(axis=1)) <= 1e-6, rung
+
+
+def test_the_step_holds_no_tokens_by_slots_by_hidden_value():
+    """Forward, recomputed forward and backward of `ExpertFFN` on its
+    ladder: no value of shape (N, k, H) anywhere, and no scatter."""
+    c = small(n_routed_experts=32)
+    n, h, k = 48, c.hidden_size, c.num_experts_per_tok
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, n, h))
+    params = ExpertFFN(c).init(jax.random.PRNGKey(6), x)["params"]
+
+    def loss(params, x):
+        y, aux = ExpertFFN(c).apply({"params": params}, x)
+        return jnp.sum(y * y) + aux["bias_loss"]
+
+    jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(loss), argnums=(0, 1)))(
+        params, x).jaxpr
+    eqns = list(sub_jaxprs(jaxpr))
+    shapes = {v.aval.shape for e in eqns for v in e.outvars
+              if hasattr(v.aval, "shape")}
+    assert (n, k, h) not in shapes
+    assert not [e for e in eqns if "scatter" in e.primitive.name]
+    sums = [e for e in eqns if e.params.get("name") == "_held_sum"]
+    # a rung each: combine's forward in the forward and the recomputed
+    # forward; in the backward the rebuilt forward's and dispatch's
+    # backward
+    assert len(sums) == 3 * (1 + 1 + 2)
+
+
 # -- (d) latent attention through the flash kernels at d = 256 ----------------
 
 
