@@ -115,6 +115,7 @@ def check_flash(grid: Sequence = FLASH_GRID,
 
     fused_limit = (flash._BWD_STREAM_VMEM_LIMIT if budget is None
                    else budget)
+    fwd_limit = flash._FWD_STREAM_VMEM_LIMIT if budget is None else budget
     budget = flash._VMEM_BUDGET if budget is None else budget
     findings = []
     for t, d, dtype_name, causal, window in grid:
@@ -133,7 +134,17 @@ def check_flash(grid: Sequence = FLASH_GRID,
             (which, plan[which]["scheme"], (bq, bk), flash._kernel_vmem(
                 which, plan[which]["scheme"], bq, bk, d, dtype.itemsize,
                 t), budget)
-            for which in (("fwd",) if fused else ("fwd", "dq", "dkv"))]
+            for which in (() if fused else ("dq", "dkv"))]
+        # a window-less forward names its own tiles; on the streaming
+        # grid it states its own limit too
+        fwd = plan["fwd"]
+        fq, fk = fwd.get("block_q", bq), fwd.get("block_k", bk)
+        kernels.insert(0, (
+            "fwd", fwd["scheme"], (fq, fk),
+            flash._kernel_vmem("fwd", fwd["scheme"], fq, fk, d,
+                               dtype.itemsize, t),
+            fwd_limit if window is None and fwd["scheme"] == "stream"
+            else budget))
         if fused:
             kernels.append(("bwd", bwd["scheme"],
                             (bwd["block_q"], bwd["block_k"]),

@@ -32,17 +32,23 @@ shape):
   and no fully-masked block is ever visited, in ALL of fwd, dq and
   dkv. The resident side is DMA'd once per head instead of once per
   outer block (the streaming grid re-fetches every K/V block nq
-  times). The forward of every such call runs here; the dq + dkv pair
-  of loops serves only calls that `_tiles` gives several blocks under
-  512 rows and windows at rectangular tiles, since the one-kernel
-  backwards below take the rest (PR 33, PR 37). A model's sliding
-  layers run their forward here (T 8192, d 128, window 2047: 512 x
-  512) and their backward on `_bwd_res_kernel`.
-- **stream** (fallback past the VMEM budget — long T, big D): the
-  round-5 grid (B*H, outer, inner) with VMEM-scratch-carried online
-  state. Causal masking skips compute via `pl.when`; sliding windows
-  narrow the inner grid dim itself (`_window_span`, affine
-  front-padded index maps).
+  times). The forward of a windowed call runs here, and of a
+  window-less one only where it is one block or `_tiles` gives it
+  blocks under 512 rows (`_fwd_schedule`); the dq + dkv pair of loops
+  serves only calls that `_tiles` gives several blocks under 512 rows
+  and windows at rectangular tiles, since the one-kernel backwards
+  below take the rest. A model's sliding layers run their forward
+  here (T 8192, d 128, window 2047: 512 x 512) and their backward on
+  `_bwd_res_kernel`.
+- **stream**: the grid (B*H, outer, inner) with VMEM-scratch-carried
+  online state. Every other window-less forward runs here, at its own
+  1024 x 1024 where T divides (the forward's sweep below): a step past
+  the causal diagonal computes nothing and names the last k-block its
+  q-block sees, so the pipeline fetches nothing for it, and only the
+  steps the diagonal crosses build the mask. Past the VMEM budget it
+  is the fallback of the other kernels too; sliding windows narrow
+  the inner grid dim itself (`_window_span`, affine front-padded
+  index maps).
 
 Window-less, the backward of a resident or streaming call is ONE
 kernel (`_bwd_stream_kernel`, "stream_fused" in `flash_plan`) on the
@@ -210,12 +216,47 @@ beside the parent's in the same call, did not move (glm 16.607 /
 16.607, `ouro` 2.378 / 2.377, the full layers 15.301 / 15.296, d 64 at
 T 4096 3.400 / 3.397): their kernels are the parent's to the jaxpr.
 
+What the chip said of the window-less forward (one TPU v5e,
+2026-10-16; bf16, the forward alone with its lse, the isolated call,
+slope-timed as `benchmarks/flash_eff.py` times it; each line twice, in
+opposite orders, the mean; the two readings within 0.01 ms but one at
+0.08). Columns: the loops at the call's auto tiles (the streaming grid
+at d = 256, where they pass the budget), the loops at 512 x 512, the
+streaming grid at 1024 x 1024 fetching and masking as before, then
+with no fetch past the diagonal (`_fwd_kv_block`), then with the mask
+only where the diagonal crosses (`_under_diag`):
+
+  d 64, T 4096, 24 heads        loops 1024^2   1.332  1.266  1.184  1.187  **1.173**
+  d 128, T 4096, 16 heads       loops 1024x512 1.005  0.913  0.870  0.871  **0.861**
+  d 128, T 8192, 32 heads on 4  loops 1024x512 6.145  5.720  5.348  5.322  **5.274**
+  d 256, T 8192, 20 heads       grid 1024x512  6.061  -      5.794  5.312  **5.260**
+  non-causal d 128, T 4096, 16  loops 1024x512 1.409  1.317  1.196  1.192  1.196
+
+At d = 256 the grid at 1024 x 512 read 5.472 with no fetch past the
+diagonal and 5.506 with the mask split as well: the square wins there
+too. So every class takes the last column (the non-causal call has no
+diagonal: the grid alone). The grid beats the loops at their own
+tiles, whose full-length K/V come once a head: a loop's trip costs more
+than a grid step here. Naming the last visible block bought nothing
+where the K/V blocks are small (d <= 128: their fetch hides under the
+step) and 0.48 ms, 8%, at d = 256; a min() in the index map lost no
+pipelining (`_span_step`'s record of a max() did not repeat, as for
+`_stream_bwd`). Taking the mask off the steps under the diagonal bought
+0.5–1%. At d = 64, whose tiles stay 1024 x 1024, every form gives the
+loops' bits; elsewhere the outputs stand within one bf16 rounding of
+the loops' (0.002 at most, the lse within 9e-6: the block partition of
+the online softmax moved). In the cell
+`gpt2-small.train-b2-t4096` the forward went 14.90 -> 13.04 ms a step
+and the rate +2.4% (PERF.md).
+
 Auto block sizes are budget-driven: the head kernels' chunk where they
 apply, else the largest power-of-two tile <= 1024 that keeps the worst
 kernel's VMEM estimate under budget (big head dims shrink blocks
 instead of failing to compile). The fused backward takes 1024 x 1024
 where T divides, whatever the forward's tiles, else the call's; under
 a window the call's (512 x 512 at the sliding call: the sweep above).
+The window-less forward past the head kernels takes 1024 x 1024 where
+T divides too (`_fwd_schedule`).
 
 Backward overhead trims (round 6): in the dq + dkv pairs the delta
 precompute (`rowsum(dO * O)`, FlashAttention-2 eq. 4) is folded into
@@ -236,6 +277,7 @@ shapes don't tile (T % block != 0) or on backends without Mosaic
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -428,6 +470,28 @@ def _diag_ok(iq, jk, causal, block_q, block_k, window=None):
     return ok
 
 
+def _under_diag(iq, jk, block_q, block_k):
+    """True where k-block `jk` lies wholly under q-block `iq`'s causal
+    diagonal: its newest key is no later than the block's oldest query,
+    so every pair is visible and the step needs no mask (the head
+    kernels' wide step, on the streaming grid's block steps)."""
+    return (jk + 1) * block_k - 1 <= iq * block_q
+
+
+def _fwd_kv_block(j, kk, nk, *, causal, block_q, block_k):
+    """The k-block that the window-less streaming forward's grid step
+    (q-block `j`, inner step `kk`) fetches: `kk`, except that a causal
+    step past the q-block's last visible k-block computes nothing and
+    names that last one, as the step before it did, so the pipeline
+    fetches nothing for it (`_stream_bwd`'s `qdo_j` in mirror image).
+    Works on Python ints (planning, tests) and traced values alike."""
+    if not causal:
+        return kk
+    _, hi = _k_span(j, nk, causal=True, window=None, block_q=block_q,
+                    block_k=block_k)
+    return jnp.minimum(kk, hi - 1)
+
+
 def _span_step(iq, kk, *, span, causal, block_q, block_k, window):
     """Streaming-scheme inner-step gate, shared by `_kernel` and
     `_bwd_dq_kernel` (the single definition of which narrowed steps
@@ -553,8 +617,13 @@ def _dkv_stream_vmem(bq, bk, d, isz, t):
 # whose estimate is under it (the largest 63.0 MiB: T 32768, d 128,
 # f32); a call whose estimate passes it runs the dq + dkv pair.
 _BWD_STREAM_VMEM_LIMIT = 64 * 1024 * 1024
-# its auto tile, by the sweep in the module docstring: 1024 x 1024
+# its auto tile, by the sweep in the module docstring: 1024 x 1024;
+# the window-less streaming forward's too (`_fwd_schedule`)
 _BWD_STREAM_BLOCK = 1024
+# the scoped-VMEM limit the window-less streaming forward states: its
+# 1024 x 1024 step at d = 256 estimates 13.6 MB, under Mosaic's 16 MB
+# default with little room for what the estimate leaves out
+_FWD_STREAM_VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _bwd_stream_vmem(bq, bk, d, isz, t):
@@ -683,6 +752,30 @@ def _kernel_vmem(which, scheme, bq, bk, d, isz, t):
             "dq": _dq_stream_vmem}[which](bq, bk, d, isz)
 
 
+def _fwd_schedule(t, d, isz, bq, bk, causal, window, auto):
+    """(scheme, block_q, block_k) of the forward kernel. The head
+    kernels, windowed calls (their 512 x 512 loops won the windowed
+    sweep in the module docstring) and calls of one block keep
+    `_choose_scheme`'s pick at the call's tiles. Every other call runs
+    on the streaming grid at its own tiles (the forward's sweep there):
+    `_BWD_STREAM_BLOCK` square where the caller left the tiles to
+    `_tiles`, T divides and `_fwd_stream_vmem` fits the limit the
+    kernel states, else the call's; where those are under 512 rows and
+    the loops fit, the loops keep the call, as `_bwd_stream_tiles`
+    keeps their pair."""
+    scheme = _choose_scheme("fwd", t, d, isz, bq, bk, causal, window)
+    if (scheme == "head" or window is not None or bq == bk == t
+            or _FORCE_SCHEME == "resident"):
+        return scheme, bq, bk
+    b = _BWD_STREAM_BLOCK
+    if (auto and t % b == 0
+            and _fwd_stream_vmem(b, b, d, isz) <= _FWD_STREAM_VMEM_LIMIT):
+        bq, bk = b, b
+    if scheme == "resident" and bk < 512 and _FORCE_SCHEME is None:
+        return scheme, bq, bk
+    return "stream", bq, bk
+
+
 def _bwd_stream_tiles(t, d, isz, bq, bk, causal, window, auto):
     """(block_q, block_k) of the fused backward where a call takes it,
     else None: `_bwd_stream_kernel` window-less, `_bwd_res_kernel` under
@@ -739,9 +832,15 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
 
     `span` (sliding window): the grid's inner dim is narrowed to the
     `span` K blocks a q-block can actually see, and the K/V index maps
-    shift by the q-block (see _flash_fwd_impl) — out-of-window K/V
+    shift by the q-block (see `_stream_fwd`) — out-of-window K/V
     blocks never even stream their DMA. The kernel recovers the REAL
-    k-block index from the window-relative grid index here."""
+    k-block index from the window-relative grid index here.
+
+    Causal without a window, the steps split in two: a k-block wholly
+    under the diagonal (`_under_diag`) takes the unmasked step, only
+    the blocks the diagonal crosses build the mask, as in the head
+    kernels; a step past the diagonal computes nothing and fetches
+    nothing (`_fwd_kv_block`)."""
     iq = pl.program_id(1)
     kk = pl.program_id(2)            # window-relative when narrowed
     nk = pl.num_programs(2)
@@ -754,15 +853,22 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(ok)
-    def _():
+    def step(masked):
         acc, m, l = _fwd_step(
             q_ref[0], k_ref[0], v_ref[0], iq, jk, acc_ref[:],
-            m_ref[:, 0], l_ref[:, 0], scale=scale, causal=causal,
+            m_ref[:, 0], l_ref[:, 0], scale=scale, causal=masked,
             block_q=block_q, block_k=block_k, window=window)
         acc_ref[:] = acc
         m_ref[:, 0] = m
         l_ref[:, 0] = l
+
+    if causal and window is None:
+        below = _under_diag(iq, jk, block_q, block_k)
+        pl.when(below)(lambda: step(False))
+        pl.when(jnp.logical_and(ok, jnp.logical_not(below)))(
+            lambda: step(True))
+    else:
+        pl.when(ok)(lambda: step(causal))
 
     @pl.when(kk == nk - 1)
     def _():
@@ -1133,8 +1239,10 @@ def flash_attention(
 
     Each kernel then runs the head scheme where it applies, else the
     VMEM-resident block-skipping loops when they fit the budget, else
-    the streaming grid — see the module docstring and `flash_plan` for
-    the decision and the per-shape visited-block counts.
+    the streaming grid; a window-less forward past the head kernels
+    and one block streams at its own tiles (`_fwd_schedule`) — see the
+    module docstring and `flash_plan` for the decision and the
+    per-shape visited-block counts.
 
     Backward pass: fused flash backward kernels — the forward saves only
     (q, k, v, o, lse), dq/dk/dv are computed blockwise with the
@@ -1332,6 +1440,60 @@ def _kv_group(q, k, v):
     return q.shape[2] // k.shape[2]
 
 
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "causal", "block_q", "block_k", "window", "save_lse",
+    "interpret"))
+def _stream_fwd(qb, kb, vb, *, scale, causal, block_q, block_k, window,
+                save_lse, interpret):
+    """(o, lse) or o of [B*H, T, D] queries and [B*H_kv, T, D] keys and
+    values on the streaming grid; lse comes back [B*H, nq, 1, block_q].
+    Window-less, the K/V index map is `_fwd_kv_block`'s and the call
+    states `_FWD_STREAM_VMEM_LIMIT`; under a window the grid narrows
+    (`_narrowed_kv`). An inlined jit, as `_head_fwd`, so that a model's
+    layers 2..L replay the first one's trace of the two-branch body."""
+    bh, t, d = qb.shape
+    nq, nk = t // block_q, t // block_k
+    kv_of = _kv_row(bh // kb.shape[0])
+    if window is None:
+        span = None
+
+        def kv_j(i, j, kk):
+            return kv_of(i), _fwd_kv_block(
+                j, kk, nk, causal=causal, block_q=block_q,
+                block_k=block_k), 0
+
+        params = dataclasses.replace(
+            _dim_semantics(3), vmem_limit_bytes=_FWD_STREAM_VMEM_LIMIT)
+    else:
+        span, kv_j, kb, vb = _narrowed_kv(causal, window, block_q,
+                                          block_k, nk, kb, vb, kv_of)
+        params = _dim_semantics(3)
+    kernel = functools.partial(
+        _kernel if save_lse else _kernel_nolse, scale=scale,
+        causal=causal, block_q=block_q, block_k=block_k,
+        window=window, span=span)
+    rows = pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0))
+    o_shape = jax.ShapeDtypeStruct(qb.shape, qb.dtype)
+    lse_spec = pl.BlockSpec((1, 1, 1, block_q),
+                            lambda i, j, kk: (i, j, 0, 0))
+    lse_shape = jax.ShapeDtypeStruct((bh, nq, 1, block_q), jnp.float32)
+    return pl.pallas_call(
+        kernel,
+        grid=(bh, nq, span if span is not None else nk),
+        in_specs=[rows, pl.BlockSpec((1, block_k, d), kv_j),
+                  pl.BlockSpec((1, block_k, d), kv_j)],
+        out_specs=[rows, lse_spec] if save_lse else rows,
+        out_shape=[o_shape, lse_shape] if save_lse else o_shape,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),  # out accumulator
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running denom
+        ],
+        compiler_params=params,
+        interpret=interpret,
+    )(qb, kb, vb)
+
+
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
                     save_lse, window=None):
     """Returns (out, lse) — lse is None on the plain-attention fallback
@@ -1351,22 +1513,22 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     isz = jnp.dtype(q.dtype).itemsize
+    auto = block_q is None and block_k is None
     tiles = _tiles(t, causal, block_q, block_k, window, d=d,
                    itemsize=isz)
     if tiles is None:
         return _plain_attention(q, k, v, causal, scale,
                                 window=window), None
-    block_q, block_k = tiles
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    scheme, block_q, block_k = _fwd_schedule(t, d, isz, *tiles, causal,
+                                             window, auto)
 
     nq, nk = t // block_q, t // block_k
     o_shape = jax.ShapeDtypeStruct((b * h, t, d), q.dtype)
     lse_shape = jax.ShapeDtypeStruct((b * h, nq, 1, block_q),
                                      jnp.float32)
 
-    scheme = _choose_scheme("fwd", t, d, isz, block_q, block_k, causal,
-                            window)
     if scheme == "head":
         result = _head_fwd(_bh(q), _bh(k), _bh(v), scale=scale,
                            block=block_q, save_lse=save_lse,
@@ -1393,35 +1555,10 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
             interpret=interpret,
         )(_bh(q), _bh(k), _bh(v))
     else:
-        span, kv_j, kb_in, vb_in = _narrowed_kv(
-            causal, window, block_q, block_k, nk, _bh(k), _bh(v), kv_of)
-        kernel = functools.partial(
-            _kernel if save_lse else _kernel_nolse, scale=scale,
-            causal=causal, block_q=block_q, block_k=block_k,
-            window=window, span=span)
-        o_spec = pl.BlockSpec((1, block_q, d),
-                              lambda i, j, kk: (i, j, 0))
-        lse_spec = pl.BlockSpec((1, 1, 1, block_q),
-                                lambda i, j, kk: (i, j, 0, 0))
-        result = pl.pallas_call(
-            kernel,
-            grid=(b * h, nq, span if span is not None else nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d),
-                             lambda i, j, kk: (i, j, 0)),
-                pl.BlockSpec((1, block_k, d), kv_j),
-                pl.BlockSpec((1, block_k, d), kv_j),
-            ],
-            out_specs=[o_spec, lse_spec] if save_lse else o_spec,
-            out_shape=[o_shape, lse_shape] if save_lse else o_shape,
-            scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),  # out accumulator
-                pltpu.VMEM((block_q, 1), jnp.float32),  # running max
-                pltpu.VMEM((block_q, 1), jnp.float32),  # running denom
-            ],
-            compiler_params=_dim_semantics(3),
-            interpret=interpret,
-        )(_bh(q), kb_in, vb_in)
+        result = _stream_fwd(_bh(q), _bh(k), _bh(v), scale=scale,
+                             causal=causal, block_q=block_q,
+                             block_k=block_k, window=window,
+                             save_lse=save_lse, interpret=interpret)
     if not save_lse:
         return _unbh(result, b, h), None
     out, lse = result
@@ -1915,6 +2052,36 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 # ---------------------------------------------------------------------------
 
 
+def _fwd_plan(t, d, isz, bq, bk, causal, auto):
+    """`flash_plan`'s "fwd" entry of a window-less call (`_fwd_schedule`
+    at the call's tiles `bq`, `bk`), counted a query head in units of
+    the forward's own tiles."""
+    scheme, bq, bk = _fwd_schedule(t, d, isz, bq, bk, causal, None, auto)
+    nq, nk = t // bq, t // bk
+    if scheme == "stream":
+        steps = [(iq, jk) for iq in range(nq) for jk in range(nk)]
+        computes = [(iq, jk) for iq, jk in steps
+                    if _diag_ok(iq, jk, causal, bq, bk)]
+        visited = len(computes)
+        masked = sum(not _under_diag(iq, jk, bq, bk)
+                     for iq, jk in computes) if causal else 0
+        kv = [int(_fwd_kv_block(iq, jk, nk, causal=causal, block_q=bq,
+                                block_k=bk)) for iq, jk in steps]
+        fetched = 1 + sum(a != b for a, b in zip(kv, kv[1:]))
+    else:   # the whole head's K/V once; a loop or the unrolled steps
+        fetched = nk
+        if scheme == "head":
+            visited, masked = nq * (nq + 1) // 2, nq
+        else:
+            visited = sum(int(hi) - int(lo) for lo, hi in (
+                _k_span(iq, nk, causal=causal, window=None, block_q=bq,
+                        block_k=bk) for iq in range(nq)))
+            masked = visited if causal else 0
+    return {"scheme": scheme, "block_q": bq, "block_k": bk,
+            "visited_blocks": visited, "masked_blocks": masked,
+            "fetched_blocks": fetched, "grid_blocks": nq * nk}
+
+
 def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
                block_q=None, block_k=None, q_per_kv=1):
     """Static execution plan for `flash_attention` at this shape: block
@@ -1941,12 +2108,26 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
     kernels. "dq" / "dkv" say what the pair would run: which of the two
     narrowed its grid or its loops.
 
+    "fwd" of a window-less call (`_fwd_plan`) names its own tiles,
+    `block_q` and `block_k` (`_fwd_schedule`), and counts in them:
+    `visited_blocks` the block steps that run; `masked_blocks` those
+    that build the mask (the loops every one, the streaming grid and
+    the head kernels only those the diagonal crosses); and
+    `fetched_blocks` the K/V blocks the pipeline moves a head: the whole
+    head once (`nk`) in the head and resident schemes, on the streaming
+    grid one wherever a step's block index (`_fwd_kv_block`) differs
+    from the step before's, so no step past the diagonal fetches. A
+    windowed call's "fwd" is counted at the call's tiles, as "dq" is.
+
     `q_per_kv` > 1 (grouped K/V heads: that many query heads read one
     K/V head) changes no tile, scheme or count, all of them a query
     head's, and adds one entry, "kv_group": how K/V are read (`_kv_row`
     in the block specs' index maps), where dk and dv are summed over a
     group (`_unbh_kv`), and the bytes of the per-query-head dk and dv
-    that sum reads, a K/V head. Ungrouped plans carry no such key."""
+    that sum reads, a K/V head. Ungrouped plans carry no such key. (A
+    full-length K/V block keeps its index over a group's query heads,
+    so the head and resident forwards move it once a group: "fwd"'s
+    `fetched_blocks` counts as if ungrouped.)"""
     isz = jnp.dtype(dtype).itemsize
     tiles = _tiles(t, causal, block_q, block_k, window, d=d,
                    itemsize=isz)
@@ -1958,7 +2139,10 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
             "operand_dtype": jnp.dtype(_operand_dtype(dtype)).name}
     span = _window_span(window, bq, bk, nk) if causal else None
     kw = dict(causal=causal, window=window, block_q=bq, block_k=bk)
-    for which in ("fwd", "dq", "dkv"):
+    auto = block_q is None and block_k is None
+    if window is None:
+        plan["fwd"] = _fwd_plan(t, d, isz, bq, bk, causal, auto)
+    for which in ("dq", "dkv") if window is None else ("fwd", "dq", "dkv"):
         scheme = _choose_scheme(which, t, d, isz, bq, bk, causal, window)
         masked = None
         if scheme == "head":
@@ -1981,8 +2165,7 @@ def flash_plan(t, d, *, dtype=jnp.float32, causal=False, window=None,
                        "masked_blocks": masked,
                        "grid_blocks": nq * nk}
     pair = [plan[which]["scheme"] for which in ("dq", "dkv")]
-    fused = _bwd_stream_tiles(t, d, isz, bq, bk, causal, window,
-                              block_q is None and block_k is None)
+    fused = _bwd_stream_tiles(t, d, isz, bq, bk, causal, window, auto)
     if fused is not None:
         bq, bk = fused
         nq, nk = t // bq, t // bk

@@ -78,7 +78,10 @@ def test_head_kernels_unroll_the_causal_schedule(t, block):
     for which in ("fwd", "dq", "dkv"):
         assert plan[which] == {
             "scheme": "head", "visited_blocks": nb * (nb + 1) // 2,
-            "masked_blocks": nb, "grid_blocks": nb * nb}
+            "masked_blocks": nb, "grid_blocks": nb * nb,
+            # the forward names its tiles and fetches the head once
+            **({"block_q": block, "block_k": block, "fetched_blocks": nb}
+               if which == "fwd" else {})}
     q, k, v = qkv(t=t)
 
     def loss(q, k, v):
@@ -497,6 +500,211 @@ def test_fused_backward_takes_its_own_tiles(monkeypatch):
                             2e-4)
 
 
+# -- the window-less forward on the streaming grid ----------------------------
+
+
+def _kv_index_map(t, d, causal, blocks, h=2, h_kv=2):
+    """The forward kernel's K block spec index map, out of the traced
+    `pallas_call`, as a function of Python ints (i, j, kk) -> block."""
+    q = jnp.zeros((1, t, h, d))
+    k = jnp.zeros((1, t, h_kv, d))
+    jaxpr = jax.make_jaxpr(lambda q, k, v: F._flash_fwd_impl(
+        q, k, v, causal, None, *blocks, True, save_lse=True))(q, k, k)
+    (eqn,) = _pallas_eqns(jaxpr.jaxpr)
+    grid = eqn.params["grid_mapping"].grid
+    fn = eqn.params["grid_mapping"].block_mappings[1].index_map_jaxpr
+
+    def block(*ijk):
+        return tuple(int(x) for x in jax.core.eval_jaxpr(
+            fn.jaxpr, fn.consts, *ijk))
+    return grid, block
+
+
+def _forward_counts_by_brute_force(t, bq, bk, causal):
+    """(visited, masked, fetched) of the streaming forward a head, from
+    the position mask alone: a step computes where its block holds a
+    visible pair, builds the mask unless every pair is visible, and the
+    pipeline moves a K/V block wherever the block a step needs (its own,
+    or for a step that computes nothing the last one its q-block sees)
+    differs from the step before's."""
+    nq, nk = t // bq, t // bk
+    keep = (np.arange(t)[:, None] >= np.arange(t)[None, :] if causal
+            else np.ones((t, t), bool)).reshape(nq, bq, nk, bk)
+    any_, all_ = keep.any(axis=(1, 3)), keep.all(axis=(1, 3))
+    seq = []
+    for iq in range(nq):
+        last = max(jk for jk in range(nk) if any_[iq, jk])
+        seq += [jk if any_[iq, jk] else last for jk in range(nk)]
+    fetched = 1 + sum(a != b for a, b in zip(seq, seq[1:]))
+    return int(any_.sum()), int((any_ & ~all_).sum()), fetched, any_
+
+
+def test_streaming_forward_traces_once_for_every_layer(monkeypatch):
+    """As the head kernels: a model's layers call the streaming forward
+    at one shape, the second call replays the first's trace of its
+    two-branch body, and the kernel lands under the caller's scope with
+    no name between it and `pallas_call`."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    q, k, v = qkv(t=768, h=3, d=16)   # a shape no other test traces
+    traced = []
+    real = F._kernel
+
+    def counting(*a, **kw):
+        traced.append(1)
+        return real(*a, **kw)
+
+    def model(q, k, v):
+        x = q
+        for i in range(3):
+            with jax.named_scope(f"CausalSelfAttention_{i}"):
+                x = flash_attention(x, k, v, causal=True, block_q=256,
+                                    block_k=256)
+        return x.sum()
+
+    import unittest.mock as mock
+    with mock.patch.object(F, "_kernel", counting):
+        jaxpr = jax.make_jaxpr(jax.grad(model))(q, k, v)
+    assert len(traced) == 1
+    eqns = _pallas_eqns(jaxpr.jaxpr)
+    assert len(eqns) == 6
+    assert {str(e.source_info.name_stack) for e in eqns} == {
+        f(f"jvp(CausalSelfAttention_{i})") for i in range(3)
+        for f in (str, "transpose({})".format)}
+
+
+@pytest.mark.parametrize("t,bq,bk,causal", [
+    (4096, 1024, 1024, True),    # the d = 64 cell's call
+    (4096, 1024, 512, True),     # the loops' old tiles at d = 128
+    (8192, 1024, 1024, True),
+    (8192, 1024, 512, True),     # the glm cell's old tiles
+    (4096, 1024, 1024, False),
+    (2048, 512, 128, True),      # bq = 4 bk
+])
+def test_forward_fetch_and_mask_counts_match_a_brute_force(
+        monkeypatch, t, bq, bk, causal):
+    """`flash_plan`'s "fwd" counts on the streaming grid against the
+    position mask: `visited_blocks` the steps `_diag_ok` passes,
+    `masked_blocks` those the diagonal crosses (every other one takes
+    the unmasked step), `fetched_blocks` the K/V blocks the pipeline
+    moves a head: no step past the diagonal fetches, and a q-block's
+    first block repeats the last one of the q-block before it only at
+    the top."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    fwd = F.flash_plan(t, 64, dtype=jnp.bfloat16, causal=causal,
+                       block_q=bq, block_k=bk)["fwd"]
+    visited, masked, fetched, any_ = _forward_counts_by_brute_force(
+        t, bq, bk, causal)
+    nq, nk = t // bq, t // bk
+    assert all(bool(F._diag_ok(iq, jk, causal, bq, bk)) == any_[iq, jk]
+               for iq in range(nq) for jk in range(nk))
+    assert fwd == {"scheme": "stream", "block_q": bq, "block_k": bk,
+                   "visited_blocks": visited, "masked_blocks": masked,
+                   "fetched_blocks": fetched, "grid_blocks": nq * nk}
+    if causal:   # the diagonal crosses bq / bk blocks a q-block
+        assert masked == nq * (bq // bk) and fetched <= visited < nq * nk
+    else:
+        assert masked == 0 and fetched == visited == nq * nk
+
+
+@pytest.mark.parametrize("causal,blocks,h,h_kv", [
+    (True, (128, 128), 2, 2), (True, (256, 128), 2, 2),
+    (True, (128, 128), 8, 1), (False, (128, 128), 2, 2)],
+    ids=["square", "rect", "grouped-8", "non-causal"])
+def test_forward_kv_index_map_names_the_last_visible_block(
+        monkeypatch, causal, blocks, h, h_kv):
+    """The K block spec of the traced forward, evaluated at every grid
+    step: a step that computes names its own k-block, of its query
+    head's K/V head; a causal step past the diagonal names the last
+    k-block its q-block sees, so the block index does not move."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", "stream")
+    t = 512
+    grid, block = _kv_index_map(t, 64, causal, blocks, h, h_kv)
+    bq, bk = blocks
+    assert grid == (h, t // bq, t // bk)
+    for i in range(grid[0]):
+        for j in range(grid[1]):
+            last = max(jk for jk in range(grid[2])
+                       if F._diag_ok(j, jk, causal, bq, bk))
+            for kk in range(grid[2]):
+                want = kk if F._diag_ok(j, kk, causal, bq, bk) else last
+                assert block(i, j, kk) == (i // (h // h_kv), want, 0)
+            assert last == (grid[2] - 1 if not causal
+                            else ((j + 1) * bq - 1) // bk)
+
+
+def _plain_lse(q, k, causal, scale):
+    """[B*H, T] logsumexp of the scaled (and causally masked) scores."""
+    group = q.shape[2] // k.shape[2]
+    k = jnp.repeat(k, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    if causal:
+        t = q.shape[1]
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return lse.reshape(-1, q.shape[1])
+
+
+@pytest.mark.parametrize("t,d,h,h_kv,causal,force,want", [
+    (3072, 64, 1, 1, True, None, (1024, 1024)),    # unforced, past the head
+    (2048, 64, 1, 1, True, "stream", (1024, 1024)),
+    (2048, 128, 2, 2, False, None, (1024, 1024)),
+    (2048, 64, 8, 1, True, "stream", (1024, 1024)),   # grouped K/V
+    (1536, 128, 2, 2, False, None, (512, 512)),    # 1024 does not divide
+    (1536, 64, 2, 2, True, "stream", (256, 256)),  # ... the call's tiles
+], ids=["t3072", "causal-d64", "non-causal-d128", "grouped-8",
+        "t1536-non-causal", "t1536-causal"])
+@pytest.mark.parametrize("save_lse", [True, False])
+def test_streaming_forward_matches_plain(monkeypatch, t, d, h, h_kv,
+                                         causal, force, want, save_lse):
+    """The window-less forward on the streaming grid at the tiles
+    `flash_plan` names, against plain attention in f32: output and the
+    lse the backward consumes, with and without it."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", force)
+    fwd = F.flash_plan(t, d, causal=causal, q_per_kv=h // h_kv)["fwd"]
+    assert fwd["scheme"] == "stream"
+    assert (fwd["block_q"], fwd["block_k"]) == want
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (1, t, h, d))
+    k, v = (jax.random.normal(x, (1, t, h_kv, d)) for x in ks[1:])
+    with jax.default_matmul_precision("highest"):
+        out, lse = F._flash_fwd_impl(q, k, v, causal, None, None, None,
+                                     None, save_lse=save_lse)
+        ref = _plain_attention(q, k, v, causal, d ** -0.5)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+        if save_lse:
+            np.testing.assert_allclose(
+                np.asarray(lse), np.asarray(_plain_lse(q, k, causal,
+                                                       d ** -0.5)),
+                rtol=2e-5, atol=2e-5)
+        else:
+            assert lse is None
+
+
+@pytest.mark.parametrize("t,d,h,h_kv,causal,force", [
+    (3072, 64, 1, 1, True, None),
+    (2048, 128, 2, 2, False, None),
+    (2048, 64, 8, 1, True, "stream"),
+], ids=["t3072", "non-causal-d128", "grouped-8"])
+def test_grads_through_the_streaming_forward_match_plain(
+        monkeypatch, t, d, h, h_kv, causal, force):
+    """Gradients of `flash_attention` where the forward streams at its
+    1024 x 1024 tiles and the fused backward reads its lse, against the
+    plain VJP."""
+    monkeypatch.setattr(F, "_FORCE_SCHEME", force)
+    plan = F.flash_plan(t, d, causal=causal, q_per_kv=h // h_kv)
+    assert plan["fwd"]["scheme"] == "stream"
+    assert plan["bwd"]["scheme"] == "stream_fused"
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    q, g = (jax.random.normal(x, (1, t, h, d)) for x in ks[:2])
+    k, v = (jax.random.normal(x, (1, t, h_kv, d)) for x in ks[2:])
+    with jax.default_matmul_precision("highest"):
+        _, ref_vjp = jax.vjp(lambda q, k, v: _plain_attention(
+            q, k, v, causal, d ** -0.5), q, k, v)
+        _assert_grads_close(_grads(q, k, v, g, causal=causal),
+                            ref_vjp(g), 2e-4)
+
+
 # -- the fused backward under a window (PR 37) --------------------------------
 
 
@@ -774,11 +982,17 @@ _RESIDENT_4096 = {"scheme": "resident", "visited_blocks": 20,
                   "masked_blocks": 20, "grid_blocks": 32}
 _STREAM_4096 = {"scheme": "stream", "visited_blocks": 16,
                 "masked_blocks": 16, "grid_blocks": 16}
+# the window-less forward at its own 1024 x 1024: the steps under the
+# diagonal unmasked, nothing fetched past it
+_FWD_1024_T4096 = {"scheme": "stream", "block_q": 1024, "block_k": 1024,
+                   "visited_blocks": 10, "masked_blocks": 4,
+                   "fetched_blocks": 9, "grid_blocks": 16}
+_FWD_1024_T8192 = {"scheme": "stream", "block_q": 1024, "block_k": 1024,
+                   "visited_blocks": 36, "masked_blocks": 8,
+                   "fetched_blocks": 35, "grid_blocks": 64}
 _TRINITY_FULL = {
     "block_q": 1024, "block_k": 512, "nq": 8, "nk": 16,
-    "operand_dtype": "bfloat16",
-    "fwd": {"scheme": "resident", "visited_blocks": 72,
-            "masked_blocks": 72, "grid_blocks": 128},
+    "operand_dtype": "bfloat16", "fwd": _FWD_1024_T8192,
     "dq": _STREAM_8192, "dkv": _STREAM_8192,
     "bwd": {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
             "visited_blocks": 36, "masked_blocks": 36, "grid_blocks": 64,
@@ -788,30 +1002,29 @@ _TRINITY_FULL = {
 @pytest.mark.parametrize("t,d,q_per_kv,want", [
     (1024, 64, 1, {   # both GPT cells' call
         "block_q": 256, "block_k": 256, "nq": 4, "nk": 4,
-        "operand_dtype": "bfloat16", "fwd": _HEAD_1024, "dq": _HEAD_1024,
-        "dkv": _HEAD_1024,
+        "operand_dtype": "bfloat16", "dq": _HEAD_1024, "dkv": _HEAD_1024,
+        "fwd": {**_HEAD_1024, "block_q": 256, "block_k": 256,
+                "fetched_blocks": 4},
         "bwd": {"scheme": "head", "block_q": 256, "block_k": 256,
                 "visited_blocks": 10, "masked_blocks": 4, "grid_blocks": 16,
                 "block_matmuls": 5, "vmem_bytes": 5517312}}),
     (8192, 256, 1, {   # the glm-4.7-flash cell's call
         "block_q": 1024, "block_k": 512, "nq": 8, "nk": 16,
-        "operand_dtype": "bfloat16", "fwd": _STREAM_8192,
+        "operand_dtype": "bfloat16", "fwd": _FWD_1024_T8192,
         "dq": _STREAM_8192, "dkv": _STREAM_8192,
         "bwd": {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
                 "visited_blocks": 36, "masked_blocks": 36, "grid_blocks": 64,
                 "block_matmuls": 5, "vmem_bytes": 42074112}}),
-    (4096, 128, 1, {   # the ouro-2.6b cell's call (PR 37's parent)
+    (4096, 128, 1, {   # the ouro-2.6b cell's call
         "block_q": 1024, "block_k": 512, "nq": 4, "nk": 8,
-        "operand_dtype": "bfloat16", "fwd": _RESIDENT_4096,
+        "operand_dtype": "bfloat16", "fwd": _FWD_1024_T4096,
         "dq": _RESIDENT_4096, "dkv": _RESIDENT_4096,
         "bwd": {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
                 "visited_blocks": 10, "masked_blocks": 10, "grid_blocks": 16,
                 "block_matmuls": 5, "vmem_bytes": 25231360}}),
     (4096, 64, 1, {   # the gpt2-small.train-b2-t4096 cell's call
         "block_q": 1024, "block_k": 1024, "nq": 4, "nk": 4,
-        "operand_dtype": "bfloat16",
-        "fwd": {"scheme": "resident", "visited_blocks": 10,
-                "masked_blocks": 10, "grid_blocks": 16},
+        "operand_dtype": "bfloat16", "fwd": _FWD_1024_T4096,
         "dq": _STREAM_4096, "dkv": _STREAM_4096,
         "bwd": {"scheme": "stream_fused", "block_q": 1024, "block_k": 1024,
                 "visited_blocks": 10, "masked_blocks": 10, "grid_blocks": 16,
@@ -825,13 +1038,49 @@ _TRINITY_FULL = {
         "full-layers-ungrouped", "trinity-full-layers"])
 def test_plans_of_the_cells_that_must_not_move(t, d, q_per_kv, want):
     """`flash_plan` at the window-less shapes of the cells, key for key
-    against a literal copied from a parent commit (PR 33's for the GPT
-    and glm cells, PR 34's for the ungrouped full layers, PR 37's for
-    the rest): grouped heads, the windowed calls' tiles and the fused
-    backward's engage rule, widened to windows by PR 37, moved none of
-    them; an ungrouped plan carries no `kv_group` key."""
+    against a literal: grouped heads, the windowed calls' tiles and the
+    fused backward's engage rule moved none of the backward's keys, and
+    "fwd" names the forward's own scheme, tiles and counts (the head
+    kernels' as before, with their tiles and one fetch of the head;
+    every other call on the streaming grid at 1024 x 1024); an
+    ungrouped plan carries no `kv_group` key."""
     assert F.flash_plan(t, d, dtype=jnp.bfloat16, causal=True,
                         q_per_kv=q_per_kv) == want
+
+
+_SLIDING_8192 = {"scheme": "resident", "visited_blocks": 70,
+                 "masked_blocks": 70, "grid_blocks": 256}
+
+
+@pytest.mark.parametrize("t,d,window,q_per_kv,want", [
+    (8192, 128, 2047, 8, {   # trinity-mini's sliding layers
+        "block_q": 512, "block_k": 512, "nq": 16, "nk": 16,
+        "operand_dtype": "bfloat16", "fwd": _SLIDING_8192,
+        "dq": _SLIDING_8192, "dkv": _SLIDING_8192,
+        "bwd": {"scheme": "resident_fused", "block_q": 512, "block_k": 512,
+                "visited_blocks": 70, "masked_blocks": 70,
+                "grid_blocks": 256, "block_matmuls": 5,
+                "vmem_bytes": 22675456},
+        "kv_group": {"q_per_kv": 8, "kv_read": "index_map",
+                     "dkv_sum": "xla_f32", "dkv_partial_bytes": 33554432}}),
+    (4096, 64, 255, 1, {   # a window under 512: 128-row tiles, the pair
+        "block_q": 128, "block_k": 128, "nq": 32, "nk": 32,
+        "operand_dtype": "bfloat16",
+        **{which: {"scheme": "resident", "visited_blocks": 93,
+                   "masked_blocks": 93, "grid_blocks": 1024}
+           for which in ("fwd", "dq", "dkv")},
+        "bwd": {"scheme": "resident", "block_q": 128, "block_k": 128,
+                "visited_blocks": 93, "masked_blocks": 93,
+                "grid_blocks": 1024, "block_matmuls": 7,
+                "vmem_bytes": 2555904}}),
+], ids=["trinity-sliding-layers", "window-255-d64"])
+def test_windowed_plans_keep_the_resident_forward(t, d, window, q_per_kv,
+                                                  want):
+    """A windowed call's forward stays on the loops at the call's
+    tiles: its whole plan, key for key, as the tree had it before the
+    window-less forward moved to the streaming grid."""
+    assert F.flash_plan(t, d, dtype=jnp.bfloat16, causal=True,
+                        window=window, q_per_kv=q_per_kv) == want
 
 
 @pytest.mark.parametrize("blocks,scheme,matmuls", [

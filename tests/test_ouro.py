@@ -295,27 +295,34 @@ def test_row_cross_entropy_kernels_match_plain_xla(n, v, sure):
 # -- (d) attention through the flash kernels at head size 128 -----------------
 
 
-@pytest.mark.parametrize("scheme, seq", [(None, 512), ("resident", 1024)],
-                         ids=["head", "resident"])
+@pytest.mark.parametrize("scheme, seq", [(None, 512), ("resident", 1024),
+                                         ("stream", 2048)],
+                         ids=["head", "resident", "stream"])
 def test_flash_at_head_size_128_matches_the_plain_path(monkeypatch, scheme,
                                                        seq):
     """The published head size, rotary included, through
     `flash_attention` in interpret mode against the plain path on the
     same parameters: by the head kernels and, forced, by the resident
-    loops over several blocks with the ONE fused backward kernel behind
-    them, which is what the cell's T 4096 runs."""
+    loops or the streaming grid at 1024 x 1024 over several blocks, with
+    the ONE fused backward kernel behind them; the streaming forward is
+    what the cell's T 4096 runs."""
     if scheme is None:  # what the cell's call reads, unforced
         cell = flash.flash_plan(4096, 128, dtype=jnp.bfloat16, causal=True)
         assert (cell["block_q"], cell["block_k"]) == (1024, 512)
-        assert cell["fwd"] == {"scheme": "resident", "visited_blocks": 20,
-                               "masked_blocks": 20, "grid_blocks": 32}
+        assert cell["fwd"] == {
+            "scheme": "stream", "block_q": 1024, "block_k": 1024,
+            "visited_blocks": 10, "masked_blocks": 4, "fetched_blocks": 9,
+            "grid_blocks": 16}
         assert cell["bwd"]["scheme"] == "stream_fused"
         assert cell["bwd"]["block_matmuls"] == 5
         assert cell["bwd"]["vmem_bytes"] <= flash._BWD_STREAM_VMEM_LIMIT
     monkeypatch.setattr(flash, "_FORCE_SCHEME", scheme)
     plan = flash.flash_plan(seq, 128, causal=True)
     assert plan["fwd"]["scheme"] == (scheme or "head")
-    assert plan["nq"] == seq // 256
+    if scheme == "stream":   # its own tiles, a 2 x 2 grid a head
+        assert plan["fwd"]["grid_blocks"] == 4
+    else:
+        assert plan["nq"] == seq // 256
     assert plan["bwd"]["scheme"] == ("stream_fused" if scheme else "head")
     c = small(hidden_size=128, num_heads=2, head_dim=128)
     x = jax.random.normal(jax.random.PRNGKey(7), (1, seq, c.hidden_size))
@@ -367,7 +374,8 @@ def flash_params():
     # forward and ONE backward kernel an application, where a dq + dkv
     # pair ran until PR 33
     ("resident", 1024, "_fwd_res_kernel", "_bwd_stream_kernel"),
-    (None, 4096, "_fwd_res_kernel", "_bwd_stream_kernel"),
+    # the cell's T 4096 unforced: the forward on the streaming grid
+    (None, 4096, "_kernel", "_bwd_stream_kernel"),
 ], ids=["head", "resident", "t4096"])
 def test_recomputation_runs_flash_forward_once_an_application(
         monkeypatch, flash_params, scheme, seq, fwd, bwd, policy):

@@ -137,6 +137,7 @@ def test_flash_latent_attention_d256_t8192(one_chip, grad):
     plan = flash.flash_plan(8192, 256, dtype=jnp.bfloat16, causal=True)
     assert (plan["block_q"], plan["block_k"]) == (1024, 512)
     assert {plan[w]["scheme"] for w in ("fwd", "dq", "dkv")} == {"stream"}
+    assert (plan["fwd"]["block_q"], plan["fwd"]["block_k"]) == (1024, 1024)
     assert plan["bwd"]["scheme"] == "stream_fused"
     assert (plan["bwd"]["block_q"], plan["bwd"]["block_k"]) == (1024, 1024)
     assert plan["bwd"]["vmem_bytes"] <= flash._BWD_STREAM_VMEM_LIMIT
@@ -155,6 +156,16 @@ def test_flash_latent_attention_d256_t8192(one_chip, grad):
     # the scoped VMEM the fused kernel states, and nothing else does
     stated = f'"size":"{flash._BWD_STREAM_VMEM_LIMIT}"'
     assert compiled.as_text().count(stated) == (1 if grad else 0)
+    _assert_forward_states_its_limit(compiled)
+
+
+def _assert_forward_states_its_limit(compiled):
+    """The window-less forward on the streaming grid hands Mosaic the
+    scoped-VMEM limit it states, once a call."""
+    from kungfu_tpu.ops import flash
+
+    stated = f'"size":"{flash._FWD_STREAM_VMEM_LIMIT}"'
+    assert compiled.as_text().count(stated) == 1
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
@@ -164,12 +175,14 @@ def test_flash_looped_model_d128_t4096(one_chip, grad):
     kernels, inside the budget of the resident loops: the forward on
     them at 1024 x 512 tiles, and the ONE fused backward kernel at its
     own 1024 x 1024 under the `vmem_limit_bytes` it states (PR 33; the
-    resident dq + dkv pair until then)."""
+    resident dq + dkv pair until then). The forward streams at its own
+    1024 x 1024 under the limit it states."""
     from kungfu_tpu.ops import flash
 
     plan = flash.flash_plan(4096, 128, dtype=jnp.bfloat16, causal=True)
     assert (plan["block_q"], plan["block_k"]) == (1024, 512)
-    assert plan["fwd"]["scheme"] == "resident"
+    assert plan["fwd"]["scheme"] == "stream"
+    assert (plan["fwd"]["block_q"], plan["fwd"]["block_k"]) == (1024, 1024)
     assert plan["bwd"]["scheme"] == "stream_fused"
     assert (plan["bwd"]["block_q"], plan["bwd"]["block_k"]) == (1024, 1024)
     assert plan["bwd"]["vmem_bytes"] <= flash._BWD_STREAM_VMEM_LIMIT
@@ -186,6 +199,38 @@ def test_flash_looped_model_d128_t4096(one_chip, grad):
     assert _kernels(compiled) == (2 if grad else 1)
     stated = f'"size":"{flash._BWD_STREAM_VMEM_LIMIT}"'
     assert compiled.as_text().count(stated) == (1 if grad else 0)
+    _assert_forward_states_its_limit(compiled)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_d64_t4096(one_chip, grad):
+    """The `gpt2-small.train-b2-t4096` cell's call, 12 times a step: two
+    sequences of 4096, 12 heads of 64, bf16, causal. Past the head
+    kernels: the forward on the streaming grid at its own 1024 x 1024
+    (the diagonal's blocks alone masked, nothing fetched past it) and
+    the ONE fused backward, each under the limit it states."""
+    from kungfu_tpu.ops import flash
+
+    plan = flash.flash_plan(4096, 64, dtype=jnp.bfloat16, causal=True)
+    assert plan["fwd"] == {
+        "scheme": "stream", "block_q": 1024, "block_k": 1024,
+        "visited_blocks": 10, "masked_blocks": 4, "fetched_blocks": 9,
+        "grid_blocks": 16}
+    assert plan["bwd"]["scheme"] == "stream_fused"
+
+    def fwd(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True,
+                                     interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    compiled = _compile(fn, *_qkv(one_chip, b=2, t=4096, h=12, d=64))
+    assert _kernels(compiled) == (2 if grad else 1)
+    stated = f'"size":"{flash._BWD_STREAM_VMEM_LIMIT}"'
+    assert compiled.as_text().count(stated) == (1 if grad else 0)
+    _assert_forward_states_its_limit(compiled)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
@@ -193,8 +238,8 @@ def test_flash_looped_model_d128_t4096(one_chip, grad):
 def test_flash_grouped_heads_d128_t8192(one_chip, window, grad):
     """The `trinity-mini.train-b1-t8192` cell's two calls: one sequence
     of 8192, 32 query heads of 128 on 4 K/V heads, bf16, causal. A full
-    layer's: the forward on the resident loops at 1024 x 512, ONE fused
-    backward kernel at its own 1024 x 1024. A sliding layer's (window
+    layer's: the forward on the streaming grid and ONE fused backward
+    kernel, each at its own 1024 x 1024. A sliding layer's (window
     2047 = `sliding_window - 1`): 512 x 512 tiles, the forward on the
     resident loops and ONE fused backward, `_bwd_res_kernel` (PR 37).
     Both backwards state their VMEM limit. K/V reach every kernel
@@ -204,7 +249,7 @@ def test_flash_grouped_heads_d128_t8192(one_chip, window, grad):
 
     plan = flash.flash_plan(8192, 128, dtype=jnp.bfloat16, causal=True,
                             window=window, q_per_kv=8)
-    assert plan["fwd"]["scheme"] == "resident"
+    assert plan["fwd"]["scheme"] == ("resident" if window else "stream")
     assert plan["bwd"]["scheme"] == ("resident_fused" if window
                                      else "stream_fused")
     assert (plan["block_q"], plan["block_k"]) == (
@@ -224,6 +269,8 @@ def test_flash_grouped_heads_d128_t8192(one_chip, window, grad):
     assert _kernels(compiled) == (2 if grad else 1)
     stated = f'"size":"{flash._BWD_STREAM_VMEM_LIMIT}"'
     assert compiled.as_text().count(stated) == (1 if grad else 0)
+    if window is None:
+        _assert_forward_states_its_limit(compiled)
     if grad:   # dk and dv come back at the K/V heads' count
         assert [x.shape for x in compiled.out_info] == [
             (1, 8192, 32, 128), (1, 8192, 4, 128), (1, 8192, 4, 128)]
