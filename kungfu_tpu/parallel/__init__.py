@@ -28,7 +28,7 @@ from .pipeline import (pipeline_apply, pipeline_train_step_1f1b,
                        stack_stage_params)
 from .rules import (PlanError, RuleTable, afmoe_rules, bert_tp_rules,
                     glm_moe_rules,
-                    gpt_moe_rules,
+                    gpt_moe_rules, granite_hybrid_rules,
                     gpt_pp_rules, gpt_serve_rules, gpt_tp_rules,
                     match_partition_rules,
                     moe_ep_rules, ouro_rules, reshard, seq_sp_rules,
@@ -69,6 +69,7 @@ __all__ = [
     "glm_moe_rules",
     "ouro_rules",
     "afmoe_rules",
+    "granite_hybrid_rules",
     "gpt_pp_rules",
     "gpt_serve_rules",
     "moe_ep_rules",

@@ -503,6 +503,27 @@ def afmoe_rules(axis: str = "model") -> RuleTable:
         batch_axes=("data",))
 
 
+def granite_hybrid_rules(axis: str = "model") -> RuleTable:
+    """The Megatron split for `models/granite_hybrid.py`'s parameter
+    paths: the attention layer's q, k and v column-parallel over their
+    heads (the K/V heads are fewer: the axis must divide THEIR count)
+    and `o_proj` row-parallel; every SwiGLU split over its width. The
+    Mamba-2 mixer replicates whole: its `in_proj` lays z, x, B, C and
+    dt side by side in one kernel, so a split of its columns would cut
+    across them, and a split by heads needs the kernel cut by segment
+    first. Norms, the embedding (the tied head) replicate."""
+    return RuleTable(
+        name=f"granite_hybrid[{axis}]",
+        rules=(
+            (r".*self_attn/(q|k|v)_proj/kernel", spec(None, axis, None)),
+            (r".*self_attn/o_proj/kernel", rows(axis)),
+            (r".*shared_mlp/(gate|up)/kernel", cols(axis)),
+            (r".*shared_mlp/down/kernel", rows(axis)),
+            _CATCH_ALL,
+        ),
+        batch_axes=("data",))
+
+
 def gpt_pp_rules(axis: str = "pipe",
                  tp_axis: Optional[str] = None) -> RuleTable:
     """Stage-stacked pipeline placement for the STACKED half of
@@ -756,6 +777,28 @@ def _template_afmoe() -> Dict[str, Tuple[int, ...]]:
     return _tree_template(shapes["params"])
 
 
+@lru_cache(maxsize=8)
+def _template_granite_hybrid() -> Dict[str, Tuple[int, ...]]:
+    """`models/granite_hybrid.py` at a size whose heads (4 on 2 K/V
+    heads) and widths a 2-way model axis divides: a Mamba-2 block and
+    an attention block. Imported here and not at the top, as
+    `_template_glm_moe`."""
+    import jax.numpy as jnp
+
+    from ..models.granite_hybrid import (ATTENTION, MAMBA,
+                                         GraniteHybridConfig,
+                                         GraniteHybridLM)
+
+    cfg = GraniteHybridConfig(
+        vocab_size=251, hidden_size=64, num_heads=4, num_kv_heads=2,
+        layer_types=(MAMBA, ATTENTION), intermediate_size=160,
+        mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16,
+        mamba_chunk_size=16, dtype=jnp.float32)
+    shapes = jax.eval_shape(GraniteHybridLM(cfg).init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 16), jnp.int32))
+    return _tree_template(shapes["params"])
+
+
 def _register_builtin_tables() -> None:
     """The shipped model-family tables at the MULTICHIP dryrun shapes
     — what `python -m kungfu_tpu.analysis` statically verifies."""
@@ -797,6 +840,10 @@ def _register_builtin_tables() -> None:
              _template_afmoe,
              [{"data": 4, "model": 2}, {"data": 1, "model": 2},
               {"data": 1, "model": 1}])
+    register("granite_hybrid", granite_hybrid_rules(),
+             _template_granite_hybrid,
+             [{"data": 4, "model": 2}, {"data": 1, "model": 2},
+              {"data": 1, "model": 1}])
     register("gpt_serve", gpt_serve_rules(),
              _template_gpt,
              # decode's (1, tp) serving mesh and the dp-replicated
@@ -824,7 +871,8 @@ def _table_universe(table: RuleTable) -> Tuple[str, ...]:
 TABLE_AXES: Dict[str, Tuple[str, ...]] = {
     f.__name__: _table_universe(f())
     for f in (bert_tp_rules, gpt_tp_rules, gpt_moe_rules,
-              glm_moe_rules, ouro_rules, afmoe_rules, gpt_pp_rules,
+              glm_moe_rules, ouro_rules, afmoe_rules,
+              granite_hybrid_rules, gpt_pp_rules,
               moe_ep_rules,
               seq_sp_rules,
               gpt_serve_rules)

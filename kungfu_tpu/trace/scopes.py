@@ -68,6 +68,18 @@ ATTN_LOCAL = "kf.attn_local"
 #: trace prices the two kinds of layer of one stack apart.
 ATTN_GLOBAL = "kf.attn_global"
 
+#: a Mamba-2 mixer's sublayer of `models/granite_hybrid.py`: its input
+#: norm, `in_proj`, the causal depthwise conv, the step size, the SSD
+#: scan (SSD below, nested), the gated norm and `out_proj`; forward,
+#: recomputed forward and backward.
+SSM = "kf.ssm"
+
+#: the chunked state-space-duality scan of `ops/ssd.py` alone, forward
+#: and backward: the decays and their sums, the in-chunk masked
+#: products, the chunk states, the pass between chunks and the output
+#: from the states.
+SSD = "kf.ssd"
+
 #: a kftrace span `name` shows in a profiler session as
 #: HOST_SPAN_PREFIX + name, on the calling thread of `/host:CPU`.
 HOST_SPAN_PREFIX = "kf."

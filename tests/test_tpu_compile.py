@@ -276,6 +276,59 @@ def test_flash_grouped_heads_d128_t8192(one_chip, window, grad):
             (1, 8192, 32, 128), (1, 8192, 4, 128), (1, 8192, 4, 128)]
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_grouped_heads_d64_t8192_scaled(one_chip, grad):
+    """The `granite-4.0-h-micro.train-b1-t8192` cell's attention layer:
+    one sequence of 8192, 32 query heads of 64 on 8 K/V heads, bf16,
+    causal, no positions, at the caller's scale 1/64: the streaming
+    forward at 1024 x 1024 and ONE fused backward."""
+    from kungfu_tpu.ops import flash
+
+    plan = flash.flash_plan(8192, 64, dtype=jnp.bfloat16, causal=True,
+                            q_per_kv=4)
+    assert plan["fwd"]["scheme"] == "stream"
+    assert plan["bwd"]["scheme"] == "stream_fused"
+
+    def fwd(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True, scale=1 / 64,
+                                     interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    q = _qkv(one_chip, b=1, t=8192, h=32, d=64)[0]
+    k = _qkv(one_chip, b=1, t=8192, h=8, d=64)[0]
+    compiled = _compile(fn, q, k, k)
+    assert _kernels(compiled) == (2 if grad else 1)
+    _assert_forward_states_its_limit(compiled)
+
+
+def test_ssd_scan_granite_published_shapes(one_chip):
+    """One Mamba-2 layer's SSD scan of the same cell, forward and
+    backward: 8192 positions in 32 chunks of 256, 64 heads of 64, one
+    group of B and C of 128, bf16 x, B and C, f32 step sizes. XLA's
+    form: no kernel, and the compiled program's scratch stays under a
+    GiB (the [chunks, heads, Q, Q] decays a pass of 8 heads at a time,
+    64 MiB each)."""
+    from kungfu_tpu.ops.ssd import ssd
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(*args):
+        return ssd(*args, chunk=256)[0].astype(jnp.float32).sum()
+
+    b, t, h, p, n = 1, 8192, 64, 64, 128
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=range(6)),
+        sds((b, t, h, p), jnp.bfloat16), sds((b, t, h), jnp.float32),
+        sds((h,), jnp.float32), sds((b, t, n), jnp.bfloat16),
+        sds((b, t, n), jnp.bfloat16), sds((h,), jnp.float32))
+    assert _kernels(compiled) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
 def test_grouped_expert_matmuls_top8_of_128(one_chip):
     """The held experts' grouped SwiGLU at the `trinity-mini` cell's
     sizes: 8 experts of 2048 x 1024 over the worst-case row buffer
