@@ -330,7 +330,7 @@ def layer_plan(c: GraniteHybridConfig, batch: int, seq: int):
     return {
         "layers": c.layer_types,
         "ssd": ssd_plan(batch, seq, c.mamba_n_heads, c.mamba_d_head,
-                        c.mamba_d_state, c.mamba_chunk_size),
+                        c.mamba_d_state, c.mamba_chunk_size, dtype=c.dtype),
         "kept": tuple(kept) + (tuple(attention_kept) if n_attention
                                else ()),
         "kept_bytes_per_block": per_block,

@@ -417,8 +417,17 @@ def test_ssd_scope_nests_inside_the_ssm_scope(granite_paths):
 
 
 def test_every_flash_kernel_sits_under_the_attention_module(granite_paths):
+    """Flash's kernels under the attention module; the other kernels are
+    the fused head + CE's and the SSD forward's, which sits inside the
+    Mamba sublayer's scope, in the forward and its recomputation and in
+    no part of the backward's own work."""
     kernels = [p for p in granite_paths if primitive(p) == "pallas_call"]
-    flash = [p for p in kernels if FUSED_CE not in p]
+    scan = _under(kernels, SSD)
+    assert scan and set(scan) <= set(_under(kernels, SSM))
+    recomputed = [p for p in scan if "rematted_computation" in p]
+    assert recomputed and not [p for p in scan if "transpose(" in p
+                               and p not in recomputed]
+    flash = [p for p in kernels if FUSED_CE not in p and p not in scan]
     assert flash and [p for p in kernels if FUSED_CE in p]
     for p in flash:
         assert "NoPEAttention_0/pallas_call" in p or (

@@ -1,10 +1,12 @@
 """`ops/ssd.py`'s chunked scan against the recurrence it computes, one
-position at a time, on the CPU in f32: outputs, the final state and the
-gradients of all six inputs, at chunk sizes 8, 16 and 32, lengths that
-are no multiple of the chunk, and decays near 0 and near 1. Then what
-the module promises of its structure: no loop over positions, f32
-decays and states under bf16 inputs, passes of heads, one trace for
-many layers, and the plan at the published shapes."""
+position at a time, on the CPU in f32 (the forward kernel in Pallas's
+interpreter): outputs, the final state, the chunks' entry states and
+the gradients of all six inputs, at chunk sizes 8, 16 and 32, lengths
+that are no multiple of the chunk, and decays near 0 and near 1. Then
+what the module promises of its structure: no loop over positions, f32
+decays and states under bf16 inputs, heads a program and passes of
+heads, one trace for many layers, and the plan at the published
+shapes."""
 
 import jax
 import jax.numpy as jnp
@@ -129,6 +131,36 @@ def test_passes_of_heads_change_nothing(monkeypatch):
             assert rel_err(g, w) < 1e-6
 
 
+@pytest.mark.parametrize("hg", [1, 2])
+def test_heads_a_program_change_nothing(monkeypatch, hg):
+    """The forward kernel over programs of one or two heads, or of all
+    four: the same outputs, final state and entry states."""
+    args = inputs(40, "slow")
+    whole = S._forward_at(*args, 8)
+    monkeypatch.setattr(S, "_heads_per_program", lambda *a: hg)
+    for got, want in zip(S._forward_at(*args, 8), whole):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_entry_states_match_the_recurrence():
+    """The state each chunk enters with, which the backward takes from
+    the forward kernel: the recurrence's state after the chunks before
+    it, under decays slow enough that it carries across all of them."""
+    chunk, t = 16, 64
+    args = inputs(t, "slow")
+    _, final, s_in = S._forward_at(*args, chunk)
+    assert s_in.shape == (2, t // chunk, 4, 8, 16)
+    assert s_in.dtype == jnp.float32
+    np.testing.assert_array_equal(s_in[:, 0], 0.0)
+    for c in range(1, t // chunk):
+        _, want = recurrence(*(a[:, :c * chunk] if a.ndim > 1 else a
+                               for a in args))
+        assert float(jnp.abs(want).max()) > 0.1     # carried, not ~0
+        np.testing.assert_allclose(s_in[:, c], want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(final, recurrence(*args)[1], rtol=2e-5,
+                               atol=2e-5)
+
+
 def test_extreme_decays_stay_finite():
     """Steps of dt A down to -1e4: every exp the scan forms is of a
     number <= 0, so nothing overflows and no inf meets a zero in the
@@ -144,22 +176,26 @@ def test_extreme_decays_stay_finite():
 
 
 def test_no_loop_runs_over_positions():
-    """T 256 in chunks of 16: the loops of the forward and the backward
-    run over the 16 chunks (the pass between them) or over passes of
-    heads; none has a trip a position."""
+    """T 256 in chunks of 16: the forward kernel's grid runs over the 16
+    chunks innermost, the backward's one loop over them (the pass
+    between chunks) or over passes of heads; none has a trip a
+    position."""
     args = inputs(256, "mid", h=4)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(ssd(*a, chunk=16)[0]), argnums=range(6)))(*args)
-    lengths = [e.params["length"] for e in sub_jaxprs(jaxpr.jaxpr)
-               if e.primitive.name == "scan"]
+    eqns = list(sub_jaxprs(jaxpr.jaxpr))
+    lengths = [e.params["length"] for e in eqns if e.primitive.name == "scan"]
     assert 16 in lengths
     assert max(lengths) == 16
+    [kernel] = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert kernel.params["grid_mapping"].grid == (2, 1, 16)
 
 
 def test_bf16_inputs_keep_f32_decays_states_and_accumulation():
-    """Under bf16 x, B and C: the carried states of both passes between
-    chunks are f32, every exp is f32, and every matmul takes bf16
-    operands (f32 accumulation) or f32 ones of the decays' side."""
+    """Under bf16 x, B and C: the carried state of the forward kernel
+    (its scratch) and of the backward's pass between chunks are f32,
+    every exp is f32, and every matmul, the kernel's included, takes
+    bf16 operands with f32 accumulation."""
     args = inputs(64, "mid", dtype=jnp.bfloat16)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(ssd(*a, chunk=16)[0].astype(jnp.float32)),
@@ -167,15 +203,20 @@ def test_bf16_inputs_keep_f32_decays_states_and_accumulation():
     eqns = list(sub_jaxprs(jaxpr.jaxpr))
     scans = [e for e in eqns if e.primitive.name == "scan"
              and e.params["length"] == 4]
-    assert len(scans) == 2      # the forward's pass and the backward's
+    assert len(scans) == 1      # the backward's pass between chunks
     for e in scans:
         carry = e.params["num_carry"]
         dtypes = {v.aval.dtype for v in e.outvars[:carry]}
         assert dtypes == {jnp.dtype(jnp.float32)}, dtypes
+    [kernel] = [e for e in eqns if e.primitive.name == "pallas_call"]
+    scratch = kernel.params["jaxpr"].invars[-1].aval
+    assert (scratch.shape, scratch.dtype) == ((4 * 8, 16), jnp.float32)
+    in_kernel = list(sub_jaxprs(kernel.params["jaxpr"]))
+    assert [e for e in in_kernel if e.primitive.name == "exp"]
     assert {e.outvars[0].aval.dtype for e in eqns
             if e.primitive.name == "exp"} == {jnp.dtype(jnp.float32)}
     dots = [e for e in eqns if e.primitive.name == "dot_general"]
-    assert dots
+    assert [e for e in in_kernel if e.primitive.name == "dot_general"]
     for e in dots:
         assert e.outvars[0].aval.dtype == jnp.float32
         assert {v.aval.dtype for v in e.invars} == {jnp.dtype(jnp.bfloat16)}
@@ -188,7 +229,9 @@ def test_layers_replay_one_trace_of_each_direction(monkeypatch):
     """Three calls of one shape in one program (a length no other case
     here takes, so that no earlier trace is cached): the forward's body
     and the backward's are each traced ONCE, and the program's ops sit
-    under the `kf.ssd` scope both ways."""
+    under the `kf.ssd` scope both ways: the forward kernel's
+    `pallas_call` under the forward's, the backward's matmuls under
+    its own."""
     traced = []
     chunked = S._chunked
     monkeypatch.setattr(S, "_chunked", lambda *a: traced.append(1) or
@@ -202,20 +245,37 @@ def test_layers_replay_one_trace_of_each_direction(monkeypatch):
 
     jaxpr = jax.make_jaxpr(jax.grad(three))(*args)
     assert len(traced) == 2
-    stacks = {str(e.source_info.name_stack) for e in sub_jaxprs(jaxpr.jaxpr)
-              if e.primitive.name == "dot_general"}
-    for way in (f"jvp({SSD})/{SSD}", f"transpose(jvp({SSD}))/{SSD}"):
-        assert [x for x in stacks if x.startswith(way)], (way, stacks)
+
+    def stacks(primitive):
+        return {str(e.source_info.name_stack)
+                for e in sub_jaxprs(jaxpr.jaxpr)
+                if e.primitive.name == primitive}
+
+    kernels = stacks("pallas_call")
+    assert len(kernels) == 1
+    assert [x for x in kernels if x.startswith(f"jvp({SSD})/{SSD}")], kernels
+    way = f"transpose(jvp({SSD}))/{SSD}"
+    assert [x for x in stacks("dot_general") if x.startswith(way)], way
 
 
 def test_plan_at_the_published_shapes():
     """granite-4.0-h-micro: T 8192 in 32 chunks of 256, 64 heads of 64,
-    state 128: 8 heads a pass (64 MiB of f32 decays), the state a
+    state 128, bf16 x: the forward kernel over 32 heads a program
+    ([256, 2048] blocks of x and y, a [2048, 128] f32 state), the
+    backward 8 heads a pass (64 MiB of f32 decays), the state a
     sequence carries 2 MiB a layer."""
-    plan = ssd_plan(1, 8192, 64, 64, 128, 256)
+    plan = ssd_plan(1, 8192, 64, 64, 128, 256, dtype=jnp.bfloat16)
+    fwd = plan.pop("fwd")
+    assert fwd == {
+        "form": "pallas_fused", "heads_per_program": 32, "grid": (1, 2, 32),
+        "block_rows": (256, 2048), "block_state": (2048, 128),
+        "vmem_bytes": S._fwd_vmem_bytes(32, 64, 128, 256, 2),
+        "vmem_limit_bytes": S._FWD_VMEM_LIMIT}
+    assert fwd["vmem_bytes"] <= S._FWD_VMEM_BUDGET < S._FWD_VMEM_LIMIT
     assert plan == {
         "form": "xla_chunked", "chunk": 256, "chunks": 32,
         "padded_positions": 0, "heads_per_pass": 8, "passes": 8,
         "state_bytes": 64 * 64 * 128 * 4, "pass_bytes": 64 * 2 ** 20,
         "largest_intermediate_bytes": 8192 * 64 * 64 * 4}
-    assert ssd_plan(1, 8000, 64, 64, 128, 256)["padded_positions"] == 192
+    assert ssd_plan(1, 8000, 64, 64, 128, 256, dtype=jnp.bfloat16)[
+        "padded_positions"] == 192

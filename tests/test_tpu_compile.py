@@ -304,29 +304,53 @@ def test_flash_grouped_heads_d64_t8192_scaled(one_chip, grad):
     _assert_forward_states_its_limit(compiled)
 
 
-def test_ssd_scan_granite_published_shapes(one_chip):
-    """One Mamba-2 layer's SSD scan of the same cell, forward and
-    backward: 8192 positions in 32 chunks of 256, 64 heads of 64, one
-    group of B and C of 128, bf16 x, B and C, f32 step sizes. XLA's
-    form: no kernel, and the compiled program's scratch stays under a
-    GiB (the [chunks, heads, Q, Q] decays a pass of 8 heads at a time,
-    64 MiB each)."""
-    from kungfu_tpu.ops.ssd import ssd
+def _ssd_args(sharding):
+    """One Mamba-2 layer's scan at the granite cell's shapes: 8192
+    positions, 64 heads of 64, one group of B and C of 128, bf16 x, B
+    and C, f32 step sizes, A and D."""
+    b, t, h, p, n = 1, 8192, 64, 64, 128
 
     def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return (sds((b, t, h, p), jnp.bfloat16), sds((b, t, h), jnp.float32),
+            sds((h,), jnp.float32), sds((b, t, n), jnp.bfloat16),
+            sds((b, t, n), jnp.bfloat16), sds((h,), jnp.float32))
+
+
+def test_ssd_scan_granite_published_shapes(one_chip, monkeypatch):
+    """One Mamba-2 layer's SSD scan of the same cell, forward and
+    backward, in 32 chunks of 256: ONE kernel (the forward's; the
+    backward is XLA's), and the compiled program's scratch stays under
+    a GiB (the backward's [chunks, heads, Q, Q] decays a pass of 8
+    heads at a time, 64 MiB each)."""
+    from kungfu_tpu.ops import ssd as S
+
+    monkeypatch.setattr(S, "_interpret", lambda: False)
 
     def loss(*args):
-        return ssd(*args, chunk=256)[0].astype(jnp.float32).sum()
+        return S.ssd(*args, chunk=256)[0].astype(jnp.float32).sum()
 
-    b, t, h, p, n = 1, 8192, 64, 64, 128
-    compiled = _compile(
-        jax.value_and_grad(loss, argnums=range(6)),
-        sds((b, t, h, p), jnp.bfloat16), sds((b, t, h), jnp.float32),
-        sds((h,), jnp.float32), sds((b, t, n), jnp.bfloat16),
-        sds((b, t, n), jnp.bfloat16), sds((h,), jnp.float32))
-    assert _kernels(compiled) == 0
+    compiled = _compile(jax.value_and_grad(loss, argnums=range(6)),
+                        *_ssd_args(one_chip))
+    assert _kernels(compiled) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_ssd_scan_forward_granite_published_shapes(one_chip, monkeypatch):
+    """The same layer's forward alone: one kernel over (batch, groups of
+    heads, chunks), which Mosaic takes under the scoped-VMEM limit it
+    states, with the buffers `ssd_plan` counts inside that limit."""
+    from kungfu_tpu.ops import ssd as S
+
+    monkeypatch.setattr(S, "_interpret", lambda: False)
+    plan = S.ssd_plan(1, 8192, 64, 64, 128, 256, dtype=jnp.bfloat16)["fwd"]
+    assert plan["vmem_bytes"] <= plan["vmem_limit_bytes"]
+    compiled = _compile(lambda *a: S.ssd(*a, chunk=256),
+                        *_ssd_args(one_chip))
+    assert _kernels(compiled) == 1
+    stated = f'"size":"{S._FWD_VMEM_LIMIT}"'
+    assert compiled.as_text().count(stated) == 1
 
 
 def test_grouped_expert_matmuls_top8_of_128(one_chip):
