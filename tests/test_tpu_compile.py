@@ -320,20 +320,26 @@ def _ssd_args(sharding):
 
 def test_ssd_scan_granite_published_shapes(one_chip, monkeypatch):
     """One Mamba-2 layer's SSD scan of the same cell, forward and
-    backward, in 32 chunks of 256: ONE kernel (the forward's; the
-    backward is XLA's), and the compiled program's scratch stays under
-    a GiB (the backward's [chunks, heads, Q, Q] decays a pass of 8
-    heads at a time, 64 MiB each)."""
+    backward, in 32 chunks of 256: TWO kernels (the forward's and the
+    backward's), which Mosaic takes under the scoped-VMEM limits they
+    state, with the buffers `ssd_plan` counts inside each limit; the
+    compiled program's scratch stays under a GiB."""
     from kungfu_tpu.ops import ssd as S
 
     monkeypatch.setattr(S, "_interpret", lambda: False)
+    plan = S.ssd_plan(1, 8192, 64, 64, 128, 256, dtype=jnp.bfloat16)
+    for kernel in ("fwd", "bwd"):
+        assert plan[kernel]["vmem_bytes"] <= plan[kernel]["vmem_limit_bytes"]
 
     def loss(*args):
         return S.ssd(*args, chunk=256)[0].astype(jnp.float32).sum()
 
     compiled = _compile(jax.value_and_grad(loss, argnums=range(6)),
                         *_ssd_args(one_chip))
-    assert _kernels(compiled) == 1
+    assert _kernels(compiled) == 2
+    text = compiled.as_text()
+    for limit in (S._FWD_VMEM_LIMIT, S._BWD_VMEM_LIMIT):
+        assert text.count(f'"size":"{limit}"') == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
